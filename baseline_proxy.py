@@ -1,4 +1,4 @@
-"""Measured CPU baseline for the bench suite (VERDICT r3 weak #5).
+"""Measured CPU baseline for the bench suite.
 
 The reference's own harness (presto-benchmark BenchmarkSuite /
 HandTpchQuery1, see BASELINE.md) cannot run in this image: there is no

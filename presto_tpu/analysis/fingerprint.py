@@ -57,7 +57,7 @@ def _render_param(v, depth: int) -> str:
 def _render_jaxpr(jaxpr, depth: int = 0) -> str:
     if depth > 16:
         return "<deep>"
-    import jax.core as jc
+    from jax.extend import core as jc
     ids = {}
 
     def vid(v) -> str:

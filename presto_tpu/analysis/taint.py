@@ -45,6 +45,8 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, List, Optional, Sequence, Tuple
 
+import numpy as np
+
 CLEAN, PAD, POISON = 0, 1, 2
 _TAINT_NAME = {CLEAN: "CLEAN", PAD: "PAD", POISON: "POISON"}
 
@@ -164,7 +166,7 @@ class _Interp:
     # -- env helpers ---------------------------------------------------
 
     def _read(self, env: Dict, v) -> AV:
-        import jax.core as jc
+        from jax.extend import core as jc
         if isinstance(v, jc.Literal):
             return AV(CLEAN)
         return env.get(v, AV(CLEAN))
@@ -218,7 +220,7 @@ class _Interp:
             return self._scan(eqn, ins)
         if name == "cond":
             return self._cond(eqn, ins)
-        if name in ("pjit", "closed_call", "core_call", "xla_call",
+        if name in ("jit", "closed_call", "core_call",
                     "custom_jvp_call", "custom_vjp_call", "remat",
                     "remat2", "checkpoint", "custom_vjp_call_jaxpr",
                     # shard_map carries its body as the `jaxpr` param;
@@ -278,7 +280,7 @@ class _Interp:
         survives when the padding value is the polarity's constant
         (False lanes for dead_false — exactly what _pad_batch
         appends)."""
-        import jax.core as jc
+        from jax.extend import core as jc
         pols = {a.pol for a in ins if a.pol is not None}
         if len(pols) != 1 or any(a.pol is None and a.taint != CLEAN
                                  for a in ins):
@@ -331,8 +333,14 @@ class _Interp:
                 and a.pol == b.pol == "dead_false" else None
             return AV(t, pol)
         if name == "convert_element_type" and len(ins) == 1:
-            keep = ins[0].pol if str(
-                eqn.params.get("new_dtype", "")) == "bool" else None
+            # a bool widened to an integer lane (common.lex_perm's
+            # 32-bit sort keys) is as deterministic on dead lanes as
+            # the bool it came from
+            new = eqn.params.get("new_dtype")
+            widened_bool = str(eqn.invars[0].aval.dtype) == "bool" \
+                and np.issubdtype(new, np.integer)
+            keep = ins[0].pol if str(new) == "bool" or widened_bool \
+                else None
             return AV(ins[0].taint, keep)
         t = max((a.taint for a in ins), default=CLEAN)
         return AV(t)

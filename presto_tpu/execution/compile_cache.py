@@ -32,66 +32,86 @@ from typing import Any, Dict, List, Optional, Sequence
 
 from presto_tpu import sanitize
 
-#: environment surface (the config-file analog): set on the server
-#: process to persist XLA executables across restarts
-ENV_CACHE_DIR = "PRESTO_TPU_COMPILATION_CACHE_DIR"
+#: JAX's own variable: when set, JAX reads it at import and this
+#: module sets NO directory in code (the one rule, docs/COMPILATION.md)
+ENV_CACHE_DIR = "JAX_COMPILATION_CACHE_DIR"
 #: optional ';'-separated warmup SQL (or @/path/to/file with one
 #: statement per non-comment line) run at coordinator start
 ENV_PREWARM_SQL = "PRESTO_TPU_PREWARM_SQL"
+#: where the cache lives when nothing places it from outside: inside
+#: the checkout (git-ignored), at a FIXED path — the path is part of
+#: the cache key, so a directory that moves never hits
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 _LOCK = sanitize.lock("compile_cache.config")
 _CONFIGURED_DIR: Optional[str] = None
 
 
-def configure_compilation_cache(cache_dir: Optional[str]) -> bool:
+def _persist_everything() -> None:
+    """Zero jax's persistence thresholds so even small kernels persist
+    (restart-warm must re-load EVERYTHING cheaply, and the serving mix
+    is mostly sub-second kernels after bucketing)."""
+    import jax
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+
+
+def configure_compilation_cache(cache_dir: Optional[str]) -> None:
     """Point jax's persistent compilation cache at `cache_dir`
     (created if missing); None disables it. Process-global by nature
     — jax holds ONE cache dir — so this is a config surface, not a
-    session property. Returns True when the backend accepted the
-    setting. Idempotent; thresholds are zeroed so even small kernels
-    persist (restart-warm must re-load EVERYTHING cheaply, and the
-    serving mix is mostly sub-second kernels after bucketing)."""
+    session property. Idempotent; a directory that cannot be made or
+    a backend that refuses the setting raises."""
     global _CONFIGURED_DIR
     with _LOCK:
         if cache_dir == _CONFIGURED_DIR:
-            return True
-        try:
-            import jax
-            if cache_dir is not None:
-                os.makedirs(cache_dir, exist_ok=True)
-            jax.config.update("jax_compilation_cache_dir", cache_dir)
-            for flag, val in (
-                    ("jax_persistent_cache_min_compile_time_secs", 0),
-                    ("jax_persistent_cache_min_entry_size_bytes", -1)):
-                try:
-                    jax.config.update(flag, val)
-                except Exception:  # noqa: BLE001 — older jax
-                    pass
-            # jax memoizes a DISABLED cache at the first compile; any
-            # compile before this call (module-import jits, an earlier
-            # query) would otherwise leave the new dir silently unused
-            try:
-                from jax._src import compilation_cache as _cc
-                _cc.reset_cache()
-            except Exception:  # noqa: BLE001 — private-API drift
-                pass
-        except Exception:  # noqa: BLE001 — backend without support
-            return False
+            return
+        import jax
+        from jax.experimental.compilation_cache import (
+            compilation_cache as _cc,
+        )
+        if cache_dir is not None:
+            os.makedirs(cache_dir, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+        _persist_everything()
+        # jax memoizes a DISABLED cache at the first compile; any
+        # compile before this call (module-import jits, an earlier
+        # query) would otherwise leave the new dir silently unused
+        _cc.reset_cache()
         _CONFIGURED_DIR = cache_dir
-        return True
 
 
 def configured_cache_dir() -> Optional[str]:
+    """The directory this module set in code (None when jax was left
+    to JAX_COMPILATION_CACHE_DIR, or when no cache is on)."""
     return _CONFIGURED_DIR
 
 
-def configure_from_env() -> bool:
-    """Honor PRESTO_TPU_COMPILATION_CACHE_DIR if set (no-op
-    otherwise). Called by LocalRunner/Coordinator construction."""
-    d = os.environ.get(ENV_CACHE_DIR)
-    if not d:
-        return False
-    return configure_compilation_cache(d)
+def configure(cache_dir: Optional[str] = None) -> None:
+    """Called when a LocalRunner/Coordinator is built. An explicit
+    `compilation_cache_dir=` wins. Without one, the rule:
+    JAX_COMPILATION_CACHE_DIR, when
+    set, is JAX's to honor and no directory is set in code; otherwise
+    the cache is `<checkout>/.jax_cache`. A CPU backend does not
+    default into the cache: XLA:CPU's persistent entries are AOT
+    executables stamped with synthetic machine features that fail the
+    loader's host check on reload (error spam, no speedup), and CPU
+    compiles are cheap anyway. Asks for the backend, so it runs at
+    construction time, never while the package is imported."""
+    if cache_dir is not None:
+        configure_compilation_cache(cache_dir)
+        return
+    if os.environ.get(ENV_CACHE_DIR):
+        _persist_everything()
+        return
+    if _CONFIGURED_DIR is not None:
+        return  # an explicit override made earlier stays
+    import jax
+    if jax.default_backend() == "cpu":
+        return
+    configure_compilation_cache(DEFAULT_CACHE_DIR)
 
 
 def clear_kernel_caches() -> None:
@@ -160,7 +180,8 @@ def prewarm(runner, statements: Sequence[str],
         "compile_ms": round(
             (METRICS.total("presto_tpu_kernel_compile_ns_total")
              - compile_ns0) / 1e6, 1),
-        "disk_cache_dir": _CONFIGURED_DIR,
+        "disk_cache_dir": _CONFIGURED_DIR
+        or os.environ.get(ENV_CACHE_DIR) or None,
     }
 
 

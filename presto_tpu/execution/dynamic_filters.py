@@ -38,6 +38,7 @@ import jax.numpy as jnp
 
 from presto_tpu import sanitize
 from presto_tpu.batch import Batch
+from presto_tpu.ops import common
 
 #: Max distinct build keys carried as a set; more degrades to bounds
 #: only (reference: dynamic-filtering.max-distinct-values-per-driver).
@@ -218,21 +219,20 @@ def distinct_set(data, mask):
     info = _ident(data.dtype)
     if jnp.issubdtype(data.dtype, jnp.floating):
         mask = mask & ~jnp.isnan(data)  # NaN never equi-matches
-    nm, sk = jax.lax.sort((~mask, data), num_keys=2, is_stable=True)
+    # dead lanes share one key value, so the order is a function of
+    # live data only
+    perm = common.lex_perm(
+        [~mask, jnp.where(mask, data, jnp.zeros((), data.dtype))])
+    nm, sk = ~mask[perm], data[perm]
     sv = ~nm
     first = jnp.concatenate([
         jnp.asarray([True]),
         (sk[1:] != sk[:-1]) | (nm[1:] != nm[:-1])])
     keep = first & sv
     n = jnp.sum(keep)
-    # pack distinct values to the front (stable sort by ~keep keeps
-    # them in ascending key order)
-    _, pk = jax.lax.sort((~keep, sk), num_keys=1, is_stable=True)
-    if pk.shape[0] >= DF_SET_MAX:
-        pk = pk[:DF_SET_MAX]
-    else:
-        pk = jnp.pad(pk, (0, DF_SET_MAX - pk.shape[0]),
-                     constant_values=info.max)
+    # pack the first DF_SET_MAX distinct values to the front, still in
+    # ascending key order
+    pk = sk[common.first_true_indices(keep, DF_SET_MAX, 0)]
     out = jnp.where(jnp.arange(DF_SET_MAX) < n, pk,
                     jnp.asarray(info.max, data.dtype))
     return out, n, n > DF_SET_MAX
@@ -302,9 +302,7 @@ register_contract(KernelContract(
     family="dynamic_filter", module=__name__,
     build=_distinct_set_point,
     structure_varies=True,
-    structure_reason="distinct_set packs into the fixed DF_SET_MAX "
-                     "slot count: inputs at or below it take the pad "
-                     "branch, larger ones the slice branch — a "
-                     "deliberate static-shape fork on capacity, one "
-                     "program per side",
+    structure_reason="first_true_indices binary-searches the rank "
+                     "prefix of the input: log2(capacity) unrolled "
+                     "rounds on the CPU side of fast_searchsorted",
     notes="bounded distinct-set build (sort + boundary dedupe)"))

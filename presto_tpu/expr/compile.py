@@ -35,6 +35,7 @@ from presto_tpu.expr import dates as D
 from presto_tpu.expr.ir import (
     Call, InputRef, Literal, RowExpression, SpecialForm,
 )
+from presto_tpu.ops.common import float64_bits
 from presto_tpu.schema import ColumnSchema
 from presto_tpu.types import (
     BIGINT, BOOLEAN, DATE, DOUBLE, INTEGER, INTERVAL_DAY, INTERVAL_YEAR,
@@ -1062,9 +1063,10 @@ def _remap_to(p: CompiledExpr, dic: Tuple[str, ...]) -> CompiledExpr:
 
 # 64-bit splitmix-style hash for shuffle partitioning / group-by.
 def _hash64(d, m):
-    x = d.astype(jnp.int64)
     if d.dtype == jnp.float64 or d.dtype == jnp.float32:
-        x = jax.lax.bitcast_convert_type(d.astype(jnp.float64), jnp.int64)
+        x = float64_bits(d)
+    else:
+        x = d.astype(jnp.int64)
     x = jnp.where(m, x, jnp.int64(-0x61c8864680b583eb))
     x = (x ^ (x >> 30)) * jnp.int64(-0x40a7b892e31b1a47)
     x = (x ^ (x >> 27)) * jnp.int64(-0x6b2fb644ecceee15)

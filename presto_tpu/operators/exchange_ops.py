@@ -79,7 +79,7 @@ def partition_key_hash(batch: Batch, partition_keys: Sequence[str],
 def partition_segments(batch: Batch, partition_keys: Tuple[str, ...],
                        remaps, n_consumers: int):
     """ONE dispatch for a whole hash repartition: sort rows by
-    destination (columns ride the variadic sort as payloads) and
+    destination (one key sort, columns follow by gather) and
     return the sorted batch plus the destination segment bounds —
     segment c is rows [bounds[c], bounds[c+1]), dead rows parked at
     the end. The DCN push then does a single device->host transfer
@@ -92,12 +92,8 @@ def partition_segments(batch: Batch, partition_keys: Tuple[str, ...],
     payloads = [batch.row_valid]
     for n in batch.names:
         payloads.extend(batch.columns[n].astuple())
-    if common.cpu_backend():
-        perm = common.stable_argsort(dest)
-        out = [dest[perm]] + [p[perm] for p in payloads]
-    else:
-        out = jax.lax.sort((dest,) + tuple(payloads), num_keys=1,
-                           is_stable=True)
+    perm = common.stable_argsort(dest)
+    out = [dest[perm]] + [p[perm] for p in payloads]
     cols = {}
     for i, n in enumerate(batch.names):
         c = batch.columns[n]

@@ -167,6 +167,7 @@ def make_compacting_chain_body(stages: Sequence[ChainStage],
         out = chain(batch)
         cap = out.capacity  # static at trace time
         from presto_tpu.batch import COMPACT_MIN, operator_capacity
+        from presto_tpu.ops.common import first_true_indices
         comp_cap = operator_capacity(int(cap * ratio),
                                      floor=COMPACT_MIN)
         live = jnp.sum(out.row_valid)
@@ -174,8 +175,7 @@ def make_compacting_chain_body(stages: Sequence[ChainStage],
             return out, jnp.asarray(False)
         # bounded nonzero + gather, the _compact_shrink_jit shape —
         # inlined here so it traces into the surrounding program
-        idx, = jnp.nonzero(out.row_valid, size=comp_cap,
-                           fill_value=cap - 1)
+        idx = first_true_indices(out.row_valid, comp_cap, cap - 1)
         rv = jnp.arange(comp_cap) < live
         cols = {n: Column(c.data[idx], c.mask[idx] & rv, c.type,
                           c.dictionary)

@@ -19,13 +19,18 @@ CVal = Tuple[jnp.ndarray, jnp.ndarray]
 
 
 # ---------------------------------------------------------------------------
-# Platform-specialized primitives. XLA:TPU has a fast native sort HLO
-# and vectorized binary search, but scatter is serialized; XLA:CPU is
-# the mirror image — its sort lowering runs ~600ns/element, variadic
-# payloads multiply that, and searchsorted lowers to a per-slot scan
-# loop, while cumsum/scatter/gather are fast. Kernels compile per
-# backend, so the fork is decided at trace time and each backend sees
-# only its fast path.
+# Platform-specialized primitives. Kernels compile per backend, so a
+# fork is decided at trace time and each backend sees only its side.
+# What is known of the two sides: XLA:CPU's sort lowering runs
+# ~600ns/element, variadic payloads multiply that, and searchsorted
+# lowers to a per-slot scan loop, while cumsum/scatter/gather are fast
+# there. On XLA:TPU sorts and whole-batch 1-D scans are the COMPILE
+# wall and widths over 32 bits multiply it, a sort runs in
+# milliseconds, and a gather or scatter of 1M rows costs tens of
+# milliseconds (PERF.md, PR 22); most TPU sides have no run time on
+# record yet. Tier-1 runs on the CPU, so tests/test_tpu_compile.py
+# steers each fork to its TPU side and compiles it for a described
+# v5e.
 #
 # NOTE on host callbacks: routing these through jax.pure_callback to
 # numpy (np.argsort is ~4x XLA:CPU's sort) DEADLOCKS under the
@@ -97,34 +102,178 @@ def search_iters(max_span: int) -> int:
     return int(math.ceil(math.log2(max(int(max_span), 2)))) + 1
 
 
+#: block width of the two-level prefix sum: XLA:TPU lowers a 1-D
+#: cumsum over a whole batch to a deep reduce-window tree that takes
+#: the compiler tens of seconds (minutes for 64-bit); a scan along the
+#: minor axis of a [n / 1024, 1024] view compiles in about a second.
+_SCAN_BLOCK = 1024
+
+
+def prefix_sum(x: jnp.ndarray, dtype=jnp.int32) -> jnp.ndarray:
+    """Inclusive prefix sum of a 1-D integer or boolean array as a
+    blocked two-level scan: scan inside blocks, scan the block totals,
+    add. Exact — integer addition is associative, wrapping included —
+    so it equals `jnp.cumsum(x, dtype=dtype)` bit for bit. Row counts,
+    ranks, group ids and offsets fit int32 at any batch size (the
+    default); pass int64 for running sums of 64-bit values. Floats
+    must not come here: re-associating a float sum changes it."""
+    assert not jnp.issubdtype(x.dtype, jnp.floating), x.dtype
+    x = x.astype(dtype)
+    n = x.shape[0]
+    if n <= _SCAN_BLOCK:
+        return jnp.cumsum(x)
+    pad = -n % _SCAN_BLOCK
+    inner = jnp.cumsum(
+        jnp.pad(x, (0, pad)).reshape(-1, _SCAN_BLOCK), axis=1)
+    totals = inner[:, -1]
+    offsets = prefix_sum(totals, dtype) - totals
+    return (inner + offsets[:, None]).reshape(-1)[:n]
+
+
+def first_true_indices(flags: jnp.ndarray, size: int,
+                       fill_value: int) -> jnp.ndarray:
+    """Indices of the first `size` True entries, ascending, padded
+    with `fill_value` — `jnp.nonzero(flags, size=, fill_value=)[0]`
+    as a prefix sum plus a binary search for each output slot (the
+    stock lowering scans and scatters over the whole input)."""
+    rank = prefix_sum(flags)
+    slots = jnp.arange(size, dtype=jnp.int32)
+    idx = fast_searchsorted(rank, slots + 1, side="left")
+    return jnp.where(slots < rank[-1], idx.astype(jnp.int32),
+                     jnp.int32(fill_value))
+
+
+def _narrow_sort_key(a: jnp.ndarray) -> List[jnp.ndarray]:
+    """Order-preserving 32-bit operands for one sort key. The TPU has
+    no 64-bit lanes: a 64-bit operand inside a sort comparator costs
+    the compiler 2.5-3x and an emulated f64 8-15x (rehearsal compiles,
+    CHANGES.md PR 22). An int64 splits into (signed high word,
+    unsigned low word); an f64 goes through its totalOrder bit pattern
+    (float64_order_key). (Booleans are packed by lex_perm.)"""
+    if a.dtype == jnp.float64:
+        a = float64_order_key(a)
+    if a.dtype in (jnp.int64, jnp.uint64):
+        flip = jnp.uint32(1 << 31)
+        hi = (a >> 32).astype(jnp.uint32)
+        if a.dtype == jnp.uint64:
+            hi = hi ^ flip
+        lo = a.astype(jnp.uint32) ^ flip
+        return [jax.lax.bitcast_convert_type(hi, jnp.int32),
+                jax.lax.bitcast_convert_type(lo, jnp.int32)]
+    return [a]
+
+
 def lex_perm(sort_ops: Sequence[jnp.ndarray]) -> jnp.ndarray:
-    """Stable permutation ordering rows by `sort_ops` (most-significant
-    first): one lax.sort carrying only iota (payloads then move by
-    gather — on CPU ~2x cheaper than riding them through the variadic
-    sorting network)."""
+    """Stable permutation (int32) ordering rows by `sort_ops`
+    (most-significant first): ONE lax.sort over 32-bit operands that
+    carries only an int32 iota. The iota is the last key, which makes
+    the order total, so the sort itself need not be stable. Payloads
+    then move by gather. The TPU compiler's time grows faster than
+    linearly in the number of KEY lanes (15 / 28 / 51 / 75 s for 1-4
+    int32 keys at 64k rows, rehearsal compiles in CHANGES.md PR 22),
+    so runs of adjacent boolean operands (~valid, null rank) share
+    one lane."""
     n = sort_ops[0].shape[0]
-    out = jax.lax.sort(tuple(sort_ops) + (jnp.arange(n),),
-                       num_keys=len(sort_ops), is_stable=True)
-    return out[-1]
+    ops: List[jnp.ndarray] = []
+    flags = None  # packed run of adjacent bool operands
+    for a in sort_ops:
+        if a.dtype == jnp.bool_:
+            b = a.astype(jnp.int32)
+            flags = b if flags is None else flags * 2 + b
+            continue
+        if flags is not None:
+            ops.append(flags)
+            flags = None
+        ops.extend(_narrow_sort_key(a))
+    if flags is not None:
+        ops.append(flags)
+    ops.append(jnp.arange(n, dtype=jnp.int32))
+    return jax.lax.sort(tuple(ops), num_keys=len(ops),
+                        is_stable=False)[-1]
 
 
 def stable_argsort(a: jnp.ndarray) -> jnp.ndarray:
     """Single-key stable argsort (traceable; see NOTE above)."""
-    return jnp.argsort(a, stable=True)
+    return lex_perm([a])
 
 
 def partition_perm(valid: jnp.ndarray) -> jnp.ndarray:
-    """Stable valid-rows-first permutation. Equivalent to
-    argsort(~valid) but built from two cumsums + one scatter — on CPU
-    the bool argsort costs ~600ms per 1M rows, the scatter form ~5ms.
-    TPU keeps the argsort (scatter is the slow path there)."""
-    if not cpu_backend():
-        return jnp.argsort(~valid, stable=True)
+    """Stable valid-rows-first permutation (int32): equivalent to
+    argsort(~valid), built from two prefix sums + one scatter of
+    unique, nearly sorted positions. On CPU the bool argsort costs
+    ~600ms per 1M rows, this form ~5ms; on a v5e the sort form ran
+    1.9 ms but took the compiler 13 s where this takes 6.0 ms and 1 s
+    (my chip run, PR 22) — and the per-column gathers that follow
+    either form cost 17 ms each there."""
     n = valid.shape[0]
-    nv = jnp.sum(valid)
-    pos = jnp.where(valid, jnp.cumsum(valid) - 1,
-                    nv + jnp.cumsum(~valid) - 1)
-    return jnp.zeros(n, jnp.int64).at[pos].set(jnp.arange(n))
+    nv = jnp.sum(valid, dtype=jnp.int32)
+    pos = jnp.where(valid, prefix_sum(valid) - 1,
+                    nv + prefix_sum(~valid) - 1)
+    return jnp.zeros(n, jnp.int32).at[pos].set(
+        jnp.arange(n, dtype=jnp.int32), unique_indices=True)
+
+
+_F64_EXP_STEPS = (512, 256, 128, 64, 32, 16, 8, 4, 2, 1)
+
+
+def float64_bits(x: jnp.ndarray) -> jnp.ndarray:
+    """IEEE-754 binary64 bit pattern of `x` as int64, by ARITHMETIC.
+    XLA:TPU refuses every bitcast *from* f64 (its X64 rewriter keeps a
+    double as two f32 halves), so the pattern is rebuilt from exact
+    power-of-two scalings: normalize |x| into [1, 2) while counting
+    the exponent, read the 53-bit significand with a convert. Equal to
+    `bitcast_convert_type(x, int64)` for every normal value, both
+    zeros and both infinities; every NaN maps to the one canonical
+    quiet pattern, and a subnormal reads as the backend's arithmetic
+    reads it (XLA:CPU flushes it to a signed zero, exactly as its
+    `==` does). This is the ONE f64 -> integer key
+    function: hash64/hash64b, expr's $hash and the merge kernel's
+    total order all call it, so both sides of any hash compare agree."""
+    x = x.astype(jnp.float64)
+    a = jnp.abs(x)
+    e = jnp.zeros(x.shape, jnp.int32)
+    for k in _F64_EXP_STEPS:                 # a >= 2: scale down
+        big = a >= 2.0 ** k
+        a = jnp.where(big, a * 2.0 ** -k, a)
+        e = jnp.where(big, e + k, e)
+    for k in (512,) + _F64_EXP_STEPS:        # a < 1: scale up
+        small = a < 2.0 ** (1 - k)
+        a = jnp.where(small, a * 2.0 ** k, a)
+        e = jnp.where(small, e - k, e)
+    mant = (a * 2.0 ** 52).astype(jnp.int64)         # [2^52, 2^53) or 0
+    normal = ((e + 1023).astype(jnp.int64) << 52) | (mant - (1 << 52))
+    subnormal = mant >> jnp.clip(-1022 - e, 0, 63).astype(jnp.int64)
+    bits = jnp.where(e >= -1022, normal, subnormal)
+    # a backend whose doubles have a narrower exponent range than
+    # binary64 (the TPU's) stops scaling a zero early
+    bits = jnp.where(a == 0, jnp.int64(0), bits)
+    bits = jnp.where(jnp.isinf(x), jnp.int64(0x7FF0 << 48), bits)
+    # the sign survives the narrowing convert (also for -0.0 and
+    # underflow), and f32 bitcasts are native on every backend
+    sign = jax.lax.bitcast_convert_type(
+        x.astype(jnp.float32), jnp.uint32) >> 31
+    bits = bits | (sign.astype(jnp.int64) << 63)
+    return jnp.where(jnp.isnan(x), jnp.int64(0x7FF8 << 48), bits)
+
+
+def float64_order_key(x: jnp.ndarray) -> jnp.ndarray:
+    """int64 whose signed order is lax.sort's order of the f64 `x`:
+    IEEE totalOrder with lax.sort's own canonicalization (-0.0 equals
+    0.0; every NaN is the one positive NaN, last)."""
+    b = float64_bits(jnp.where(x == 0, 0.0, x))
+    # negative floats order by descending magnitude
+    return jnp.where(b < 0, ~b ^ jnp.int64(-1 << 63), b)
+
+
+def _hash_lanes(data: jnp.ndarray, mask: jnp.ndarray,
+                null_lane: int) -> jnp.ndarray:
+    """Key column -> uint64 lanes for the avalanche mixers; NULL takes
+    a fixed lane."""
+    if data.dtype in (jnp.float32, jnp.float64):
+        x = float64_bits(data)
+    else:
+        x = data.astype(jnp.int64)
+    return jnp.where(mask, x, jnp.int64(null_lane)).astype(jnp.uint64)
 
 
 def hash64(data: jnp.ndarray, mask: jnp.ndarray) -> jnp.ndarray:
@@ -133,12 +282,7 @@ def hash64(data: jnp.ndarray, mask: jnp.ndarray) -> jnp.ndarray:
     shift sign-extends and biases every high bit toward the sign —
     harmless for low-bit bucketing, fatal for anything reading the top
     bits (HLL rho, spill partitioning's h >> 32)."""
-    if data.dtype in (jnp.float32, jnp.float64):
-        x = jax.lax.bitcast_convert_type(data.astype(jnp.float64), jnp.int64)
-    else:
-        x = data.astype(jnp.int64)
-    x = jnp.where(mask, x, jnp.int64(-0x61C8864680B583EB))
-    x = x.astype(jnp.uint64)
+    x = _hash_lanes(data, mask, -0x61C8864680B583EB)
     x = (x ^ (x >> 30)) * jnp.uint64(0xBF58476D1CE4E5B9)
     x = (x ^ (x >> 27)) * jnp.uint64(0x94D049BB133111EB)
     x = x ^ (x >> 31)
@@ -152,12 +296,7 @@ def hash64b(data: jnp.ndarray, mask: jnp.ndarray) -> jnp.ndarray:
     search hash already matches is confirmed by comparing this hash
     instead of gathering every key column (see docs/JOIN_KERNEL.md
     for the collision argument)."""
-    if data.dtype in (jnp.float32, jnp.float64):
-        x = jax.lax.bitcast_convert_type(data.astype(jnp.float64), jnp.int64)
-    else:
-        x = data.astype(jnp.int64)
-    x = jnp.where(mask, x, jnp.int64(0x2545F4914F6CDD1D))
-    x = x.astype(jnp.uint64)
+    x = _hash_lanes(data, mask, 0x2545F4914F6CDD1D)
     x = (x ^ (x >> 33)) * jnp.uint64(0xFF51AFD7ED558CCD)
     x = (x ^ (x >> 33)) * jnp.uint64(0xC4CEB9FE1A85EC53)
     x = x ^ (x >> 33)
@@ -186,62 +325,18 @@ def row_hash2(cols: Sequence[CVal]) -> jnp.ndarray:
     return h
 
 
-def lex_order(keys: Sequence[CVal],
-              descending: Optional[Sequence[bool]] = None,
-              nulls_first: Optional[Sequence[bool]] = None,
-              valid: Optional[jnp.ndarray] = None) -> jnp.ndarray:
-    """Permutation sorting rows by keys lexicographically.
-
-    Implemented as iterated stable argsorts from least- to most-significant
-    key (each lowers to XLA's stable sort). Invalid rows (valid=False) sort
-    to the end regardless of key. SQL default: NULLS LAST ascending.
-    """
-    n = keys[0][0].shape[0] if keys else (valid.shape[0] if valid is not None else 0)
-    perm = jnp.arange(n)
-    desc = descending or [False] * len(keys)
-    nf = nulls_first or [False] * len(keys)
-    for (data, mask), d, nfirst in reversed(list(zip(keys, desc, nf))):
-        key = data[perm]
-        kmask = mask[perm]
-        if d:
-            sort_val = _negate_for_desc(key)
-        else:
-            sort_val = key
-        # canonicalize NULLs before the value sort: masked rows carry
-        # arbitrary payloads, and sorting by them would scatter the
-        # null block and destroy the contiguity of less-significant
-        # keys within it (the nulls-first/last pass below then moves
-        # one cohesive block, stably)
-        zero = jnp.zeros((), sort_val.dtype)
-        sort_val = jnp.where(kmask, sort_val, zero)
-        order = jnp.argsort(sort_val, stable=True)
-        perm = perm[order]
-        # second stable pass moves NULLs to front/back without disturbing
-        # the value order within the null/non-null partitions
-        kmask = mask[perm]
-        # argsort(bool): False first — nulls_first sorts by kmask (nulls
-        # are False), nulls_last by ~kmask
-        order = jnp.argsort(kmask if nfirst else ~kmask, stable=True)
-        perm = perm[order]
-    if valid is not None:
-        order = jnp.argsort(~valid[perm], stable=True)
-        perm = perm[order]
-    return perm
-
-
 def sort_rows(keys: Sequence[CVal],
               descending: Optional[Sequence[bool]] = None,
               nulls_first: Optional[Sequence[bool]] = None,
               valid: Optional[jnp.ndarray] = None,
               payloads: Sequence[jnp.ndarray] = ()):
-    """Lexicographic sort carrying payloads through ONE `lax.sort`.
-
-    The TPU-critical difference from `lex_order` + gathers: a single
-    variadic sort HLO moves keys AND payloads through the sorting
-    network together, where the argsort+gather formulation pays one
-    full sort per key plus one random gather per carried array (each
-    ~0.8s per 1M rows measured on v5e — the dominant cost of the old
-    sort-based aggregation tier).
+    """Lexicographic sort of rows: one `lex_perm` over the keys, then
+    one gather per carried array. (Riding keys and payloads through a
+    single variadic lax.sort instead costs the TPU compiler minutes:
+    on a v5e a 5-payload int64 sort of 1M rows compiled in 96 s and
+    ran in 6 ms, this form compiled in 25 s and ran in 82 ms — my chip
+    run, PR 22, PERF.md. The gathers are the price of a cold start
+    that fits a client's timeout.)
 
     Sort operands per key are (null_rank, canonical_value) so SQL
     null ordering and NULL==NULL grouping hold; `valid=False` rows sort
@@ -256,27 +351,12 @@ def sort_rows(keys: Sequence[CVal],
         sort_ops.append(mask if nfirst else ~mask)
         sv = _negate_for_desc(data) if d else data
         sort_ops.append(jnp.where(mask, sv, jnp.zeros((), sv.dtype)))
-    payload_ops: List[jnp.ndarray] = []
-    for data, mask in keys:
-        payload_ops.extend((data, mask))
-    payload_ops.extend(payloads)
     if not sort_ops:
         return list(keys), valid, list(payloads)
-    if cpu_backend():
-        # host lexsort + gathers: XLA:CPU's variadic sort moves every
-        # payload through a ~600ns/element sorting network; numpy's
-        # permutation + per-array gathers are ~4x faster at 1M rows
-        perm = lex_perm(sort_ops)
-        tail = [p[perm] for p in payload_ops]
-        svalid = None if valid is None else valid[perm]
-    else:
-        out = jax.lax.sort(tuple(sort_ops) + tuple(payload_ops),
-                           num_keys=len(sort_ops), is_stable=True)
-        tail = list(out[len(sort_ops):])
-        svalid = None if valid is None else ~out[0]
-    skeys = [(tail[2 * i], tail[2 * i + 1]) for i in range(len(keys))]
-    spay = list(tail[2 * len(keys):])
-    return skeys, svalid, spay
+    perm = lex_perm(sort_ops)
+    skeys = [(d[perm], m[perm]) for d, m in keys]
+    svalid = None if valid is None else valid[perm]
+    return skeys, svalid, [p[perm] for p in payloads]
 
 
 def _negate_for_desc(key: jnp.ndarray) -> jnp.ndarray:

@@ -96,8 +96,8 @@ VERIFY_MODES = ("hash", "full")
 class BuildTable:
     """Sorted-by-hash build side, ready for probing. A pytree whose
     AUX DATA carries the static search/layout parameters.
-    `batch` rows are IN sorted-hash order (the variadic build sort
-    carries every column as payload), so a probe candidate at sorted
+    `batch` rows are IN sorted-hash order (every column follows the
+    build's hash permutation), so a probe candidate at sorted
     slot s reads batch row s directly — no index indirection."""
     sorted_hash: jnp.ndarray          # [n] int64, invalid rows at +inf end
     hash2: jnp.ndarray                # [n] int64 second hash (verify)
@@ -184,24 +184,14 @@ def _hash_batch(batch: Batch, key_names: Tuple[str, ...]):
 
 @functools.partial(jax.jit, static_argnums=(1, 2))
 def _build_sorted(batch: Batch, key_names: Tuple[str, ...], k: int):
-    """Device build: hash keys, sort ROWS by hash in one variadic sort
-    (columns ride as payloads — no argsort + per-column gather), then
-    derive the radix metadata from the sorted hashes. Returns the
-    BuildTable fields plus (max bucket span, max valid run length) for
-    the host's static search-depth/layout choice."""
+    """Device build: hash keys, order the rows by hash (one
+    permutation, one gather per column), then derive the radix
+    metadata from the sorted hashes. Returns the BuildTable fields
+    plus (max bucket span, max valid run length) for the host's static
+    search-depth/layout choice."""
     h, h2, valid = _hash_batch(batch, key_names)
-    payloads = [h2, batch.row_valid]
-    for n in batch.names:
-        payloads.extend(batch.columns[n].astuple())
-    out = jax.lax.sort((h,) + tuple(payloads), num_keys=1,
-                       is_stable=True)
-    sh = out[0]
-    cols = {}
-    for i, n in enumerate(batch.names):
-        c = batch.columns[n]
-        cols[n] = Column(out[3 + 2 * i], out[4 + 2 * i], c.type,
-                         c.dictionary)
-    sbatch = Batch(cols, out[2])
+    perm = common.lex_perm([h])
+    sh, sh2, sbatch = _build_apply_perm(batch, h, h2, perm)
     n = sh.shape[0]
     first_inv = jnp.searchsorted(sh, _H_INVALID, side="left")
     if k > 0:
@@ -224,7 +214,7 @@ def _build_sorted(batch: Batch, key_names: Tuple[str, ...], k: int):
     max_run = jnp.max(jnp.where(idx < first_inv,
                                 jnp.minimum(run_end, first_inv) - idx,
                                 0))
-    return sh, out[1], part_starts, run_len, jnp.sum(valid), sbatch, \
+    return sh, sh2, part_starts, run_len, jnp.sum(valid), sbatch, \
         jnp.stack([max_span.astype(jnp.int64),
                    max_run.astype(jnp.int64)])
 
@@ -256,7 +246,7 @@ def build_for_backend(batch: Batch, key_names: Tuple[str, ...],
     step is legal — pure_callback inside jit deadlocks against the
     driver's blocking reads, see ops/common.py), and the bucket
     offsets/run lengths are linear numpy passes. On TPU: the
-    one-dispatch variadic sort plus one tiny fetch (max bucket span +
+    one-dispatch device build plus one tiny fetch (max bucket span +
     max run length) — legal here for the same operator-level reason.
 
     `radix_bits` overrides the size-derived k (0 forces the
@@ -620,7 +610,7 @@ def _expand_general(table, probe, key_names, lo, counts, out_capacity,
     if left_join:
         emit = jnp.where(probe.row_valid & (counts == 0), 1, counts)
         emit = jnp.where(probe.row_valid, emit, 0)
-    cum = jnp.cumsum(emit) - emit  # exclusive prefix
+    cum = common.prefix_sum(emit, emit.dtype) - emit  # exclusive prefix
     total = cum[-1] + emit[-1] if emit.shape[0] else jnp.asarray(0)
 
     slots = jnp.arange(out_capacity)
@@ -631,7 +621,7 @@ def _expand_general(table, probe, key_names, lo, counts, out_capacity,
     if common.cpu_backend():
         heads = jnp.zeros(out_capacity + 1, jnp.int64).at[
             jnp.clip(cum, 0, out_capacity)].add(1, mode="drop")
-        pid = jnp.cumsum(heads[:out_capacity]) - 1
+        pid = common.prefix_sum(heads[:out_capacity]) - 1
     else:
         pid = common.fast_searchsorted(cum, slots, side="right") - 1
     pid = jnp.clip(pid, 0, emit.shape[0] - 1)
@@ -954,8 +944,8 @@ def _outer_point(cap, variant):
 
 register_contract(KernelContract(
     family="join_build", module=__name__, build=_build_point,
-    notes="device variadic-sort build (the TPU path; traceable on "
-          "every backend)"))
+    notes="device build: order by hash + gathers (the TPU path; "
+          "traceable on every backend)"))
 register_contract(KernelContract(
     family="join_build", module=__name__,
     build=lambda cap, v: _build_point(cap, {"entry": "hash"}),

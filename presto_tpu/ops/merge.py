@@ -23,23 +23,25 @@ import jax
 import jax.numpy as jnp
 
 from presto_tpu.batch import Batch, Column
-from presto_tpu.ops.common import _negate_for_desc
+from presto_tpu.ops.common import _negate_for_desc, float64_order_key
 
 CVal = Tuple[jnp.ndarray, jnp.ndarray]
 
 
 def _total_order(v: jnp.ndarray) -> jnp.ndarray:
     """Map a sort operand to an integer with the SAME order lax.sort
-    uses. Floats get the sign-flip bitcast that realizes IEEE
-    totalOrder (-NaN < -inf < ... < +inf < +NaN) as unsigned integer
-    order — a plain IEEE `<`/`==` would treat NaN keys as unordered,
-    collapsing the merge's rank arithmetic into colliding scatter
-    slots (dropped + duplicated rows)."""
+    uses. Floats get the sign-flip of their bit pattern that realizes
+    IEEE totalOrder (-inf < ... < +inf < NaN) as integer order — a
+    plain IEEE `<`/`==` would treat NaN keys as unordered, collapsing
+    the merge's rank arithmetic into colliding scatter slots (dropped
+    + duplicated rows). lax.sort compares -0.0 equal to 0.0 and every
+    NaN as the one positive NaN; so does this key."""
     if v.dtype == jnp.float64:
-        u = jax.lax.bitcast_convert_type(v, jnp.uint64)
-        top = jnp.uint64(1) << 63
-        return jnp.where(u & top != 0, ~u, u | top)
+        # arithmetic, not a bitcast: XLA:TPU refuses bitcasts from f64
+        return float64_order_key(v)
     if v.dtype == jnp.float32:
+        v = jnp.where(v == 0, jnp.float32(0), v)
+        v = jnp.where(jnp.isnan(v), jnp.float32(jnp.nan), v)
         u = jax.lax.bitcast_convert_type(v, jnp.uint32)
         top = jnp.uint32(1) << 31
         return jnp.where(u & top != 0, ~u, u | top)

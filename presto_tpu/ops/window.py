@@ -172,7 +172,7 @@ def _window_kernel_jit(batch: Batch,
     part_cols = [batch.columns[n].astuple() for n in part_names]
     order_cols = [batch.columns[n].astuple() for n in order_names]
 
-    # ONE variadic sort carries the referenced argument columns and a
+    # ONE sort_rows call carries the referenced argument columns and a
     # row-index iota; results return to input order with a second sort
     # keyed on that iota (a sort, not the scatter-lowered inverse
     # permutation — scatters serialize on TPU)

@@ -34,14 +34,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# jax.shard_map graduated out of jax.experimental in newer releases;
-# support both spellings so the engine runs on the container's pinned
-# jax as well as current ones.
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:  # pragma: no cover - depends on installed jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from presto_tpu.batch import Batch, Column, bucket_capacity
 from presto_tpu.ops import common
 from presto_tpu.parallel.mesh import worker_axis
@@ -126,7 +118,7 @@ def _bucketize(dest: jnp.ndarray, valid: jnp.ndarray, n_parts: int,
     """
     rows = dest.shape[0]
     dest = jnp.where(valid, dest, n_parts)  # invalid -> dropped bucket
-    order = jnp.argsort(dest, stable=True)
+    order = common.stable_argsort(dest)
     sdest = dest[order]
     # offset of each bucket's first row among the sorted rows
     counts = jax.ops.segment_sum(jnp.ones_like(sdest), sdest,
@@ -180,7 +172,7 @@ def hash_repartition(sb: ShardedBatch, key_names: Sequence[str]
 
     body = functools.partial(_shuffle_core, w, axis)
     spec = P(axis)
-    fn = _shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(spec,) * 5,
         out_specs=(spec, spec, spec))
@@ -221,7 +213,7 @@ def _wave_body(n_parts: int, axis: str, row_valid, key_datas,
     their KernelContract trace points."""
     r_datas, r_masks, valid = _shuffle_core(
         n_parts, axis, row_valid, key_datas, key_masks, datas, masks)
-    order = jnp.argsort(~valid, stable=True)
+    order = common.partition_perm(valid)
     out_datas = tuple(f[order] for f in r_datas)
     out_masks = tuple(f[order] for f in r_masks)
     out_valid = valid[order]
@@ -238,7 +230,7 @@ def _wave_program(mesh: Mesh, axis: str, w: int, n_keys: int,
     # the lru entry holds the instrumented wrapper, so the warm jit
     # cache (and with it the zero-new-kernels guarantee for the second
     # same-bucket wave) travels with the cache hit
-    return instrument_kernel(jax.jit(_shard_map(
+    return instrument_kernel(jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(spec,) * 5,
         out_specs=(spec, spec, spec, spec))), "spmd_shuffle")
@@ -321,7 +313,7 @@ def _chained_wave_program(mesh: Mesh, axis: str, w: int,
 
     spec = P(axis)
     from presto_tpu.telemetry.kernels import instrument_kernel
-    fn = instrument_kernel(jax.jit(_shard_map(
+    fn = instrument_kernel(jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(spec, spec, spec, P()),
         out_specs=(spec, spec, spec, spec))), "spmd_fragment")
@@ -487,7 +479,7 @@ def _spmd_shuffle_point(cap, variant):
         datas = tuple(batch.columns[n].data for n in names)
         masks = tuple(batch.columns[n].mask for n in names)
         body = functools.partial(_wave_body, w, worker_axis)
-        sm = _shard_map(body, mesh=mesh, in_specs=(spec,) * 5,
+        sm = jax.shard_map(body, mesh=mesh, in_specs=(spec,) * 5,
                         out_specs=(spec,) * 4)
         out_datas, out_masks, out_valid, count = sm(
             batch.row_valid, (datas[0],), (masks[0],), datas, masks)
@@ -544,7 +536,7 @@ def _spmd_fragment_point(cap, variant):
             return _wave_body(w, worker_axis, out.row_valid, kd, km,
                               o_datas, o_masks)
 
-        sm = _shard_map(body, mesh=mesh, in_specs=(spec,) * 3,
+        sm = jax.shard_map(body, mesh=mesh, in_specs=(spec,) * 3,
                         out_specs=(spec,) * 4)
         datas = tuple(batch.columns[n].data for n in names)
         masks = tuple(batch.columns[n].mask for n in names)

@@ -333,14 +333,11 @@ class LocalRunner:
                  resource_groups=None,
                  history_dir: Optional[str] = None):
         # persistent XLA compilation cache: explicit arg wins, else
-        # the PRESTO_TPU_COMPILATION_CACHE_DIR env surface (both
-        # process-global — jax holds one cache dir)
+        # the one default rule (JAX_COMPILATION_CACHE_DIR, or
+        # <checkout>/.jax_cache off the CPU); process-global — jax
+        # holds one cache dir
         from presto_tpu.execution import compile_cache
-        if compilation_cache_dir is not None:
-            compile_cache.configure_compilation_cache(
-                compilation_cache_dir)
-        else:
-            compile_cache.configure_from_env()
+        compile_cache.configure(compilation_cache_dir)
         # history-based optimization store (presto_tpu/history): same
         # surface shape as the compile cache — explicit arg wins, else
         # PRESTO_TPU_HISTORY_DIR; both process-global. A restarted

@@ -200,11 +200,7 @@ class Coordinator(Node):
         # persistent XLA cache dir (arg > env > unset) and an optional
         # warmup statement list replayed at start() BEFORE the server
         # takes traffic, so restart-warm serving compiles nothing
-        if compilation_cache_dir is not None:
-            compile_cache.configure_compilation_cache(
-                compilation_cache_dir)
-        else:
-            compile_cache.configure_from_env()
+        compile_cache.configure(compilation_cache_dir)
         if prewarm_sql is None:
             prewarm_sql = compile_cache.parse_prewarm_sql(
                 os.environ.get(compile_cache.ENV_PREWARM_SQL))

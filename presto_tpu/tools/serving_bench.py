@@ -561,6 +561,13 @@ def _run_worker_churn_phase(schema: str, work: List[Tuple[str, str]],
     from presto_tpu.server.coordinator import Coordinator
     from presto_tpu.server.node import http_get
     from presto_tpu.telemetry.metrics import METRICS
+    if _backend() != "cpu":
+        # one process per chip: this parent has run queries and holds
+        # the device, so a worker subprocess that needs it would fail
+        # or hang. The HTTP worker fleet is a CPU-only topology.
+        raise RuntimeError(
+            "the worker-churn phase starts worker subprocesses and "
+            f"runs on the CPU backend only (this is {_backend()})")
     workers = [list(_spawn_churn_worker()) for _ in range(n_workers)]
     urls = [w[1] for w in workers]
     coord = Coordinator(
@@ -901,57 +908,12 @@ def run_serving_bench(clients: int = 4, schema: str = "sf0_1",
                       host: str = "127.0.0.1",
                       mesh_phase: bool = False,
                       mesh_rounds: int = 2) -> dict:
-    """Thin wrapper owning the auto-created compilation-cache dir:
-    a --restart-warm run without --cache-dir gets a tmpdir that is
-    removed (and unconfigured) when the bench finishes, success or
-    not — repeated CI runs must not accumulate populated XLA caches
-    under /tmp."""
-    auto_cache_dir = None
-    if restart_warm and cache_dir is None:
-        import tempfile
-        cache_dir = auto_cache_dir = tempfile.mkdtemp(
-            prefix="presto_tpu_xla_cache_")
-    try:
-        return _serving_bench(
-            clients=clients, schema=schema, mix=mix,
-            warm_rounds=warm_rounds,
-            flight_ab_rounds=flight_ab_rounds,
-            verify_off=verify_off,
-            chaos=chaos, chaos_rounds=chaos_rounds,
-            chaos_spec=chaos_spec, restart_warm=restart_warm,
-            cache_dir=cache_dir, fusion_report=fusion_report,
-            overload=overload, overload_rounds=overload_rounds,
-            overload_concurrency=overload_concurrency,
-            sanitize_phase=sanitize_phase,
-            history_phase=history_phase, worker_churn=worker_churn,
-            churn_workers=churn_workers, churn_rounds=churn_rounds,
-            churn_kills=churn_kills, churn_period_s=churn_period_s,
-            timeline_out=timeline_out,
-            assert_verdict=assert_verdict, host=host,
-            mesh_phase=mesh_phase, mesh_rounds=mesh_rounds)
-    finally:
-        if auto_cache_dir is not None:
-            import shutil
-            from presto_tpu.execution import compile_cache
-            compile_cache.configure_compilation_cache(None)
-            shutil.rmtree(auto_cache_dir, ignore_errors=True)
-
-
-def _serving_bench(clients: int, schema: str, mix: Sequence[str],
-                   warm_rounds: int, flight_ab_rounds: int,
-                   verify_off: bool, chaos: bool,
-                   chaos_rounds: int, chaos_spec: str,
-                   restart_warm: bool, cache_dir: Optional[str],
-                   fusion_report: bool, overload: bool,
-                   overload_rounds: int,
-                   overload_concurrency: Optional[int],
-                   sanitize_phase: bool, history_phase: bool,
-                   worker_churn: bool, churn_workers: int,
-                   churn_rounds: int, churn_kills: int,
-                   churn_period_s: float, timeline_out: Optional[str],
-                   assert_verdict: Optional[str],
-                   host: str, mesh_phase: bool = False,
-                   mesh_rounds: int = 2) -> dict:
+    """`cache_dir` is an explicit persistent-compilation-cache
+    override; without it the coordinators follow the one rule of
+    execution/compile_cache.configure
+    (JAX_COMPILATION_CACHE_DIR, else <checkout>/.jax_cache off the
+    CPU). A --restart-warm run therefore needs --cache-dir on a CPU
+    backend, which does not default into the cache."""
     from presto_tpu.cache import get_cache_manager
     from presto_tpu.execution import compile_cache
     from presto_tpu.server.coordinator import Coordinator
@@ -1208,7 +1170,8 @@ def _serving_bench(clients: int, schema: str, mix: Sequence[str],
             "prewarm": coord2.prewarm_report,
             "qps_vs_warm": round(rw["qps"] / warm["qps"], 3)
             if warm.get("qps") else None,
-            "compilation_cache_dir": cache_dir,
+            "compilation_cache_dir": cache_dir
+            or coord2.prewarm_report.get("disk_cache_dir"),
         }
         if rw["fresh_compiles"] != 0:
             # the restart-warm CONTRACT: prewarm + the persistent

@@ -22,7 +22,7 @@ def test_configure_and_persist(tmp_path):
     from presto_tpu.execution import compile_cache
     from presto_tpu.runner.local import LocalRunner
     d = str(tmp_path / "xla")
-    assert compile_cache.configure_compilation_cache(d)
+    compile_cache.configure_compilation_cache(d)
     assert compile_cache.configured_cache_dir() == d
     r = LocalRunner("memory", "default", properties=dict(_NO_CACHES))
     r.execute("CREATE TABLE cc1 AS SELECT custkey ck1, acctbal cb1 "
@@ -34,11 +34,66 @@ def test_configure_and_persist(tmp_path):
     assert len(os.listdir(d)) > 0
 
 
+@pytest.mark.parametrize("env_dir,backend,want", [
+    # placed from outside: jax honors its own variable, no dir in code
+    ("/somewhere/else", "tpu", None),
+    ("/somewhere/else", "cpu", None),
+    # not placed: the checkout's .jax_cache — but never on a CPU backend
+    ("", "tpu", "default"),
+    ("", "cpu", None),
+])
+def test_default_cache_rule(monkeypatch, env_dir, backend, want):
+    """The one rule (compile_cache.configure): with
+    JAX_COMPILATION_CACHE_DIR set the program sets no directory in
+    code; unset, the cache is <checkout>/.jax_cache, except that a
+    CPU backend does not default into it."""
+    import jax
+    from presto_tpu.execution import compile_cache
+    if env_dir:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    monkeypatch.setattr(compile_cache, "_CONFIGURED_DIR", None)
+    set_dirs = []
+    monkeypatch.setattr(compile_cache, "configure_compilation_cache",
+                        set_dirs.append)
+    compile_cache.configure()
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    expect = [os.path.join(repo, ".jax_cache")] if want else []
+    assert set_dirs == expect
+
+
+def test_explicit_override_survives_default_rule(tmp_path, monkeypatch):
+    """A `compilation_cache_dir=` given to one runner is not undone by
+    the next runner built without it."""
+    import jax
+    from presto_tpu.execution import compile_cache
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    d = str(tmp_path / "xla")
+    compile_cache.configure_compilation_cache(d)
+    compile_cache.configure()
+    assert compile_cache.configured_cache_dir() == d
+    assert jax.config.jax_compilation_cache_dir == d
+
+
+def test_unusable_cache_dir_raises(tmp_path):
+    """A cache that cannot be configured is an error, not a silent
+    cold start."""
+    from presto_tpu.execution import compile_cache
+    blocker = tmp_path / "file"
+    blocker.write_text("x")
+    with pytest.raises(OSError):
+        compile_cache.configure_compilation_cache(
+            str(blocker / "sub"))
+
+
 def test_restart_then_prewarm_serves_without_compiles(tmp_path):
     from presto_tpu.execution import compile_cache
     from presto_tpu.runner.local import LocalRunner
     d = str(tmp_path / "xla")
-    assert compile_cache.configure_compilation_cache(d)
+    compile_cache.configure_compilation_cache(d)
     r = LocalRunner("memory", "default", properties=dict(_NO_CACHES))
     r.execute("CREATE TABLE cc2 AS SELECT custkey ck2, acctbal cb2 "
               "FROM tpch.tiny.customer LIMIT 64")

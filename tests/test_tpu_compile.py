@@ -1,0 +1,192 @@
+"""Compile guards: the main path's kernels must COMPILE for a TPU v5e.
+
+Tier-1 runs on the CPU, so every trace-time platform fork
+(ops/common.cpu_backend, ops/hashagg._use_searchsorted) only ever
+shows the suite its CPU side, and XLA:TPU refuses programs XLA:CPU
+accepts (every bitcast from f64, for one). These cases steer the forks
+to their TPU side with monkeypatch and AOT-compile each kernel for a
+DESCRIBED v5e:2x2 — no chip attached, nothing runs, no result or time
+is checked. A compile that passes here is not a chip run
+(`python chip_smoke.py` is).
+
+The topology is described inside the module-scoped fixture below and
+nowhere else: only one process may load the TPU's library, so nothing
+at import time, nothing in conftest.py, no child process, one file.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
+
+#: rows per batch of the chip smoke and the bench (`batch_rows`)
+BATCH = 1 << 20
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any reason it can't
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent
+    # cache but can never be read back without one: keep it off
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def tpu_forks(monkeypatch):
+    """Steer every trace-time platform fork to its TPU side."""
+    from presto_tpu.ops import common, hashagg
+    monkeypatch.setattr(common, "cpu_backend", lambda: False)
+    monkeypatch.setattr(hashagg, "_use_searchsorted", lambda: True)
+
+
+def _place(tree, sharding):
+    """Abstract twin of `tree` whose array leaves live on `sharding`."""
+    def leaf(x):
+        if hasattr(x, "shape") and hasattr(x, "dtype"):
+            return jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                        sharding=sharding)
+        return x
+    return jax.tree_util.tree_map(leaf, tree)
+
+
+def _compile(fn, args, sharding):
+    compiled = jax.jit(fn).lower(*_place(args, sharding)).compile()
+    assert compiled.memory_analysis().generated_code_size_in_bytes > 0
+    return compiled
+
+
+def _sds(n, dtype):
+    return jax.ShapeDtypeStruct((n,), dtype)
+
+
+@pytest.mark.parametrize("name", ["hash64", "hash64b"])
+def test_hash_of_double_key(one_chip, tpu_forks, name):
+    """GROUP BY / join / DISTINCT / shuffle on a DOUBLE key: the f64 ->
+    int64 key is arithmetic (common.float64_bits), not the bitcast
+    XLA:TPU's X64 rewriter refuses."""
+    from presto_tpu.ops import common
+    _compile(getattr(common, name),
+             (_sds(BATCH, jnp.float64), _sds(BATCH, jnp.bool_)),
+             one_chip)
+
+
+def test_expr_hash_of_double(one_chip, tpu_forks):
+    from presto_tpu.expr import compile as expr_compile
+    _compile(expr_compile._hash64,
+             (_sds(BATCH, jnp.float64), _sds(BATCH, jnp.bool_)),
+             one_chip)
+
+
+def test_merge_total_order_f64(one_chip, tpu_forks):
+    """The streaming merge of an ORDER BY on a DOUBLE."""
+    from presto_tpu.ops import merge
+    _compile(merge._total_order, (_sds(BATCH, jnp.float64),), one_chip)
+
+
+def test_sort_on_double_key(one_chip, tpu_forks):
+    """ORDER BY a DOUBLE, nulls last, over a live-row mask: the
+    comparator sees 32-bit lanes only (common._narrow_sort_key)."""
+    from presto_tpu.ops import common
+    n = 1 << 12  # the bucket a query's final ORDER BY lands in
+
+    def fn(d, m, v):
+        return common.sort_rows([(d, m)], descending=[True], valid=v,
+                                payloads=[d])
+    _compile(fn, (_sds(n, jnp.float64), _sds(n, jnp.bool_),
+                  _sds(n, jnp.bool_)), one_chip)
+
+
+def test_partition_perm_and_compaction(one_chip, tpu_forks):
+    from presto_tpu.ops import common
+
+    def fn(valid, col):
+        return col[common.partition_perm(valid)], \
+            common.first_true_indices(valid, BATCH // 4, BATCH - 1)
+    _compile(fn, (_sds(BATCH, jnp.bool_), _sds(BATCH, jnp.int64)),
+             one_chip)
+
+
+@pytest.mark.parametrize("reduce", ["sum", "min"])
+def test_slot_reduce_onehot(one_chip, tpu_forks, reduce):
+    """Q1's 12-slot direct aggregation: the TPU side is the one-hot
+    masked reduce, not segment_*."""
+    from presto_tpu.ops import hashagg
+    _compile(
+        lambda c, g: hashagg._slot_reduce(c, g, 12, reduce, jnp.float64),
+        (_sds(BATCH, jnp.float64), _sds(BATCH, jnp.int32)), one_chip)
+
+
+def test_q1_fused_step(one_chip, tpu_forks):
+    """__graft_entry__.entry(): the flagship Q1 filter + project +
+    grouped fold + finalize (took the TPU compiler 260 s before the
+    sort and scan operands were narrowed, ISSUE 22)."""
+    import __graft_entry__
+    fn, args = __graft_entry__.entry()
+    _compile(fn, args, one_chip)
+
+
+def test_sorted_aggregation_step(one_chip, tpu_forks):
+    """The high-cardinality (sort-based) aggregation Q3 runs: hash
+    sort, boundaries, prefix-sum group ids, and the TPU side of
+    _sorted_reduce / _first_rows."""
+    from presto_tpu.ops import hashagg
+    from presto_tpu.types import BIGINT, DOUBLE
+    n, cap = 1 << 14, 1 << 12  # the shapes Q3 traces at sf1
+    aggs = (hashagg.make_sum(DOUBLE, DOUBLE), hashagg.make_count(BIGINT))
+
+    def fn(valid, k1, k2, x):
+        true = jnp.ones_like(valid)
+        return hashagg.batch_aggregate(
+            valid, [(k1, true), (k2, true)], [x, None],
+            [valid, valid], aggs, cap)
+    _compile(fn, (_sds(n, jnp.bool_), _sds(n, jnp.int64),
+                  _sds(n, jnp.int32), _sds(n, jnp.float64)), one_chip)
+
+
+@pytest.mark.parametrize("family,cap", [("join_build", 1 << 16),
+                                        ("join_probe", BATCH)])
+def test_join_kernels(one_chip, tpu_forks, family, cap):
+    """The device join build (hash, order by hash, radix metadata) and
+    the inner probe, through their KernelContract trace points."""
+    from presto_tpu.analysis.contracts import contract_for
+    import presto_tpu.ops.join  # noqa: F401 — registers the contracts
+    point = contract_for(family)[0].build(cap, {})
+    _compile(point.fn, point.args, one_chip)
+
+
+@pytest.mark.parametrize("family", ["spmd_shuffle", "spmd_fragment"])
+def test_exchange_shard_map_on_four_chips(topo, tpu_forks, monkeypatch,
+                                          family):
+    """The hash shuffle as ONE program over the four described chips:
+    the compiler must keep the all_to_all, and every chip gets a
+    shard."""
+    from presto_tpu.analysis.contracts import contract_for
+    from presto_tpu.parallel import shuffle
+    from presto_tpu.parallel.mesh import worker_axis
+    mesh = Mesh(np.array(topo.devices[:4]), (worker_axis,))
+    monkeypatch.setattr(shuffle, "_contract_mesh", lambda: mesh)
+    point = contract_for(family)[0].build(1 << 16, {})
+    compiled = _compile(point.fn, point.args,
+                        NamedSharding(mesh, P(worker_axis)))
+    assert "all-to-all" in compiled.as_text()
