@@ -1,0 +1,4 @@
+def read(run):
+    if run.trace is None or not run.trace["window_s"]:
+        return None
+    return 100.0 * (1 - run.trace["busy_s"] / run.trace["window_s"])
