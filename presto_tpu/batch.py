@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from presto_tpu.telemetry import kernels as _kernels
 from presto_tpu.types import Type, VARCHAR, BOOLEAN, DOUBLE, BIGINT
 
 MIN_CAPACITY = 16
@@ -118,19 +119,21 @@ def operator_capacity(n: int, floor: int = MIN_CAPACITY) -> int:
     return max(floor, bucket_capacity(max(n, 1)))
 
 
-@functools.partial(jax.jit, static_argnums=(1,))
-# lint-ok: TS005 shape plumbing, deliberately not an engine kernel
-def _pad_batch(batch: "Batch", pad: int) -> "Batch":
+@functools.partial(_kernels.jit, family="pad", static_argnums=(1,))
+def _pad_batch_jit(batch: "Batch", pad: int) -> "Batch":
     """Append `pad` dead lanes (mask False, row_valid False, data 0)
-    to every column. One tiny fused kernel per (schema, pad) pair —
-    deliberately NOT instrumented as an engine kernel family: it is
-    shape plumbing, not operator work."""
+    to every column. One fused kernel per (schema, pad) pair, its own
+    family (`pad`): shape plumbing, but a tenth of the device's time
+    in the scan cells (PERF.md), so it is counted like any kernel."""
     cols = {
         n: Column(jnp.pad(c.data, (0, pad)), jnp.pad(c.mask, (0, pad)),
                   c.type, c.dictionary)
         for n, c in batch.columns.items()
     }
     return Batch(cols, jnp.pad(batch.row_valid, (0, pad)))
+
+
+_pad_batch = _kernels.instrument_kernel(_pad_batch_jit, "pad")
 
 
 def pad_for_kernel(batch: "Batch") -> "Batch":
@@ -434,7 +437,7 @@ def empty_batch(schema_cols: Sequence[Tuple],
     return Batch(cols, jnp.zeros(capacity, bool))
 
 
-@jax.jit
+@functools.partial(_kernels.jit, family="compact")
 def _compact_jit(batch: Batch) -> Batch:
     from presto_tpu.ops.common import partition_perm
     order = partition_perm(batch.row_valid)
@@ -446,7 +449,8 @@ def _compact_jit(batch: Batch) -> Batch:
     return Batch(cols, batch.row_valid[order])
 
 
-@functools.partial(jax.jit, static_argnums=(1,))
+@functools.partial(_kernels.jit, family="compact", part="shrink",
+                   static_argnums=(1,))
 def _compact_shrink_jit(batch: Batch, capacity: int) -> Batch:
     """Pack live rows into a SMALLER batch: indices of the first
     `capacity` live rows (bounded nonzero), then a capacity-sized
@@ -464,7 +468,7 @@ def _compact_shrink_jit(batch: Batch, capacity: int) -> Batch:
 
 # compile-vs-execute attribution for the compaction family (module-
 # level jits previously landed in "execute" via operator busy time)
-from presto_tpu.telemetry.kernels import instrument_kernel as _instr
+_instr = _kernels.instrument_kernel
 
 _compact = _instr(_compact_jit, "compact")
 _compact_shrink = _instr(_compact_shrink_jit, "compact",
@@ -495,6 +499,15 @@ def _compact_shrink_point(cap, variant):
         (b,), (rb,))
 
 
+def _pad_point(cap, variant):
+    b, rb = _abstract_batch(cap, _compact_contract_schema())
+    return TracePoint(
+        lambda batch: _pad_batch_jit(batch, 3 * cap), (b,), (rb,))
+
+
+_register_contract(KernelContract(
+    family="pad", module=__name__, build=_pad_point,
+    notes="pad_for_kernel's lift to the next power-of-four bucket"))
 _register_contract(KernelContract(
     family="compact", module=__name__, build=_compact_point))
 _register_contract(KernelContract(

@@ -39,6 +39,7 @@ import jax.numpy as jnp
 from presto_tpu import sanitize
 from presto_tpu.batch import Batch
 from presto_tpu.ops import common
+from presto_tpu.telemetry import kernels as _kernels
 
 #: Max distinct build keys carried as a set; more degrades to bounds
 #: only (reference: dynamic-filtering.max-distinct-values-per-driver).
@@ -174,7 +175,7 @@ def _ident(dtype):
     return info
 
 
-@jax.jit
+@functools.partial(_kernels.jit, family="dynamic_filter", part="bounds")
 def bounds_step(state, data, mask):
     """Fold one batch's column into running (min, max) IN THE KEY'S OWN
     DTYPE — no float widening, so int64 key domains stay exact.
@@ -199,7 +200,7 @@ def bounds_init(dtype):
     return (jnp.asarray(info.max, dtype), jnp.asarray(info.min, dtype))
 
 
-from presto_tpu.telemetry.kernels import instrument_kernel as _instr
+_instr = _kernels.instrument_kernel
 
 # compile-vs-execute attribution for the dynamic-filter family —
 # previously uninstrumented module-level jits whose compiles landed
@@ -207,7 +208,7 @@ from presto_tpu.telemetry.kernels import instrument_kernel as _instr
 bounds_step = _instr(bounds_step, "dynamic_filter")
 
 
-@jax.jit
+@functools.partial(_kernels.jit, family="dynamic_filter", part="distinct_set")
 def distinct_set(data, mask):
     """Bounded distinct set of a (merged) build key column: ONE sort +
     boundary dedupe, packed into DF_SET_MAX slots. Returns
@@ -238,7 +239,8 @@ def distinct_set(data, mask):
     return out, n, n > DF_SET_MAX
 
 
-@functools.partial(jax.jit, static_argnums=(1, 4))
+@functools.partial(_kernels.jit, family="dynamic_filter", part="apply",
+                   static_argnums=(1, 4))
 def apply_filter(batch: Batch, col: str, mn, mx, has_set: bool,
                  dset_vals=None, dset_count=None) -> Batch:
     """Narrow row_valid to rows whose key can possibly match the build

@@ -24,6 +24,7 @@ from presto_tpu.operators.base import (
     DriverContext, Operator, OperatorContext, OperatorFactory,
 )
 from presto_tpu.ops import hashagg
+from presto_tpu.telemetry import kernels as _kernels
 from presto_tpu.types import Type
 
 
@@ -53,8 +54,9 @@ class AggSpec:
 #: instrumented as its own kernel family (previously its compile time
 #: landed in busy as "execute" — the attribution gap flagged in
 #: CHANGES.md after the telemetry PR)
-_jit_merge = jax.jit(hashagg.merge_partials, static_argnums=(1, 2))
-from presto_tpu.telemetry.kernels import instrument_kernel as _instr
+_jit_merge = _kernels.jit(hashagg.merge_partials, "hashagg_merge",
+                          static_argnums=(1, 2))
+_instr = _kernels.instrument_kernel
 _merge_instr = _instr(_jit_merge, "hashagg_merge")
 
 
@@ -70,8 +72,9 @@ def merge_states(states, aggs, out_cap: int):
 _MERGE_FANIN = 8
 
 #: live-group count of a partial (consumed one round later, async)
-_jit_count = _instr(jax.jit(lambda valid: jnp.sum(valid)),
-                    "agg_count")
+_jit_count = _instr(
+    _kernels.jit(lambda valid: jnp.sum(valid), "agg_count"),
+    "agg_count")
 
 #: Smallest state capacity the shrink protocol packs down to. Keeps the
 #: compiled-shape set bounded (tiny partials all land on one bucket) and
@@ -79,7 +82,8 @@ _jit_count = _instr(jax.jit(lambda valid: jnp.sum(valid)),
 _SHRINK_FLOOR = 4096
 
 
-@functools.partial(jax.jit, static_argnums=(1,))
+@functools.partial(_kernels.jit, family="agg_shrink",
+                   static_argnums=(1,))
 def _shrink_state(st: "hashagg.GroupByState", cap: int):
     """Slice a PACKED sort-path state down to `cap` slots. Safe because
     _group_reduce lands live groups at the front (valid = slots < n);
@@ -162,13 +166,16 @@ def make_agg_step_kernel(key_exprs: Sequence[CompiledExpr],
         except TypeError:
             key = None
 
+    def _chained(batch: Batch):
+        """(the batch after the fused upstream chain, the chain's
+        compaction-overflow flag or None)."""
+        if pre is None:
+            return batch, None
+        if pre_compacted:
+            return pre(batch)
+        return pre(batch), None
+
     def _batch_parts(batch: Batch):
-        ovf = None
-        if pre is not None:
-            if pre_compacted:
-                batch, ovf = pre(batch)
-            else:
-                batch = pre(batch)
         env = {n: (c.data, c.mask) for n, c in batch.columns.items()}
         cap = batch.capacity
         key_cols = []
@@ -204,16 +211,23 @@ def make_agg_step_kernel(key_exprs: Sequence[CompiledExpr],
         # filter narrows it inside this trace, and groups must not
         # form from rows the chain filtered out
         return (batch.row_valid, key_cols, agg_inputs, agg_weights,
-                tuple(merge), ovf)
+                tuple(merge))
+
+    # a kernel with a fused upstream chain is a whole-fragment program:
+    # family `fragment`, device name `fragment_agg_step`
+    family, part = ("fragment", "agg_step") if pre is not None \
+        else ("agg_step", None)
 
     if domains is not None:
-        @jax.jit
+        @functools.partial(_kernels.jit, family=family, part=part)
         def kernel(state, batch: Batch):
-            row_valid, key_cols, agg_inputs, agg_weights, merge, \
-                ovf = _batch_parts(batch)
-            out = hashagg.direct_step(
-                state, row_valid, key_cols, domains, agg_inputs,
-                agg_weights, aggs, merge)
+            batch, ovf = _chained(batch)
+            with jax.named_scope("agg_step"):
+                row_valid, key_cols, agg_inputs, agg_weights, merge = \
+                    _batch_parts(batch)
+                out = hashagg.direct_step(
+                    state, row_valid, key_cols, domains, agg_inputs,
+                    agg_weights, aggs, merge)
             return (out, ovf) if pre_compacted else out
     else:
         # sort path: expression eval + per-batch compaction fused into
@@ -224,22 +238,21 @@ def make_agg_step_kernel(key_exprs: Sequence[CompiledExpr],
         group_fn = hashagg.presorted_aggregate if presorted \
             else hashagg.batch_aggregate
 
-        @functools.partial(jax.jit, static_argnums=(0,))
+        @functools.partial(_kernels.jit, family=family, part=part,
+                           static_argnums=(0,))
         def kernel(out_cap: int, batch: Batch):
-            row_valid, key_cols, agg_inputs, agg_weights, merge, \
-                ovf = _batch_parts(batch)
-            out = group_fn(
-                row_valid, key_cols, agg_inputs, agg_weights,
-                aggs, out_cap, merge)
+            batch, ovf = _chained(batch)
+            with jax.named_scope("agg_step"):
+                row_valid, key_cols, agg_inputs, agg_weights, merge = \
+                    _batch_parts(batch)
+                out = group_fn(
+                    row_valid, key_cols, agg_inputs, agg_weights,
+                    aggs, out_cap, merge)
             return (out, ovf) if pre_compacted else out
 
     # compile-vs-execute attribution rides the cached kernel (same
-    # contract as core's filter_project instrumentation); a kernel
-    # with a fused upstream chain is a whole-fragment program and
-    # reports under the `fragment` family
-    from presto_tpu.telemetry.kernels import instrument_kernel
-    kernel = instrument_kernel(
-        kernel, "fragment" if pre is not None else "agg_step")
+    # contract as core's filter_project instrumentation)
+    kernel = _kernels.instrument_kernel(kernel, family)
 
     if key is not None:
         _AGG_STEP_CACHE[key] = kernel
@@ -262,7 +275,7 @@ def make_agg_finalize_kernel(mode: str, key_names, key_types, key_dicts,
         _AGG_FIN_CACHE.move_to_end(key)
         return cached
 
-    @jax.jit
+    @functools.partial(_kernels.jit, family="agg_finalize")
     def fin(state):
         if domains is not None:
             f = hashagg.direct_intermediate if mode == "partial" \
@@ -275,8 +288,7 @@ def make_agg_finalize_kernel(mode: str, key_names, key_types, key_dicts,
         return hashagg.finalize(state, key_names, key_types, key_dicts,
                                 out_names, aggs)
 
-    from presto_tpu.telemetry.kernels import instrument_kernel
-    fin = instrument_kernel(fin, "agg_finalize")
+    fin = _kernels.instrument_kernel(fin, "agg_finalize")
 
     _AGG_FIN_CACHE[key] = fin
     while len(_AGG_FIN_CACHE) > _AGG_STEP_CACHE_MAX:
@@ -647,7 +659,8 @@ class AggregationOperator(Operator):
             self._host_spill = []
 
 
-@functools.partial(jax.jit, static_argnums=(2,))
+@functools.partial(_kernels.jit, family="agg_stream",
+                   static_argnums=(2,))
 def _stream_step_jit(carry: "hashagg.GroupByState",
                      partial: "hashagg.GroupByState", aggs):
     """One streaming-aggregation round, all arithmetic — NO re-grouping

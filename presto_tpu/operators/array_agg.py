@@ -35,6 +35,7 @@ from presto_tpu.operators.base import (
     DriverContext, Operator, OperatorContext, OperatorFactory,
 )
 from presto_tpu.ops import common
+from presto_tpu.telemetry import kernels as _kernels
 from presto_tpu.types import BIGINT, Type
 
 
@@ -62,7 +63,8 @@ class CollectSpec:
         self.mask = mask            # FILTER (WHERE ...)
 
 
-@functools.partial(jax.jit, static_argnums=(1, 2, 3, 4))
+@functools.partial(_kernels.jit, family="array_agg", part="collect",
+                   static_argnums=(1, 2, 3, 4))
 def _collect_kernel(batch: Batch, key_syms: Tuple[str, ...],
                     specs_meta: Tuple, out_cap: int, width: int):
     """(packed keys, per-spec [out_cap, W] blocks, lengths, overflow).
@@ -149,7 +151,7 @@ def _collect_kernel(batch: Batch, key_syms: Tuple[str, ...],
 
 # compile-vs-execute attribution for the array_agg/map_agg family —
 # previously an uninstrumented module-level jit
-from presto_tpu.telemetry.kernels import instrument_kernel as _instr
+_instr = _kernels.instrument_kernel
 
 _collect_kernel = _instr(_collect_kernel, "array_agg")
 
@@ -283,7 +285,8 @@ class ArrayAggOperatorFactory(OperatorFactory):
         kx = list(key_exprs)
         sp = list(specs)
 
-        @jax.jit
+        @functools.partial(_kernels.jit, family="array_agg",
+                           part="eval")
         def eval_kernel(batch: Batch) -> Batch:
             env = {n: (c.data, c.mask)
                    for n, c in batch.columns.items()}

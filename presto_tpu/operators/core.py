@@ -20,6 +20,7 @@ from presto_tpu.operators.base import (
     DriverContext, Operator, OperatorContext, OperatorFactory,
 )
 from presto_tpu.ops import sort as sort_ops
+from presto_tpu.telemetry import kernels as _kernels
 
 
 class SourceOperator(Operator):
@@ -183,14 +184,14 @@ def make_filter_project_kernel(
     from presto_tpu.operators.fused_fragment import (
         ChainStage, make_chain_body,
     )
-    kernel = jax.jit(make_chain_body(
-        [ChainStage(filter_expr, tuple(projections), input_dicts)]))
+    kernel = _kernels.jit(make_chain_body(
+        [ChainStage(filter_expr, tuple(projections), input_dicts)]),
+        "filter_project")
 
     # compile-vs-execute attribution travels WITH the cached kernel:
     # an LRU hit keeps its warm jit cache, so its calls report execute
     # only (telemetry/kernels.py)
-    from presto_tpu.telemetry.kernels import instrument_kernel
-    kernel = instrument_kernel(kernel, "filter_project")
+    kernel = _kernels.instrument_kernel(kernel, "filter_project")
 
     if key is not None:
         _FP_KERNEL_CACHE[key] = kernel

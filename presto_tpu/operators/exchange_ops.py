@@ -39,6 +39,7 @@ from presto_tpu.operators.base import (
 )
 from presto_tpu.ops import common
 from presto_tpu.parallel.shuffle import wave_repartition
+from presto_tpu.telemetry import kernels as _kernels
 
 
 def build_remap_tables(hash_dicts, key_dictionaries):
@@ -75,7 +76,8 @@ def partition_key_hash(batch: Batch, partition_keys: Sequence[str],
     return jnp.abs(common.row_hash(cols))
 
 
-@functools.partial(jax.jit, static_argnums=(1, 3))
+@functools.partial(_kernels.jit, family="exchange_partition",
+                   static_argnums=(1, 3))
 def partition_segments(batch: Batch, partition_keys: Tuple[str, ...],
                        remaps, n_consumers: int):
     """ONE dispatch for a whole hash repartition: sort rows by
@@ -108,7 +110,7 @@ def partition_segments(batch: Batch, partition_keys: Tuple[str, ...],
 # compile-vs-execute attribution for the repartition family —
 # previously an uninstrumented module-level jit whose compile landed
 # in exchange-push busy time
-from presto_tpu.telemetry.kernels import instrument_kernel as _instr
+_instr = _kernels.instrument_kernel
 
 partition_segments = _instr(partition_segments, "exchange_partition")
 

@@ -56,6 +56,7 @@ from presto_tpu.operators.sort_ops import (
     DistinctOperator, TopNOperator,
 )
 from presto_tpu.ops import sort as sort_kernels
+from presto_tpu.telemetry import kernels as _kernels
 
 
 # -- chain stages ------------------------------------------------------
@@ -175,11 +176,12 @@ def make_compacting_chain_body(stages: Sequence[ChainStage],
             return out, jnp.asarray(False)
         # bounded nonzero + gather, the _compact_shrink_jit shape —
         # inlined here so it traces into the surrounding program
-        idx = first_true_indices(out.row_valid, comp_cap, cap - 1)
-        rv = jnp.arange(comp_cap) < live
-        cols = {n: Column(c.data[idx], c.mask[idx] & rv, c.type,
-                          c.dictionary)
-                for n, c in out.columns.items()}
+        with jax.named_scope("compact"):
+            idx = first_true_indices(out.row_valid, comp_cap, cap - 1)
+            rv = jnp.arange(comp_cap) < live
+            cols = {n: Column(c.data[idx], c.mask[idx] & rv, c.type,
+                              c.dictionary)
+                    for n, c in out.columns.items()}
         return Batch(cols, rv), live > comp_cap
     return body
 
@@ -188,7 +190,10 @@ def make_chain_body(stages: Sequence[ChainStage]):
     """The traceable chain: batch -> batch, applying each stage's
     filter (narrowing row_valid) and projection forest in sequence —
     semantically identical to running the standalone FilterProject
-    kernels back to back, minus the per-stage materialization."""
+    kernels back to back, minus the per-stage materialization. Each
+    stage's ops carry a `jax.named_scope` of its kind (`filter`,
+    `project`; the terminals add theirs), which is what splits one
+    fused module's device time by operator in xprof."""
     stages = tuple(stages)
 
     def body(batch: Batch) -> Batch:
@@ -198,15 +203,18 @@ def make_chain_body(stages: Sequence[ChainStage]):
             cap = batch.capacity
             rv = batch.row_valid
             if st.filter_expr is not None:
-                d, m = st.filter_expr.fn(env)
-                rv = rv & jnp.broadcast_to(d & m, (cap,))
+                with jax.named_scope("filter"):
+                    d, m = st.filter_expr.fn(env)
+                    rv = rv & jnp.broadcast_to(d & m, (cap,))
             cols = {}
-            for name, ce in st.projections:
-                d, m = ce.fn(env)
-                d = jnp.broadcast_to(
-                    jnp.asarray(d, ce.type.np_dtype), (cap,))
-                cols[name] = Column(d, jnp.broadcast_to(m, (cap,)),
-                                    ce.type, ce.dictionary)
+            with jax.named_scope("project"):
+                for name, ce in st.projections:
+                    d, m = ce.fn(env)
+                    d = jnp.broadcast_to(
+                        jnp.asarray(d, ce.type.np_dtype), (cap,))
+                    cols[name] = Column(
+                        d, jnp.broadcast_to(m, (cap,)), ce.type,
+                        ce.dictionary)
             batch = Batch(cols, rv)
         return batch
     return body
@@ -229,8 +237,7 @@ def _cached_fragment_kernel(key, builder):
         if cached is not None:
             _FUSED_KERNEL_CACHE.move_to_end(key)
             return cached
-    from presto_tpu.telemetry.kernels import instrument_kernel
-    kernel = instrument_kernel(builder(), "fragment")
+    kernel = _kernels.instrument_kernel(builder(), "fragment")
     if key is not None:
         _FUSED_KERNEL_CACHE[key] = kernel
         while len(_FUSED_KERNEL_CACHE) > _FUSED_KERNEL_CACHE_MAX:
@@ -262,7 +269,7 @@ class FusedChainOperatorFactory(OperatorFactory):
         body = make_chain_body(stages)
         self._kernel = _cached_fragment_kernel(
             ("chain", chain_key) if chain_key is not None else None,
-            lambda: jax.jit(body))
+            lambda: _kernels.jit(body, "fragment", "chain"))
         self._selective = chain_selective(stages)
 
     def create(self, driver_context: DriverContext) -> Operator:
@@ -301,10 +308,12 @@ class FusedLimitOperatorFactory(OperatorFactory):
 
         def builder():
             def fn(batch: Batch, n, emitted):
-                out = sort_kernels._limit_batch_impl(
-                    body(batch), n, emitted)
-                return out, emitted + jnp.sum(out.row_valid)
-            return jax.jit(fn)
+                batch = body(batch)
+                with jax.named_scope("limit"):
+                    out = sort_kernels._limit_batch_impl(
+                        batch, n, emitted)
+                    return out, emitted + jnp.sum(out.row_valid)
+            return _kernels.jit(fn, "fragment", "limit")
         self._kernel = _cached_fragment_kernel(
             ("limit", chain_key) if chain_key is not None else None,
             builder)
@@ -352,9 +361,11 @@ class FusedTopNOperatorFactory(OperatorFactory):
 
         def builder():
             def fn(state: Batch, batch: Batch, n):
-                return sort_kernels._topn_step_impl(
-                    state, body(batch), n, keys, desc, nf)
-            return jax.jit(fn)
+                batch = body(batch)
+                with jax.named_scope("topn"):
+                    return sort_kernels._topn_step_impl(
+                        state, batch, n, keys, desc, nf)
+            return _kernels.jit(fn, "fragment", "topn")
         self._kernel = _cached_fragment_kernel(
             ("topn", chain_key, keys, desc, nf)
             if chain_key is not None else None,
@@ -396,9 +407,11 @@ class FusedDistinctOperatorFactory(OperatorFactory):
 
         def builder():
             def fn(state: Batch, batch: Batch):
-                return sort_kernels._distinct_step_impl(
-                    state, body(batch))
-            return jax.jit(fn)
+                batch = body(batch)
+                with jax.named_scope("distinct"):
+                    return sort_kernels._distinct_step_impl(
+                        state, batch)
+            return _kernels.jit(fn, "fragment", "distinct")
         self._kernel = _cached_fragment_kernel(
             ("distinct", chain_key) if chain_key is not None else None,
             builder)

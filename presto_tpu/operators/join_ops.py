@@ -26,6 +26,7 @@ from presto_tpu.operators.base import (
 )
 from presto_tpu.ops import common as ops_common
 from presto_tpu.ops import join as join_ops
+from presto_tpu.telemetry import kernels as _kernels
 
 
 class JoinCapacityExceeded(Exception):
@@ -388,36 +389,45 @@ def make_probe_kernel(key_names: Tuple[str, ...], join_type: str,
             cap = rv.shape[0]
             env = {n: (c.data, c.mask) for n, c in cols.items()}
             if fused_filter is not None:
-                d, m = fused_filter.fn(env)
-                rv = rv & jnp.broadcast_to(d & m, (cap,))
+                with jax.named_scope("filter"):
+                    d, m = fused_filter.fn(env)
+                    rv = rv & jnp.broadcast_to(d & m, (cap,))
             if fused_projections:
                 cols = {}
-                for name, ce in fused_projections:
-                    d, m = ce.fn(env)
-                    d = jnp.broadcast_to(
-                        jnp.asarray(d, ce.type.np_dtype), (cap,))
-                    cols[name] = Column(d, jnp.broadcast_to(m, (cap,)),
-                                        ce.type, ce.dictionary)
+                with jax.named_scope("project"):
+                    for name, ce in fused_projections:
+                        d, m = ce.fn(env)
+                        d = jnp.broadcast_to(
+                            jnp.asarray(d, ce.type.np_dtype), (cap,))
+                        cols[name] = Column(
+                            d, jnp.broadcast_to(m, (cap,)), ce.type,
+                            ce.dictionary)
         out = Batch(cols, rv)
         return out, jnp.sum(rv)
 
     def _expand_project(table, batch, lo_enc, h2, matched,
                         out_capacity: int):
-        out, overflow, _, matched = join_ops._expand_from_enc(
-            table, batch, key_names, lo_enc, matched, out_capacity,
-            join_type, probe_output, build_output, build_keys, verify,
-            h2=h2)
+        with jax.named_scope("join_probe"):
+            out, overflow, _, matched = join_ops._expand_from_enc(
+                table, batch, key_names, lo_enc, matched,
+                out_capacity, join_type, probe_output, build_output,
+                build_keys, verify, h2=h2)
         out, live = _project(out)
         return out, overflow, live, matched
 
-    family = "fragment" if pre is not None else "join_probe"
+    # a probe with a fused upstream chain is a whole-fragment program:
+    # family `fragment`, device name `fragment_join_probe`
+    family, part = ("fragment", "join_probe") if pre is not None \
+        else ("join_probe", None)
     jit_list = None
     if ops_common.cpu_backend():
         # two dispatches: the candidate search materializes ONCE (see
         # ops/join.py on XLA:CPU fusion re-materialization); the probe
         # hash2 rides across the boundary so expand needn't rehash
-        stage2 = functools.partial(jax.jit, static_argnums=(5,))(
-            _expand_project)
+        stage2 = _kernels.jit(
+            _expand_project, family,
+            "stage2" if part is None else f"{part}_stage2",
+            static_argnums=(5,))
 
         if _pre_batch is None:
             def kernel(table, batch, matched, out_capacity: int):
@@ -432,7 +442,8 @@ def make_probe_kernel(key_names: Tuple[str, ...], join_type: str,
             # (stage0): still two probe-side materializations, but
             # the former FilterProject dispatch — and its deferred
             # count/compact round — are gone
-            @jax.jit
+            @functools.partial(_kernels.jit, family=family,
+                               part=f"{part}_stage0")
             def stage0(batch):
                 b = _pre_batch(batch)
                 h, h2 = join_ops._probe_hashes(b, key_names)
@@ -445,12 +456,14 @@ def make_probe_kernel(key_names: Tuple[str, ...], join_type: str,
                               out_capacity)
             jit_list = [stage0, stage2, join_ops._search_jit]
     else:
-        @functools.partial(jax.jit, static_argnums=(3,))
+        @functools.partial(_kernels.jit, family=family, part=part,
+                           static_argnums=(3,))
         def kernel(table, batch, matched, out_capacity: int):
             if _pre_batch is not None:
                 batch = _pre_batch(batch)
-            lo_enc = join_ops._candidates_enc(table, batch, key_names,
-                                              verify)
+            with jax.named_scope("join_probe"):
+                lo_enc = join_ops._candidates_enc(
+                    table, batch, key_names, verify)
             return _expand_project(table, batch, lo_enc, None, matched,
                                    out_capacity)
 
@@ -459,11 +472,7 @@ def make_probe_kernel(key_names: Tuple[str, ...], join_type: str,
     # plus the shared module-level search jit — so all executable
     # caches are polled for compile detection. A probe with a fused
     # upstream chain is a whole-fragment program (`fragment` family).
-    from presto_tpu.telemetry.kernels import instrument_kernel
-    if jit_list is not None:
-        kernel = instrument_kernel(kernel, family, jits=jit_list)
-    else:
-        kernel = instrument_kernel(kernel, family)
+    kernel = _kernels.instrument_kernel(kernel, family, jits=jit_list)
 
     if key is not None:
         _PROBE_KERNEL_CACHE[key] = kernel

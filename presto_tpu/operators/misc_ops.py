@@ -10,6 +10,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from presto_tpu.telemetry import kernels as _kernels
 from presto_tpu.batch import Batch, Column, bucket_capacity
 from presto_tpu.operators.base import (
     DriverContext, Operator, OperatorContext, OperatorFactory,
@@ -116,7 +117,8 @@ class NestedLoopJoinOperator(Operator):
 import functools
 
 
-@functools.partial(jax.jit, static_argnums=(2,))
+@functools.partial(_kernels.jit, family="nested_loop",
+                   static_argnums=(2,))
 def _cross_product(probe: Batch, build: Batch, out_cap: int) -> Batch:
     nb_valid = jnp.sum(build.row_valid)
     np_valid = jnp.sum(probe.row_valid)
@@ -138,7 +140,7 @@ def _cross_product(probe: Batch, build: Batch, out_cap: int) -> Batch:
 
 # compile-vs-execute attribution for the nested-loop (cross join)
 # family — previously an uninstrumented module-level jit
-from presto_tpu.telemetry.kernels import instrument_kernel as _instr
+_instr = _kernels.instrument_kernel
 
 _cross_product = _instr(_cross_product, "nested_loop")
 

@@ -74,6 +74,7 @@ import numpy as np
 from presto_tpu.batch import Batch, Column
 from presto_tpu.native import pages
 from presto_tpu.ops import common
+from presto_tpu.telemetry import kernels as _kernels
 
 CVal = Tuple[jnp.ndarray, jnp.ndarray]
 
@@ -182,7 +183,8 @@ def _hash_batch(batch: Batch, key_names: Tuple[str, ...]):
     return h, h2, valid
 
 
-@functools.partial(jax.jit, static_argnums=(1, 2))
+@functools.partial(_kernels.jit, family="join_build", part="sorted",
+                   static_argnums=(1, 2))
 def _build_sorted(batch: Batch, key_names: Tuple[str, ...], k: int):
     """Device build: hash keys, order the rows by hash (one
     permutation, one gather per column), then derive the radix
@@ -219,13 +221,14 @@ def _build_sorted(batch: Batch, key_names: Tuple[str, ...], k: int):
                    max_run.astype(jnp.int64)])
 
 
-@functools.partial(jax.jit, static_argnums=(1,))
+@functools.partial(_kernels.jit, family="join_build", part="hash",
+                   static_argnums=(1,))
 def _build_hash(batch: Batch, key_names: Tuple[str, ...]):
     h, h2, _ = _hash_batch(batch, key_names)
     return h, h2
 
 
-@jax.jit
+@functools.partial(_kernels.jit, family="join_build", part="apply_perm")
 def _build_apply_perm(batch: Batch, h: jnp.ndarray, h2: jnp.ndarray,
                       perm: jnp.ndarray):
     cols = {
@@ -337,7 +340,9 @@ def _probe_hashes(probe: Batch, probe_keys: Tuple[str, ...]):
     return h, h2
 
 
-_hash_jit = jax.jit(_probe_hashes, static_argnums=(1,))
+#: shared with semi_join; named once, under the family that owns it
+_hash_jit = _kernels.jit(_probe_hashes, "join_probe", "hash",
+                        static_argnums=(1,))
 
 
 def _search_enc(table: BuildTable, h: jnp.ndarray, h2: jnp.ndarray,
@@ -369,7 +374,8 @@ def _search_enc(table: BuildTable, h: jnp.ndarray, h2: jnp.ndarray,
     return jnp.where(found, lo, jnp.int64(-1))
 
 
-_search_jit = jax.jit(_search_enc, static_argnums=(3,))
+_search_jit = _kernels.jit(_search_enc, "join_probe", "search",
+                          static_argnums=(3,))
 
 
 def _candidates_enc(table: BuildTable, probe: Batch,
@@ -403,7 +409,8 @@ def probe_counts(table: BuildTable, probe: Batch,
     return _counts_jit(table, probe, probe_keys, lo_enc)
 
 
-@functools.partial(jax.jit, static_argnums=(2,))
+@functools.partial(_kernels.jit, family="join_probe", part="counts",
+                   static_argnums=(2,))
 def _counts_jit(table, probe, probe_keys, lo_enc):
     keys = [probe.columns[k].astuple() for k in probe_keys]
     valid = probe.row_valid
@@ -448,7 +455,9 @@ def expand(table: BuildTable, probe: Batch, key_names,
     return out
 
 
-@functools.partial(jax.jit, static_argnums=(2, 6, 7, 8, 9, 10, 11, 12,
+@functools.partial(_kernels.jit, family="join_probe",
+                   part="expand_general",
+                   static_argnums=(2, 6, 7, 8, 9, 10, 11, 12,
                                             13))
 def _expand_general_jit(table, probe, key_names, lo, counts,
                         probe_key_valid, out_capacity, join_type,
@@ -516,7 +525,8 @@ def probe_join_full(table: BuildTable, probe: Batch,
                              build_output, build_keys, verify)
 
 
-@functools.partial(jax.jit, static_argnums=(2, 4, 5, 6, 7, 8, 9))
+@functools.partial(_kernels.jit, family="join_probe", part="fused",
+                   static_argnums=(2, 4, 5, 6, 7, 8, 9))
 def _probe_join_fused(table, probe, key_names, matched, out_capacity,
                       join_type, probe_output, build_output, build_keys,
                       verify):
@@ -526,7 +536,8 @@ def _probe_join_fused(table, probe, key_names, matched, out_capacity,
                             build_output, build_keys, verify)
 
 
-@functools.partial(jax.jit, static_argnums=(2, 6, 7, 8, 9, 10, 11))
+@functools.partial(_kernels.jit, family="join_probe", part="expand",
+                   static_argnums=(2, 6, 7, 8, 9, 10, 11))
 def _expand_dispatch(table, probe, key_names, lo_enc, h2, matched,
                      out_capacity, join_type, probe_output,
                      build_output, build_keys, verify):
@@ -675,7 +686,8 @@ def _expand_general(table, probe, key_names, lo, counts, out_capacity,
     return Batch(cols, live), total > out_capacity, brow, verified
 
 
-@functools.partial(jax.jit, static_argnums=(2, 3))
+@functools.partial(_kernels.jit, family="join_outer",
+                   static_argnums=(2, 3))
 def unmatched_build(table: BuildTable, matched: jnp.ndarray,
                     probe_schema: Tuple[Tuple, ...],
                     build_output: Tuple[str, ...]):
@@ -726,7 +738,8 @@ def semi_mark(table: BuildTable, probe: Batch,
                        verify)
 
 
-@functools.partial(jax.jit, static_argnums=(2,))
+@functools.partial(_kernels.jit, family="semi_join", part="unique",
+                   static_argnums=(2,))
 def _semi_unique_fused(table: BuildTable, probe: Batch, key_names):
     """Unique-run membership in ONE dispatch (TPU): the search stage's
     folded second-hash verification fully resolves each probe row."""
@@ -742,17 +755,20 @@ def _semi_resolve(probe: Batch, key_names, lo_enc):
     return (lo_enc >= 0) & valid, valid
 
 
-_semi_from_enc = jax.jit(_semi_resolve, static_argnums=(1,))
+_semi_from_enc = _kernels.jit(_semi_resolve, "semi_join", "resolve",
+                             static_argnums=(1,))
 
 
-@functools.partial(jax.jit, static_argnums=(2, 3, 4))
+@functools.partial(_kernels.jit, family="semi_join", part="fused",
+                   static_argnums=(2, 3, 4))
 def _semi_fused(table, probe, key_names, build_keys, verify):
     lo_enc = _candidates_enc(table, probe, key_names, verify)
     return _semi_scan(table, probe, key_names, lo_enc, build_keys,
                       verify)
 
 
-@functools.partial(jax.jit, static_argnums=(2, 4, 5))
+@functools.partial(_kernels.jit, family="semi_join", part="scan",
+                   static_argnums=(2, 4, 5))
 def _semi_scan_jit(table, probe, key_names, lo_enc, build_keys,
                    verify):
     return _semi_scan(table, probe, key_names, lo_enc, build_keys,
@@ -819,7 +835,7 @@ def _semi_scan(table, probe, key_names, lo_enc, build_keys, verify):
 # in operators/join_ops.py register their own per-plan jits the same
 # way). The *_impl jits above stay unwrapped so they can compose into
 # other traces without double accounting.
-from presto_tpu.telemetry.kernels import instrument_kernel as _instr
+_instr = _kernels.instrument_kernel
 
 build_for_backend = _instr(
     build_for_backend, "join_build",

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 
 from presto_tpu.batch import Batch, Column
 from presto_tpu.ops.common import _negate_for_desc, float64_order_key
+from presto_tpu.telemetry import kernels as _kernels
 
 CVal = Tuple[jnp.ndarray, jnp.ndarray]
 
@@ -95,7 +96,8 @@ def _lex_count_below(b_ops: List[jnp.ndarray],
     return lo
 
 
-@functools.partial(jax.jit, static_argnums=(2, 3, 4))
+@functools.partial(_kernels.jit, family="merge",
+                   static_argnums=(2, 3, 4))
 def _merge_pair_jit(a: Batch, b: Batch, key_names: Tuple[str, ...],
                     descending: Tuple[bool, ...],
                     nulls_first: Tuple[bool, ...]) -> Batch:
@@ -124,7 +126,7 @@ def _merge_pair_jit(a: Batch, b: Batch, key_names: Tuple[str, ...],
 
 
 # compile-vs-execute attribution for the sorted-run merge family
-from presto_tpu.telemetry.kernels import instrument_kernel as _instr
+_instr = _kernels.instrument_kernel
 
 merge_pair = _instr(_merge_pair_jit, "merge")
 

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 
 from presto_tpu.batch import Batch, Column
 from presto_tpu.ops import common
+from presto_tpu.telemetry import kernels as _kernels
 
 
 def _sort_batch_impl(batch: Batch, key_names: Tuple[str, ...],
@@ -46,8 +47,8 @@ def _sort_batch_impl(batch: Batch, key_names: Tuple[str, ...],
 
 
 #: the jit (internal callers compose the impl inside their own traces)
-_sort_batch = functools.partial(
-    jax.jit, static_argnums=(1, 2, 3))(_sort_batch_impl)
+_sort_batch = _kernels.jit(_sort_batch_impl, "sort",
+                           static_argnums=(1, 2, 3))
 
 
 def _topn_step_impl(state: Batch, batch: Batch, n,
@@ -79,8 +80,8 @@ def _topn_step_impl(state: Batch, batch: Batch, n,
     return Batch(cols, live[:cap])
 
 
-_topn_step = functools.partial(
-    jax.jit, static_argnums=(3, 4, 5))(_topn_step_impl)
+_topn_step = _kernels.jit(_topn_step_impl, "topn",
+                          static_argnums=(3, 4, 5))
 
 
 def _limit_batch_impl(batch: Batch, n, already_emitted) -> Batch:
@@ -92,7 +93,7 @@ def _limit_batch_impl(batch: Batch, n, already_emitted) -> Batch:
     return Batch(batch.columns, keep)
 
 
-_limit_batch = jax.jit(_limit_batch_impl)
+_limit_batch = _kernels.jit(_limit_batch_impl, "limit")
 
 
 def distinct_state(schema_cols, capacity: int) -> Batch:
@@ -129,7 +130,7 @@ def _distinct_step_impl(state: Batch, batch: Batch) -> Batch:
     return Batch(cols, gr.valid)
 
 
-_distinct_step_jit = jax.jit(_distinct_step_impl)
+_distinct_step_jit = _kernels.jit(_distinct_step_impl, "distinct")
 
 
 # -- instrumented public entry points ---------------------------------
@@ -140,7 +141,7 @@ _distinct_step_jit = jax.jit(_distinct_step_impl)
 # execute" gap flagged after the telemetry PR. The *_impl bodies above
 # stay importable so operators/fused_fragment.py can compose them into
 # whole-fragment traces.
-from presto_tpu.telemetry.kernels import instrument_kernel as _instr
+_instr = _kernels.instrument_kernel
 
 sort_batch = _instr(_sort_batch, "sort")
 topn_step = _instr(_topn_step, "topn")

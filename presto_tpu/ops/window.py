@@ -32,6 +32,7 @@ import numpy as np
 
 from presto_tpu.batch import Batch, Column
 from presto_tpu.ops import common
+from presto_tpu.telemetry import kernels as _kernels
 from presto_tpu.types import Type
 
 #: legacy frame modes (still accepted; normalized in the kernel)
@@ -158,7 +159,7 @@ def _segment_positions(bnd: jnp.ndarray) -> jnp.ndarray:
 
 
 @functools.partial(
-    jax.jit,
+    _kernels.jit, family="window",
     static_argnames=("part_names", "order_names", "descending",
                      "nulls_first", "calls"))
 def _window_kernel_jit(batch: Batch,
@@ -462,7 +463,7 @@ def _window_kernel_jit(batch: Batch,
 
 # compile-vs-execute attribution for the window family (previously an
 # uninstrumented module-level jit whose compile time landed in busy)
-from presto_tpu.telemetry.kernels import instrument_kernel as _instr
+_instr = _kernels.instrument_kernel
 
 window_kernel = _instr(_window_kernel_jit, "window")
 

@@ -37,6 +37,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from presto_tpu.batch import Batch, Column, bucket_capacity
 from presto_tpu.ops import common
 from presto_tpu.parallel.mesh import worker_axis
+from presto_tpu.telemetry import kernels as _kernels
 
 
 class ShardedBatch:
@@ -226,14 +227,14 @@ def _wave_program(mesh: Mesh, axis: str, w: int, n_keys: int,
                   n_cols: int):
     spec = P(axis)
     body = functools.partial(_wave_body, w, axis)
-    from presto_tpu.telemetry.kernels import instrument_kernel
     # the lru entry holds the instrumented wrapper, so the warm jit
     # cache (and with it the zero-new-kernels guarantee for the second
     # same-bucket wave) travels with the cache hit
-    return instrument_kernel(jax.jit(jax.shard_map(
+    return _kernels.instrument_kernel(_kernels.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(spec,) * 5,
-        out_specs=(spec, spec, spec, spec))), "spmd_shuffle")
+        out_specs=(spec, spec, spec, spec)), "spmd_shuffle"),
+        "spmd_shuffle")
 
 
 # -- chained wave: a fused-fragment chain traced INSIDE the wave -------
@@ -312,11 +313,11 @@ def _chained_wave_program(mesh: Mesh, axis: str, w: int,
                           tuple(key_masks), o_datas, o_masks)
 
     spec = P(axis)
-    from presto_tpu.telemetry.kernels import instrument_kernel
-    fn = instrument_kernel(jax.jit(jax.shard_map(
+    fn = _kernels.instrument_kernel(_kernels.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(spec, spec, spec, P()),
-        out_specs=(spec, spec, spec, spec))), "spmd_fragment")
+        out_specs=(spec, spec, spec, spec)), "spmd_fragment"),
+        "spmd_fragment")
     entry = (fn, out_meta)
     _CHAINED_PROGRAMS[cache_key] = entry
     while len(_CHAINED_PROGRAMS) > _CHAINED_PROGRAMS_MAX:

@@ -22,6 +22,7 @@ from typing import Dict, List, Optional
 
 from presto_tpu import sanitize
 from presto_tpu.execution import faults
+from presto_tpu.telemetry.metrics import METRICS
 from presto_tpu.server.node import (
     TRANSPORT_RETRIES, Node, build_http_exchanges, derive_fragments,
     http_delete, http_get, http_post,
@@ -130,6 +131,9 @@ class _Query:
         self.columns: Optional[List[dict]] = None
         self.data: Optional[List[list]] = None
         self.done_at: Optional[float] = None  # set at terminal state
+        #: when the answer's tail page (the one without nextUri) was
+        #: first handed to the client: `served_ms` in the stats tree
+        self.served_at: Optional[float] = None
         self.user = ""
         self.source = ""
         self.group = "root"
@@ -414,6 +418,19 @@ class Coordinator(Node):
 
     def handle_post(self, path: str, body: bytes,
                     headers: Optional[dict] = None) -> bytes:
+        if path != "/v1/statement":
+            return self._handle_post(path, body, headers)
+        # the protocol layer timed from inside: POST in to response
+        # out (admission decided, runner thread started)
+        t0 = time.perf_counter_ns()
+        try:
+            return self._handle_post(path, body, headers)
+        finally:
+            METRICS.inc("presto_tpu_protocol_ns_total",
+                        time.perf_counter_ns() - t0, phase="accept")
+
+    def _handle_post(self, path: str, body: bytes,
+                     headers: Optional[dict]) -> bytes:
         if path == "/v1/statement":
             from presto_tpu.execution.resource_groups import (
                 QueryRejected,
@@ -651,6 +668,14 @@ class Coordinator(Node):
                     out["nextUri"] = \
                         f"{self.url}/v1/statement/executing/" \
                         f"{qid}/{token + 1}"
+                else:
+                    self._stamp_served(q)
+                t0 = time.perf_counter_ns()
+                page = json.dumps(out).encode()
+                METRICS.inc("presto_tpu_protocol_ns_total",
+                            time.perf_counter_ns() - t0,
+                            phase="encode")
+                return page
             elif q.state == "FAILED":
                 out["error"] = {"message": q.error,
                                 "errorKind": q.error_kind}
@@ -664,6 +689,25 @@ class Coordinator(Node):
                                  f"{qid}/{token}"
             return json.dumps(out).encode()
         return super().handle_get(path)
+
+    def _stamp_served(self, q: _Query) -> None:
+        """The answer's tail page is being handed out: how long the
+        finished answer waited for the client's poll is the protocol's
+        `result_wait`, and submit-to-here is `served_ms` beside
+        `wall_ms`. Once per query (a client may re-read a page)."""
+        if q.served_at is not None:
+            return
+        q.served_at = time.monotonic()
+        if q.done_at is None:
+            # polled between state=FINISHED and the stats rollup of
+            # _run_query: nothing waited; the rollup writes served_ms
+            return
+        METRICS.inc("presto_tpu_protocol_ns_total",
+                    (q.served_at - q.done_at) * 1e9,
+                    phase="result_wait")
+        if isinstance(q.stats, dict):  # None until the rollup's end
+            q.stats["served_ms"] = round(
+                (q.served_at - q.created_at) * 1000, 3)
 
     def _ui_page(self) -> bytes:
         """Single self-contained cluster status page (the webapp/
@@ -915,6 +959,9 @@ th{{background:#222}}
             q.stats = {**base, **inner,
                        "wall_ms": round(wall_ms, 3),
                        "queued_ms": round(queued_ms, 3)}
+            if q.served_at is not None:
+                # the tail page left before this rollup (_stamp_served)
+                q.stats["served_ms"] = q.stats["wall_ms"]
             # re-close the attribution ledger against the FULL query
             # wall (coordinator queue + execution + result
             # materialization + protocol overhead): categories come

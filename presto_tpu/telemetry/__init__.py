@@ -13,7 +13,10 @@ TaskStats / QueryStats hierarchy, server/QueryResource, and the
              cache boundary: a kernel call that grew the jit cache was
              a COMPILE (cache-miss trace), anything else is dispatch/
              execute — credited to the operator whose add_input/
-             get_output was running (see operators/driver.py)
+             get_output was running (see operators/driver.py); also
+             the one place a device program is jitted and named after
+             its kernel family (`kernels.jit`), which is what a
+             jax.profiler device trace groups by
   stats    — plain-dict OperatorStats snapshots and the shared
              EXPLAIN ANALYZE / task-status renderer
   ledger   — the per-query wall-clock attribution ledger: a

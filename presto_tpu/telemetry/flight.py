@@ -28,7 +28,10 @@ call site):
 
     query       (state, kind_or_user, sql_head)   lifecycle edges
     span        (edge, name, detail)              traced-span edges
-    compile     (kernel, ms, reason)              XLA compiles
+    compile     (kernel, ms, reason)              XLA compiles (reason
+                                                  xla_unnamed: a program
+                                                  no kernel family named,
+                                                  by jax's fun_name)
     shed        (kind, group, "")                 admission sheds
     retry       (tier, target, detail)            transport/task/query
     demotion    (level, label, "")                executor MLFQ

@@ -27,6 +27,13 @@ Attribution targets, all optional per call:
 ``ENABLED`` is the zero-overhead gate (the faults.ARMED pattern): when
 False the instrumented wrapper is a single branch + tail call.
 
+The same boundary names the DEVICE's time (docs/OBSERVABILITY.md, "The
+device timeline"): `jit` below is the one place a device program is
+jitted and named after its kernel family, so a device trace groups by
+family; the wrapper's call is a `kernel:<family>` host span on
+jax.profiler's clock; and one jax.monitoring listener counts every XLA
+compile by family, the eager jnp ops no wrapper sees included.
+
 Concurrency: compile detection is a heuristic over SHARED jit caches,
 hardened for the two-cold-queries race. Every in-flight call registers
 in the wrapper's active set under the state lock; the call that
@@ -45,10 +52,15 @@ exactness would need a per-call compile signal jax does not expose."""
 
 from __future__ import annotations
 
+import functools
+import re
 import threading
 import time
 import weakref
 from typing import Dict, Optional
+
+import jax
+from jax.profiler import TraceAnnotation
 
 from presto_tpu import sanitize
 from presto_tpu.telemetry.metrics import METRICS
@@ -56,6 +68,62 @@ from presto_tpu.telemetry import flight as _flight
 from presto_tpu.telemetry import ledger as _ledger
 from presto_tpu.telemetry import sentinel as _sentinel
 from presto_tpu.telemetry import trace as _trace
+
+#: device name -> kernel family, filled by `jit` below: the one map
+#: from what a device trace carries (the XLA module name) back to the
+#: KernelContract family the counters are labelled with
+_DEVICE_NAMES: Dict[str, str] = {}
+
+#: the forms a device name arrives in: `jit_<name>(<fingerprint>)` on
+#: the profiler's "XLA Modules" line, `jit_<name>` once reduced,
+#: `PjitFunction(<name>)` on a host thread, `jit(<name>)` in
+#: jax.monitoring's `fun_name`
+_MODULE_FORMS = re.compile(
+    r"^(?:PjitFunction\((?P<host>.*)\)|jit\((?P<event>.*)\)"
+    r"|jit_(?P<module>.*?)(?:\(\d+\))?)$")
+
+
+def jit(fn, family: str, part: Optional[str] = None, **jit_kwargs):
+    """THE place a device program gets its name: `jax.jit` of `fn`
+    under the device name `<family>` or `<family>_<part>` (one family,
+    several programs). The name becomes the XLA module `jit_<name>` in
+    a device trace and `PjitFunction(<name>)` on the host's lane, so
+    device time groups by kernel family with no lookup table kept
+    beside the code. `fn` itself keeps its Python name (impl bodies
+    compose into other traces under theirs). Lint rule TS007 refuses a
+    `jax.jit` that bypasses this function."""
+    name = family if part is None else f"{family}_{part}"
+    known = _DEVICE_NAMES.setdefault(name, family)
+    if known != family:
+        raise ValueError(f"device name {name!r} already belongs to "
+                         f"family {known!r}, not {family!r}")
+
+    @functools.wraps(fn)
+    def named(*args, **kwargs):
+        return fn(*args, **kwargs)
+    named.__name__ = named.__qualname__ = name
+    return jax.jit(named, **jit_kwargs)
+
+
+def device_names() -> Dict[str, str]:
+    """device name -> family, as registered so far."""
+    return dict(_DEVICE_NAMES)
+
+
+def device_name_of(module: str) -> str:
+    """The device name inside any of the forms a trace or a compile
+    event carries it in (`module` itself when it is none of them)."""
+    m = _MODULE_FORMS.match(module)
+    if m is None:
+        return module
+    return next(g for g in m.groups() if g is not None)
+
+
+def family_of_module(module: str) -> Optional[str]:
+    """`jit_join_build_sorted(123)` -> `join_build`; None for a
+    program no kernel family named (an eager jnp op)."""
+    return _DEVICE_NAMES.get(device_name_of(module))
+
 
 #: master gate for kernel timing. On by default: the per-call cost is
 #: two clock reads + a cache-size poll (~hundreds of ns) under batch-
@@ -245,6 +313,33 @@ def record(name: str, dur_ns: int, compiled: bool,
         _sentinel.observe_kernel(name, dur_ns / 1e6)
 
 
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+
+
+def _on_jax_duration(event: str, duration_secs: float,
+                     fun_name: str = "", **_) -> None:
+    """The complete compile count: every program XLA builds (or loads
+    from the persistent cache) in this process, whether or not a
+    kernel family's wrapper was around it. The cache-size poll above
+    sees instrumented kernels only; an eager jnp op on the driver
+    path is its own device program and lands here as `(unnamed)`,
+    with its `fun_name` in the flight ring."""
+    if event != _BACKEND_COMPILE:
+        return
+    family = family_of_module(fun_name)
+    if family is None:
+        family = "(unnamed)"
+        _flight.record("compile", fun_name,
+                       round(duration_secs * 1e3, 1), "xla_unnamed")
+    METRICS.inc("presto_tpu_xla_compiles_total", family=family)
+    METRICS.inc("presto_tpu_xla_compile_seconds_total", duration_secs,
+                family=family)
+
+
+# jax calls it on a compile and never on a warm dispatch
+jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+
+
 def record_expr_compile(dur_ns: int) -> None:
     """Host-side expression-closure building time (expr/compile.py) —
     the non-XLA share of plan->kernel cost."""
@@ -281,6 +376,11 @@ def instrument_kernel(kernel, name: str, jits=None):
     state = {"traced": False, "accounted": 0,
              "lock": sanitize.lock("telemetry.kernel_state"),
              "active": {}}
+    # the call's span on jax.profiler's timeline: a kernel object's
+    # first call always compiles; a later retrace for a new shape
+    # cannot be told before the call and stays a `kernel:` span, with
+    # jax's own compile events inside it
+    warm_span, cold_span = f"kernel:{name}", f"compile:{name}"
 
     def wrapped(*args, **kwargs):
         if not ENABLED:
@@ -297,7 +397,9 @@ def instrument_kernel(kernel, name: str, jits=None):
                 stall = _HANDICAP_MS.get(name)
                 if stall:
                     time.sleep(stall / 1e3)
-            out = kernel(*args, **kwargs)
+            with TraceAnnotation(
+                    warm_span if state["traced"] else cold_span):
+                out = kernel(*args, **kwargs)
         except BaseException:
             with state["lock"]:
                 state["active"].pop(tok, None)

@@ -98,6 +98,8 @@ import threading
 import time
 from typing import Any, Dict, Optional, Tuple
 
+from jax.profiler import TraceAnnotation
+
 from presto_tpu import sanitize
 
 #: the full category set, in rendering order
@@ -252,7 +254,10 @@ def current() -> Optional[QueryLedger]:
 def span(category: str):
     """Charge this frame's SELF time (elapsed minus nested charges on
     this thread) to `category`. A no-op — zero clock reads — when the
-    thread has no current ledger."""
+    thread has no current ledger. With one, the frame is also a
+    `ledger:<category>` host event on jax.profiler's timeline, the
+    clock the device trace shares (about 0.5 us a frame, attached or
+    not: docs/OBSERVABILITY.md, "The device timeline")."""
     led = getattr(_TL, "ledger", None)
     if led is None:
         yield
@@ -261,7 +266,8 @@ def span(category: str):
     frame = [category, time.perf_counter_ns(), 0]
     stack.append(frame)
     try:
-        yield
+        with TraceAnnotation(f"ledger:{category}"):
+            yield
     finally:
         stack.pop()
         dur = time.perf_counter_ns() - frame[1]

@@ -207,6 +207,21 @@ METRICS.describe("presto_tpu_kernel_retrace_total",
                  "of a program, shape = an existing kernel re-traced "
                  "for a new input signature (the retrace source "
                  "kernel_shape_buckets bounds)")
+METRICS.describe("presto_tpu_xla_compiles_total",
+                 "Programs XLA built or loaded from the persistent "
+                 "cache, by kernel family (jax.monitoring's backend "
+                 "compile event; family looked up from the program's "
+                 "device name, `(unnamed)` for an eager jnp op that "
+                 "no kernel family jitted)")
+METRICS.describe("presto_tpu_xla_compile_seconds_total",
+                 "Seconds of the backend compile events counted by "
+                 "presto_tpu_xla_compiles_total, by kernel family")
+METRICS.describe("presto_tpu_protocol_ns_total",
+                 "Client-protocol ns on the coordinator by phase: "
+                 "accept = POST /v1/statement in to response out, "
+                 "result_wait = a finished answer waiting for the "
+                 "client's poll (done to tail page handed out), "
+                 "encode = json.dumps of result pages that carry data")
 METRICS.describe("presto_tpu_prewarm_statements_total",
                  "AOT prewarm statements by status")
 METRICS.describe("presto_tpu_expr_compile_ns_total",

@@ -102,7 +102,7 @@ def build_suite(rows: int):
         jax.block_until_ready(x)
 
     # --- filter + project (the PageProcessor analog) -----------------
-    @jax.jit
+    @jax.jit  # lint-ok: TS007 a bench body, no family to name it
     # lint-ok: TS005 bench measures the raw kernel; a wrapper would skew it
     def filter_project(b: Batch):
         k = b.columns["k"]
@@ -130,7 +130,7 @@ def build_suite(rows: int):
         lambda: join_ops.semi_mark(table, probe, ("k",)), blk, rows)
 
     # --- grouped aggregation: sort path (random keys) ----------------
-    @jax.jit
+    @jax.jit  # lint-ok: TS007 a bench body, no family to name it
     # lint-ok: TS005 bench measures the raw kernel; a wrapper would skew it
     def agg_sorted_path(b: Batch):
         k = b.columns["k"].astuple()
@@ -141,7 +141,7 @@ def build_suite(rows: int):
                                 rows)
 
     # --- grouped aggregation: presorted path (streaming) -------------
-    @jax.jit
+    @jax.jit  # lint-ok: TS007 a bench body, no family to name it
     # lint-ok: TS005 bench measures the raw kernel; a wrapper would skew it
     def agg_presorted(b: Batch):
         k = b.columns["k"].astuple()
@@ -151,7 +151,7 @@ def build_suite(rows: int):
     suite["agg_presorted"] = (lambda: agg_presorted(sortedb), blk, rows)
 
     # --- variadic row sort ------------------------------------------
-    @jax.jit
+    @jax.jit  # lint-ok: TS007 a bench body, no family to name it
     # lint-ok: TS005 bench measures the raw kernel; a wrapper would skew it
     def row_sort(b: Batch):
         keys = [b.columns["k"].astuple()]

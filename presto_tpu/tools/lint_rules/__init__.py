@@ -165,24 +165,36 @@ def dotted(node: ast.AST) -> Optional[str]:
 
 
 def is_jax_jit(node: ast.AST) -> bool:
+    """A RAW jax.jit: the device program it makes carries the Python
+    function's name (TS007)."""
     return dotted(node) in ("jax.jit", "jit")
 
 
+def is_kernel_jit(node: ast.AST) -> bool:
+    """telemetry.kernels.jit under any module alias (`_kernels.jit`):
+    jax.jit plus the device name, same jit keywords."""
+    return (dotted(node) or "").endswith("kernels.jit")
+
+
+def is_jit(node: ast.AST) -> bool:
+    return is_jax_jit(node) or is_kernel_jit(node)
+
+
 def partial_of_jit(call: ast.AST) -> Optional[ast.Call]:
-    """The Call node when `call` is functools.partial(jax.jit, ...)."""
+    """The Call node when `call` is functools.partial(<jit>, ...)."""
     if isinstance(call, ast.Call) \
             and dotted(call.func) in ("functools.partial", "partial") \
-            and call.args and is_jax_jit(call.args[0]):
+            and call.args and is_jit(call.args[0]):
         return call
     return None
 
 
 def jit_call_of(value: ast.AST) -> Optional[ast.Call]:
-    """The jit-ish Call when `value` is jax.jit(...) or
-    functools.partial(jax.jit, ...)(...) — i.e. an expression whose
-    result is a jitted callable."""
+    """The jit-ish Call when `value` is <jit>(...) or
+    functools.partial(<jit>, ...)(...) — i.e. an expression whose
+    result is a jitted callable (<jit>: jax.jit or kernels.jit)."""
     if isinstance(value, ast.Call):
-        if is_jax_jit(value.func):
+        if is_jit(value.func):
             return value
         if partial_of_jit(value.func) is not None:
             return value
@@ -236,7 +248,7 @@ def _str_elements(node: ast.AST) -> List[str]:
 
 def jit_decorator_of(fn: ast.AST) -> Optional[ast.AST]:
     """The decorator expression when `fn` is decorated as a jit body
-    (@jax.jit or @functools.partial(jax.jit, ...))."""
+    (@jax.jit or @functools.partial(<jit>, ...))."""
     if not isinstance(fn, (ast.FunctionDef, ast.AsyncFunctionDef)):
         return None
     for dec in fn.decorator_list:

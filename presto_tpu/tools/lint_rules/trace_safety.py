@@ -24,6 +24,10 @@ machine-checked:
          family (instrument_kernel) — its compile time lands in
          operator busy time and the compile-wall attribution lies
          (the exact PR 5 gap class)
+  TS007  a jax.jit that bypasses telemetry.kernels.jit — the device
+         program is named after the Python closure (`jit_kernel`,
+         `jit_fn`), so a device trace cannot say which kernel family
+         the chip's time went to (PERF.md, PR 26)
 """
 
 from __future__ import annotations
@@ -399,6 +403,31 @@ def check_mutable_capture(mod: ModuleInfo,
     return uniq
 
 
+@rule("TS007", "jax.jit that bypasses telemetry.kernels.jit (the "
+               "device program keeps the Python function's name)")
+def check_unnamed_jit(mod: ModuleInfo,
+                      project: Project) -> List[Finding]:
+    """Every device program is jitted through `kernels.jit(fn,
+    family, part)`, which names the XLA module after its kernel
+    family. Any other spelling of jax.jit — call, decorator, partial,
+    `from jax import jit` — is a finding."""
+    out: List[Finding] = []
+    for node in ast.walk(mod.tree):
+        raw = isinstance(node, ast.Attribute) \
+            and dotted(node) == "jax.jit"
+        imported = isinstance(node, ast.ImportFrom) \
+            and node.module == "jax" \
+            and any(a.name == "jit" for a in node.names)
+        if raw or imported:
+            out.append(mod.finding(
+                "TS007", node,
+                "jax.jit used directly: jit through "
+                "telemetry.kernels.jit(fn, family, part=...) so the "
+                "XLA module is named after its kernel family"))
+    return out
+
+
 TRACE_RULES = (check_traced_branch, check_host_sync,
                check_numpy_in_jit, check_unhashable_static,
-               check_unregistered_jit, check_mutable_capture)
+               check_unregistered_jit, check_mutable_capture,
+               check_unnamed_jit)

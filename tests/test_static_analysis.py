@@ -65,7 +65,7 @@ def test_mesh_drive_loop_has_lifecycle_checkpoints():
 
 def test_rule_catalogue_complete():
     assert set(RULES) == {"TS001", "TS002", "TS003", "TS004", "TS005",
-                          "TS006",
+                          "TS006", "TS007",
                           "CC001", "CC002", "CC003", "CC004",
                           "CC005", "CC006"}
 
@@ -206,6 +206,51 @@ def test_ts005_unregistered_jit():
                                 jits=[component])
     """
     assert not _rules(clean, "TS005")
+
+
+def test_ts007_raw_jit_bypasses_the_device_name():
+    bad = """
+    import functools, jax
+    from jax import jit
+
+    @functools.partial(jax.jit, static_argnums=(1,))
+    def kernel(x, n):  # lint-ok: TS005 fixture kernel
+        return x + n
+
+    other = jax.jit(lambda x: x)  # lint-ok: TS005 fixture kernel
+    """
+    assert len(_rules(bad, "TS007")) == 3
+    clean = """
+    import functools
+    from presto_tpu.telemetry import kernels as _kernels
+
+    @functools.partial(_kernels.jit, family="fixture", part="add",
+                       static_argnums=(1,))
+    def kernel(x, n):
+        return x + n
+
+    def _impl(x):
+        return x
+
+    other = _kernels.jit(_impl, "fixture")
+    kernel = _kernels.instrument_kernel(kernel, "fixture")
+    other = _kernels.instrument_kernel(other, "fixture")
+    """
+    assert not _rules(clean, "TS007")
+    # the named jit is still a jit to every other rule
+    assert not _rules(clean, "TS005")
+    branching = """
+    import functools
+    from presto_tpu.telemetry import kernels as _kernels
+
+    @functools.partial(_kernels.jit, family="fixture",
+                       static_argnums=(1,))
+    def kernel(x, n):  # lint-ok: TS005 fixture kernel
+        if x > 0:
+            return x
+        return x + n
+    """
+    assert _rules(branching, "TS001")
 
 
 def test_ts005_jits_list_variable_resolves():
