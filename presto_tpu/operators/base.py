@@ -169,6 +169,12 @@ class OperatorContext:
             from presto_tpu.execution.memory import batch_bytes
             pool.reserve(self.tag, batch_bytes(batch))
 
+    def reserve_bytes(self, nbytes: int) -> None:
+        """Device state that is no Batch (a join's direct table)."""
+        pool = self.driver_context.memory
+        if pool is not None:
+            pool.reserve(self.tag, nbytes)
+
     def release_all(self) -> None:
         pool = self.driver_context.memory
         if pool is not None:

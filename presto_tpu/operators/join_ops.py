@@ -27,6 +27,7 @@ from presto_tpu.operators.base import (
 from presto_tpu.ops import common as ops_common
 from presto_tpu.ops import join as join_ops
 from presto_tpu.telemetry import kernels as _kernels
+from presto_tpu.telemetry.metrics import METRICS
 
 
 class JoinCapacityExceeded(Exception):
@@ -142,7 +143,8 @@ class HashBuildOperator(Operator):
                  key_dicts: Optional[List[Optional[tuple]]] = None,
                  schema_cols: Optional[Sequence[tuple]] = None,
                  spillable: bool = False,
-                 df_publish: Optional[List[tuple]] = None):
+                 df_publish: Optional[List[tuple]] = None,
+                 consumer_layouts: Sequence[str] = ("sorted",)):
         super().__init__(ctx)
         self.bridge = bridge
         self.key_names = key_names
@@ -152,6 +154,19 @@ class HashBuildOperator(Operator):
         self._spill = None  # part -> [host Batch] once revoked
         self._total = None
         self._finished = False
+        #: why this build keeps the sorted layout (a `reason` of
+        #: presto_tpu_join_direct_fallback_total), None while the
+        #: direct one is still possible. What the plan fixes is known
+        #: here; dtype, spread and uniqueness only the rows show
+        #: (ops/join.py, "The DIRECT layout"). A build revoked to host
+        #: RAM never gets this far: its parts are indexed one by one,
+        #: sorted (SpilledBuild.build_part)
+        self._sorted_because: Optional[str] = (
+            "join_type" if "direct" not in consumer_layouts
+            else "multi_key" if len(key_names) != 1 else None)
+        #: int64 [rows, min key, max key] while the direct layout is
+        #: possible: takes the place of `_total`
+        self._key_stats = None
         #: dynamic filtering: [(key_name, df_id, registry)] — running
         #: min/max per named key, published at finish
         self._df_publish = df_publish or []
@@ -187,12 +202,30 @@ class HashBuildOperator(Operator):
         # flight while later batches stream, so finish()'s one blocking
         # read usually finds the bytes already on the host instead of
         # paying a full device roundtrip
-        t = jnp.sum(batch.row_valid)
-        self._total = t if self._total is None else self._total + t
+        if self._direct_candidate(batch):
+            # the key's range rides the same fetch
+            c = batch.columns[self.key_names[0]]
+            self._key_stats = running = join_ops.key_stats_step(
+                join_ops.key_stats_init() if self._key_stats is None
+                else self._key_stats, c.data, c.mask, batch.row_valid)
+        else:
+            t = jnp.sum(batch.row_valid)
+            self._total = running = t if self._total is None \
+                else self._total + t
         try:
-            self._total.copy_to_host_async()
+            running.copy_to_host_async()
         except (AttributeError, RuntimeError):
             pass
+
+    def _direct_candidate(self, batch: Batch) -> bool:
+        """Still possible after seeing this batch's key dtype: `key -
+        min` addresses a table only for an integer (BIGINT, INTEGER,
+        DATE, dictionary codes after the unified remap)."""
+        if self._sorted_because is None and not jnp.issubdtype(
+                batch.columns[self.key_names[0]].data.dtype,
+                jnp.integer):
+            self._sorted_because = "dtype"
+        return self._sorted_because is None
 
     # -- spill (memory revocation) ------------------------------------
 
@@ -269,8 +302,13 @@ class HashBuildOperator(Operator):
             return
         # one device->host sync for the whole build side (not per batch)
         from presto_tpu.native.pages import to_host
-        total = int(to_host(self._total)) if self._total is not None \
-            else 0
+        key_stats = to_host(self._key_stats) \
+            if self._key_stats is not None else None
+        if key_stats is not None:
+            total = int(key_stats[0])
+        else:
+            total = int(to_host(self._total)) \
+                if self._total is not None else 0
         # shape bucketing: the probe kernel's jit cache keys on the
         # BUILD table shape too — landing build capacities on the
         # coarse ladder lets different tables/scale factors reuse one
@@ -288,9 +326,36 @@ class HashBuildOperator(Operator):
         else:
             raise RuntimeError("empty build side needs schema plumbing")
         self._publish_df(merged)
-        self.bridge.table = join_ops.build_for_backend(
-            merged, self.key_names)
+        table = self._build_direct(merged, key_stats)
+        if table is None:
+            METRICS.inc("presto_tpu_join_direct_fallback_total",
+                        reason=self._sorted_because)
+            table = join_ops.build_for_backend(merged, self.key_names)
+        METRICS.inc("presto_tpu_join_builds_total", layout=table.layout)
+        self.bridge.table = table
         self._batches = []
+
+    def _build_direct(self, merged: Batch, key_stats):
+        """The direct table when the build side allows it, else None
+        with `_sorted_because` saying why not. `key_stats` is the
+        host's copy of `_key_stats` (None: no batch ever arrived)."""
+        if not self._direct_candidate(merged):
+            return None
+        stats = self._key_stats
+        if key_stats is None:       # an empty build: the empty range
+            stats = key_stats = join_ops.key_stats_init()
+        table_len = join_ops.direct_table_len(
+            int(key_stats[1]), int(key_stats[2]), merged.capacity)
+        if table_len is None:
+            self._sorted_because = "spread"
+            return None
+        table = join_ops.build_direct(merged, self.key_names[0], stats,
+                                      table_len)
+        if table is None:
+            self._sorted_because = "duplicate"
+            return None
+        self.ctx.reserve_bytes(4 * table_len)
+        return table
 
     def is_finished(self) -> bool:
         return self._finished
@@ -431,12 +496,17 @@ def make_probe_kernel(key_names: Tuple[str, ...], join_type: str,
 
         if _pre_batch is None:
             def kernel(table, batch, matched, out_capacity: int):
+                if table.layout == "direct":
+                    lo_enc = join_ops._direct_jit(table, batch,
+                                                  key_names)
+                    return stage2(table, batch, lo_enc, None, matched,
+                                  out_capacity)
                 h, h2 = join_ops._hash_jit(batch, key_names)
                 lo_enc = join_ops._search_jit(table, h, h2, verify)
                 return stage2(table, batch, lo_enc, h2, matched,
                               out_capacity)
             jit_list = [stage2, join_ops._hash_jit,
-                        join_ops._search_jit]
+                        join_ops._search_jit, join_ops._direct_jit]
         else:
             # the upstream chain + remap fold into the HASH dispatch
             # (stage0): still two probe-side materializations, but
@@ -449,12 +519,25 @@ def make_probe_kernel(key_names: Tuple[str, ...], join_type: str,
                 h, h2 = join_ops._probe_hashes(b, key_names)
                 return b, h, h2
 
+            # a direct table hashes nothing: its stage0 is the chain
+            # and the one-gather search
+            @functools.partial(_kernels.jit, family=family,
+                               part=f"{part}_stage0_direct")
+            def stage0_direct(table, batch):
+                b = _pre_batch(batch)
+                return b, join_ops._direct_enc(table, b, key_names)
+
             def kernel(table, batch, matched, out_capacity: int):
+                if table.layout == "direct":
+                    b, lo_enc = stage0_direct(table, batch)
+                    return stage2(table, b, lo_enc, None, matched,
+                                  out_capacity)
                 b, h, h2 = stage0(batch)
                 lo_enc = join_ops._search_jit(table, h, h2, verify)
                 return stage2(table, b, lo_enc, h2, matched,
                               out_capacity)
-            jit_list = [stage0, stage2, join_ops._search_jit]
+            jit_list = [stage0, stage0_direct, stage2,
+                        join_ops._search_jit]
     else:
         @functools.partial(_kernels.jit, family=family, part=part,
                            static_argnums=(3,))
@@ -572,7 +655,10 @@ class LookupJoinOperator(Operator):
             and not self._finishing
 
     def _probe(self, table, batch: Batch) -> Batch:
-        cap = bucket_capacity(batch.capacity * self.expansion_factor)
+        # a direct table's keys are unique: no probe row expands, so
+        # the output is aligned to the probe batch whatever the factor
+        cap = batch.capacity if table.layout == "direct" else \
+            bucket_capacity(batch.capacity * self.expansion_factor)
         if self.join_type == "full" and self._matched is None:
             self._matched = jnp.zeros(table.sorted_hash.shape[0],
                                       dtype=bool)
@@ -768,7 +854,8 @@ class HashBuildOperatorFactory(OperatorFactory):
                  key_dicts: Optional[List[Optional[tuple]]] = None,
                  schema_cols: Optional[Sequence[tuple]] = None,
                  spillable: bool = False,
-                 df_publish: Optional[List[tuple]] = None):
+                 df_publish: Optional[List[tuple]] = None,
+                 consumer_layouts: Sequence[str] = ("sorted",)):
         super().__init__(operator_id, "hash_build")
         self.bridge = bridge
         self.key_names = tuple(key_names)
@@ -776,12 +863,16 @@ class HashBuildOperatorFactory(OperatorFactory):
         self.schema_cols = schema_cols
         self.spillable = spillable
         self.df_publish = df_publish
+        #: the build layouts the operator across the bridge can probe
+        #: (its factory's `readable_layouts`): a static fact of the plan
+        self.consumer_layouts = tuple(consumer_layouts)
 
     def create(self, driver_context: DriverContext) -> Operator:
         return HashBuildOperator(
             OperatorContext(self.operator_id, self.name, driver_context),
             self.bridge, self.key_names, self.key_dicts,
-            self.schema_cols, self.spillable, self.df_publish)
+            self.schema_cols, self.spillable, self.df_publish,
+            self.consumer_layouts)
 
 
 class LookupJoinOperatorFactory(OperatorFactory):
@@ -817,6 +908,15 @@ class LookupJoinOperatorFactory(OperatorFactory):
         self.fused_sel_provenance = "static"
         self._pre = None        # (body, chain_key) upstream chain
         self._kernels = None
+
+    @staticmethod
+    def readable_layouts(join_type: str) -> Tuple[str, ...]:
+        """Build layouts a lookup join of `join_type` can probe. The
+        direct one has no hash runs, so only the aligned expansion
+        reads it: inner and left. FULL keeps per-build-row matched
+        flags in sorted order; semi/anti joins are another operator."""
+        return join_ops.LAYOUTS if join_type in ("inner", "left") \
+            else ("sorted",)
 
     @property
     def fused(self) -> bool:

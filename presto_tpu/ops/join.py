@@ -4,7 +4,12 @@ generated PagesHashStrategy over PagesIndex.java:75; partitioning
 design after Balkesen et al., "Main-Memory Hash Joins on Multi-Core
 CPUs", ICDE 2013).
 
-TPU-native design: no pointer-chasing hash table. The build side is
+TPU-native design: no pointer-chasing hash table. Two build layouts
+share the expand stage and nothing else. A build with ONE unique
+integer key whose range is the order of its row count is addressed
+directly (`build_direct`: `slot_of[key - min]`, one gather a probe
+row, nothing hashed or sorted; chosen by HashBuildOperator.finish
+from what the build side shows). Every other build side is
 *sorted by key hash* once; `build_for_backend` then records, per
 top-`radix_bits` hash prefix, where that bucket starts in the sorted
 order (`part_starts`, one bucket per ~build row), the length of every
@@ -87,6 +92,20 @@ MAX_RADIX_BITS = 18
 #: whole-table search is already that shallow)
 MIN_RADIX_ROWS = 1024
 
+#: the DIRECT layout (one int32 slot per key value in [min, max]) is
+#: taken only while the table stays the order of the build itself:
+#: 8 slots a build row is 32 bytes, what the sorted layout's two
+#: hashes, run length and permutation index cost per row, and covers
+#: a primary key thinned by a filter to an eighth of its range.
+DIRECT_MAX_SPREAD_FACTOR = 8
+#: ... and never past 2^27 entries (512 MB of int32, 3% of a 16 GB
+#: chip), whatever the build's capacity. A sparser or wider key domain
+#: keeps the sorted layout, whose cost follows rows and not range.
+DIRECT_MAX_SPREAD = 1 << 27
+
+#: build layouts a probe may be handed (BuildTable.layout)
+LAYOUTS = ("sorted", "direct")
+
 #: verify modes: "hash" elides the per-candidate full-key compare via
 #: the second independent hash; "full" gathers and compares every key
 #: column (the pre-radix behavior — collision fallback + test oracle).
@@ -95,29 +114,44 @@ VERIFY_MODES = ("hash", "full")
 
 @dataclasses.dataclass
 class BuildTable:
-    """Sorted-by-hash build side, ready for probing. A pytree whose
-    AUX DATA carries the static search/layout parameters.
-    `batch` rows are IN sorted-hash order (every column follows the
-    build's hash permutation), so a probe candidate at sorted
-    slot s reads batch row s directly — no index indirection."""
-    sorted_hash: jnp.ndarray          # [n] int64, invalid rows at +inf end
-    hash2: jnp.ndarray                # [n] int64 second hash (verify)
-    part_starts: jnp.ndarray          # [2^k + 1] int64 bucket offsets
-    run_len: jnp.ndarray              # [n] int64: run length AT run starts
+    """Build side, ready for probing. A pytree whose AUX DATA carries
+    the static search/layout parameters.
+
+    `layout == "sorted"`: `batch` rows are IN sorted-hash order (every
+    column follows the build's hash permutation), so a probe candidate
+    at sorted slot s reads batch row s directly — no index
+    indirection. The direct fields are None.
+
+    `layout == "direct"` (one unique integer key, see `build_direct`):
+    `batch` stays in ARRIVAL order, `slot_of[key - key_min]` is the
+    batch row holding that key (-1: no such key), and the four
+    sorted-hash fields are None. `unique_runs` is True by
+    construction."""
+    sorted_hash: Optional[jnp.ndarray]  # [n] int64, invalid rows at +inf end
+    hash2: Optional[jnp.ndarray]      # [n] int64 second hash (verify)
+    part_starts: Optional[jnp.ndarray]  # [2^k + 1] int64 bucket offsets
+    run_len: Optional[jnp.ndarray]    # [n] int64: run length AT run starts
     valid_count: jnp.ndarray          # scalar: live build rows
-    batch: Batch                      # build rows, sorted by key hash
+    batch: Batch                      # build rows (order: see layout)
     radix_bits: int = 0               # STATIC: k (0 = whole-table)
     search_depth: int = 64            # STATIC: bounded-search iterations
     unique_runs: bool = False         # STATIC: every valid run has len 1
+    layout: str = "sorted"            # STATIC: one of LAYOUTS
+    slot_of: Optional[jnp.ndarray] = None   # [R] int32 row, -1 = empty
+    key_min: Optional[jnp.ndarray] = None   # scalar int64, a LEAF: a
+    key_max: Optional[jnp.ndarray] = None   # new literal compiles nothing
 
 
 jax.tree_util.register_pytree_node(
     BuildTable,
     lambda t: ((t.sorted_hash, t.hash2, t.part_starts, t.run_len,
-                t.valid_count, t.batch),
-               (t.radix_bits, t.search_depth, t.unique_runs)),
-    lambda aux, c: BuildTable(*c, radix_bits=aux[0], search_depth=aux[1],
-                              unique_runs=aux[2]),
+                t.valid_count, t.batch, t.slot_of, t.key_min,
+                t.key_max),
+               (t.radix_bits, t.search_depth, t.unique_runs, t.layout)),
+    lambda aux, c: BuildTable(*c[:6], radix_bits=aux[0],
+                              search_depth=aux[1], unique_runs=aux[2],
+                              layout=aux[3], slot_of=c[6],
+                              key_min=c[7], key_max=c[8]),
 )
 
 #: int64 sentinel pushing NULL-key/invalid build rows to the sorted end
@@ -319,6 +353,115 @@ def build(batch: Batch, key_names: Tuple[str, ...],
 
 
 # ---------------------------------------------------------------------------
+# The DIRECT layout: one unique integer key, `key - min` is the address
+# (the array join of DuckDB's perfect hash join / HyPer). Nothing is
+# hashed, sorted or permuted; see docs/JOIN_KERNEL.md, "The direct
+# layout". The choice is the operator's (operators/join_ops.py,
+# HashBuildOperator.finish), from what the build side itself shows.
+
+
+@functools.partial(_kernels.jit, family="join_build", part="stats")
+def key_stats_step(stats: jnp.ndarray, data: jnp.ndarray,
+                   mask: jnp.ndarray, row_valid: jnp.ndarray):
+    """Fold one build batch into int64 [rows, min key, max key]: rows
+    counts `row_valid` (NULL keys included: they occupy the table),
+    min/max read live non-NULL keys only. One array, so the operator's
+    one blocking fetch at finish brings all three."""
+    live = row_valid & mask
+    k = data.astype(jnp.int64)
+    info = jnp.iinfo(jnp.int64)
+    return jnp.stack([
+        stats[0] + jnp.sum(row_valid, dtype=jnp.int64),
+        jnp.minimum(stats[1], jnp.min(jnp.where(live, k, info.max))),
+        jnp.maximum(stats[2], jnp.max(jnp.where(live, k, info.min)))])
+
+
+def key_stats_init() -> np.ndarray:
+    """No rows, and the empty range (min > max) that no key is in."""
+    info = np.iinfo(np.int64)
+    return np.asarray([0, info.max, info.min], np.int64)
+
+
+def direct_table_len(key_min: int, key_max: int,
+                     capacity: int) -> Optional[int]:
+    """HOST: the direct table's length for live keys in [key_min,
+    key_max] — their spread rounded up to a power of two, a static
+    shape, so a build side compiles a handful of variants — or None
+    when the spread is too wide for `capacity` rows (the sorted layout
+    then). An empty range (min > max: no live key) takes length 1."""
+    spread = max(int(key_max) - int(key_min) + 1, 1)
+    if spread > min(DIRECT_MAX_SPREAD_FACTOR * capacity,
+                    DIRECT_MAX_SPREAD):
+        return None
+    return 1 << (spread - 1).bit_length()
+
+
+@functools.partial(_kernels.jit, family="join_build", part="direct",
+                   static_argnums=(1, 3))
+def _build_direct(batch: Batch, key_name: str, stats: jnp.ndarray,
+                  table_len: int):
+    """Device build: ONE scatter `slot_of[key - min] = row` over the
+    live rows and one gather back. A slot two rows wrote keeps one of
+    them, so the other reads back a row that is not itself: the
+    all-reduce of `slot_of[key - min] == row` is the uniqueness flag.
+    `stats` is key_stats_step's array: min and max stay device values
+    (a different literal or seed compiles nothing)."""
+    c = batch.columns[key_name]
+    live = batch.row_valid & c.mask
+    key_min, key_max = stats[1], stats[2]
+    rows = jnp.arange(live.shape[0], dtype=jnp.int32)
+    # dead rows address one past the end: dropped by the scatter
+    at = jnp.where(live, c.data.astype(jnp.int64) - key_min,
+                   table_len).astype(jnp.int32)
+    slot_of = jnp.full(table_len, -1, jnp.int32).at[at].set(
+        rows, mode="drop")
+    back = slot_of[jnp.minimum(at, table_len - 1)]
+    unique = jnp.all(~live | (back == rows))
+    return slot_of, key_min, key_max, jnp.sum(live), unique
+
+
+def build_direct(batch: Batch, key_name: str, stats: jnp.ndarray,
+                 table_len: int) -> Optional[BuildTable]:
+    """The direct table over `batch` (rows stay in arrival order), or
+    None when two live rows share a key: the caller then builds the
+    sorted layout. One tiny fetch (the uniqueness flag), where the
+    sorted build fetches its span and run maxima."""
+    slot_of, key_min, key_max, vc, unique = _build_direct(
+        batch, key_name, stats, table_len)
+    if not bool(pages.to_host(unique)):
+        return None
+    return BuildTable(None, None, None, None, vc, batch,
+                      unique_runs=True, layout="direct",
+                      slot_of=slot_of, key_min=key_min, key_max=key_max)
+
+
+def _direct_enc(table: BuildTable, probe: Batch,
+                probe_keys: Tuple[str, ...]) -> jnp.ndarray:
+    """Per probe row: the build batch row holding its key, or -1.
+    Exact by construction (a slot holds the one row with that key), so
+    no verify mode applies. The range test comes BEFORE the
+    subtraction, so an extreme int64 key cannot wrap into the table."""
+    (name,) = probe_keys
+    data, mask = probe.columns[name].astuple()
+    if jnp.issubdtype(data.dtype, jnp.floating):
+        # the sorted layout hashes a float's bit pattern, which no
+        # integer build key shares: nothing matches there either
+        return jnp.full(data.shape, -1, jnp.int32)
+    k = data.astype(jnp.int64)
+    in_range = probe.row_valid & mask \
+        & (k >= table.key_min) & (k <= table.key_max)
+    at = jnp.where(in_range, k - table.key_min, 0).astype(jnp.int32)
+    brow = table.slot_of[at]
+    return jnp.where(in_range & (brow >= 0), brow, jnp.int32(-1))
+
+
+#: the CPU path's search dispatch for a direct table (XLA:TPU fuses it
+#: into the probe program)
+_direct_jit = _kernels.jit(_direct_enc, "join_probe", "direct",
+                          static_argnums=(2,))
+
+
+# ---------------------------------------------------------------------------
 # Probe stage 1: candidate search. On CPU it runs as TWO dispatches
 # (hash, then search) each with ONE expensive output, so XLA:CPU's
 # fusion emitter cannot re-materialize the hash chain into every
@@ -381,7 +524,11 @@ _search_jit = _kernels.jit(_search_enc, "join_probe", "search",
 def _candidates_enc(table: BuildTable, probe: Batch,
                     probe_keys: Tuple[str, ...],
                     verify: str = "hash") -> jnp.ndarray:
-    """Traceable single-region composition (the TPU fused path)."""
+    """Traceable single-region composition (the TPU fused path).
+    The table's layout is static: a direct table takes one gather and
+    hashes nothing."""
+    if table.layout == "direct":
+        return _direct_enc(table, probe, probe_keys)
     h, h2 = _probe_hashes(probe, probe_keys)
     return _search_enc(table, h, h2, verify)
 
@@ -391,6 +538,8 @@ def _candidates_cpu(table: BuildTable, probe: Batch,
                     verify: str = "hash") -> jnp.ndarray:
     """Two-dispatch composition (the CPU path) — still zero host
     syncs, the stages just materialize their one hot output each."""
+    if table.layout == "direct":
+        return _direct_jit(table, probe, probe_keys)
     h, h2 = _hash_jit(probe, probe_keys)
     return _search_jit(table, h, h2, verify)
 
@@ -490,8 +639,11 @@ def probe_join(table: BuildTable, probe: Batch,
       output compaction (its d2h copy starts immediately, so the read
       a driver round later is normally a cache hit)."""
     if common.cpu_backend():
-        h, h2 = _hash_jit(probe, key_names)
-        lo_enc = _search_jit(table, h, h2, verify)
+        if table.layout == "direct":
+            lo_enc, h2 = _direct_jit(table, probe, key_names), None
+        else:
+            h, h2 = _hash_jit(probe, key_names)
+            lo_enc = _search_jit(table, h, h2, verify)
         out, overflow, total, _ = _expand_dispatch(
             table, probe, key_names, lo_enc, h2, None, out_capacity,
             join_type, probe_output, build_output, build_keys, verify)
@@ -559,6 +711,11 @@ def _expand_from_enc(table, probe, key_names, lo_enc, matched,
         and join_type in ("inner", "left", "full")
         and out_capacity == probe.row_valid.shape[0]
     )
+    # a direct table has no hash runs to expand: its builder promised
+    # a consumer that reads it aligned (HashBuildOperator.finish)
+    assert aligned or table.layout != "direct", \
+        f"direct build table probed unaligned: {join_type} join, " \
+        f"capacity {out_capacity} for {probe.row_valid.shape[0]} rows"
     if aligned:
         out, overflow, brow, verified = _expand_aligned(
             table, probe, key_names, lo_enc, join_type, probe_output,
@@ -840,9 +997,11 @@ _instr = _kernels.instrument_kernel
 build_for_backend = _instr(
     build_for_backend, "join_build",
     jits=[_build_sorted, _build_hash, _build_apply_perm])
+build_direct = _instr(build_direct, "join_build", jits=[_build_direct])
+key_stats_step = _instr(key_stats_step, "join_build")
 probe_join = _instr(
     probe_join, "join_probe",
-    jits=[_hash_jit, _search_jit, _expand_dispatch,
+    jits=[_hash_jit, _search_jit, _direct_jit, _expand_dispatch,
           _probe_join_fused, _expand_general_jit])
 probe_join_full = _instr(
     probe_join_full, "join_probe",
@@ -888,9 +1047,58 @@ def _abstract_table(n: int, k: int, unique: bool, depth: int = 8):
     return t, rt
 
 
+def _abstract_direct_table(n: int, table_len: int):
+    from presto_tpu.analysis.contracts import sds
+    from presto_tpu.types import BIGINT, DOUBLE
+    import numpy as _np
+    batch, rbatch = abstract_batch(n, [("bk", BIGINT), ("bv", DOUBLE)])
+    scalar = sds((), _np.int64)
+    t = BuildTable(None, None, None, None, scalar, batch,
+                   unique_runs=True, layout="direct",
+                   slot_of=sds((table_len,), _np.int32),
+                   key_min=scalar, key_max=scalar)
+    # join_build's direct contract proves slot_of addresses live rows
+    # only (dead rows scatter out of range), so the probe may assume it
+    rt = BuildTable(None, None, None, None, "clean", rbatch,
+                    unique_runs=True, layout="direct", slot_of="clean",
+                    key_min="clean", key_max="clean")
+    return t, rt
+
+
 def _probe_schema():
     from presto_tpu.types import BIGINT, DOUBLE
     return [("pk", BIGINT), ("pv", DOUBLE)]
+
+
+def _build_direct_point(cap, variant):
+    from presto_tpu.analysis.contracts import sds
+    import numpy as _np
+    b, rb = abstract_batch(cap, _probe_schema())
+    return TracePoint(
+        lambda bb, st: _build_direct(bb, "pk", st, 8 * cap),
+        (b, sds((3,), _np.int64)), (rb, "clean"))
+
+
+def _key_stats_point(cap, variant):
+    from presto_tpu.analysis.contracts import sds
+    import numpy as _np
+    b, rb = abstract_batch(cap, [("pk", _probe_schema()[0][1])])
+    c, rc = b.columns["pk"], rb.columns["pk"]
+    return TracePoint(
+        lambda st, d, m, rv: key_stats_step.__wrapped__(st, d, m, rv),
+        (sds((3,), _np.int64), c.data, c.mask, b.row_valid),
+        ("clean", rc.data, rc.mask, rb.row_valid))
+
+
+def _probe_direct_point(cap, variant):
+    t, rt = _abstract_direct_table(4096, 32768)
+    p, rp = abstract_batch(cap, _probe_schema())
+    jt = variant.get("join_type", "inner")
+    return TracePoint(
+        lambda tt, pp: _probe_join_fused(
+            tt, pp, ("pk",), None, cap, jt, ("pk", "pv"), ("bv",),
+            ("bk",), "hash"),
+        (t, p), (rt, rp))
 
 
 def _build_point(cap, variant):
@@ -970,8 +1178,24 @@ register_contract(KernelContract(
     family="join_build", module=__name__, build=_build_perm_point,
     notes="permutation-apply stage of the CPU host-argsort build"))
 register_contract(KernelContract(
+    family="join_build", module=__name__, build=_build_direct_point,
+    notes="direct layout: one scatter by key - min, one gather back "
+          "for the uniqueness flag; dead rows scatter out of range"))
+register_contract(KernelContract(
+    family="join_build", module=__name__, build=_key_stats_point,
+    notes="per-batch fold of [rows, min key, max key], the one array "
+          "the operator fetches at finish"))
+register_contract(KernelContract(
     family="join_probe", module=__name__, build=_probe_point,
     notes="inner probe, general (duplicate-run) expand layout"))
+register_contract(KernelContract(
+    family="join_probe", module=__name__, build=_probe_direct_point,
+    notes="inner probe of a direct table: range test, one int32 "
+          "gather, aligned expand"))
+register_contract(KernelContract(
+    family="join_probe", module=__name__,
+    build=lambda cap, v: _probe_direct_point(cap, {"join_type": "left"}),
+    notes="left probe of a direct table"))
 register_contract(KernelContract(
     family="join_probe", module=__name__,
     build=lambda cap, v: _probe_point(cap, {"join_type": "left"}),

@@ -897,7 +897,9 @@ class LocalExecutionPlanner:
                 spillable=bool(get_property(self.session.properties,
                                             "spill_enabled"))
                 and jt != "full",
-                df_publish=df_publish))
+                df_publish=df_publish,
+                consumer_layouts=LookupJoinOperatorFactory
+                .readable_layouts(jt)))
             self._pipelines.append(build_pipe)
             self._visit(probe, pipe)
             # stats-seeded output capacity: a many-to-many join whose

@@ -216,6 +216,18 @@ METRICS.describe("presto_tpu_xla_compiles_total",
 METRICS.describe("presto_tpu_xla_compile_seconds_total",
                  "Seconds of the backend compile events counted by "
                  "presto_tpu_xla_compiles_total, by kernel family")
+METRICS.describe("presto_tpu_join_builds_total",
+                 "Join build sides indexed at HashBuildOperator.finish, "
+                 "by layout: direct = one unique integer key addressed "
+                 "by key - min, sorted = the sorted-hash layout (spilled "
+                 "builds' per-part tables are not counted)")
+METRICS.describe("presto_tpu_join_direct_fallback_total",
+                 "Builds that kept the sorted layout, by the first "
+                 "reason the direct one was ruled out: join_type (the "
+                 "consumer reads only the sorted layout: FULL, semi/"
+                 "anti), multi_key, dtype (not "
+                 "an integer), spread (max - min + 1 over 8 x capacity "
+                 "or 2^27), duplicate (two live rows share a key)")
 METRICS.describe("presto_tpu_protocol_ns_total",
                  "Client-protocol ns on the coordinator by phase: "
                  "accept = POST /v1/statement in to response out, "

@@ -175,6 +175,34 @@ def test_join_kernels(one_chip, tpu_forks, family, cap):
     _compile(point.fn, point.args, one_chip)
 
 
+@pytest.mark.parametrize("program", ["stats", "build", "probe_inner",
+                                     "probe_left"])
+def test_direct_join_kernels_at_q3_size(one_chip, tpu_forks, program):
+    """The direct layout's programs at the shapes Q3's first join has
+    at sf1: a 1M-lane build side (date-filtered orders) whose keys
+    spread over 2^21 slots, probed by 1M-row lineitem batches."""
+    from presto_tpu.ops import join
+    slots = 1 << 21
+    if program == "stats":
+        fn = join.key_stats_step.__wrapped__
+        args = (_sds(3, jnp.int64), _sds(BATCH, jnp.int64),
+                _sds(BATCH, jnp.bool_), _sds(BATCH, jnp.bool_))
+    elif program == "build":
+        batch, _ = join.abstract_batch(BATCH, join._probe_schema())
+        fn = lambda b, st: join._build_direct(b, "pk", st, slots)  # noqa: E731
+        args = (batch, _sds(3, jnp.int64))
+    else:
+        table, _ = join._abstract_direct_table(BATCH, slots)
+        probe, _ = join.abstract_batch(BATCH, join._probe_schema())
+        jt = program[len("probe_"):]
+        fn = lambda t, p: join._probe_join_fused(  # noqa: E731
+            t, p, ("pk",), None, BATCH, jt, ("pk", "pv"), ("bv",),
+            ("bk",), "hash")
+        args = (table, probe)
+    text = _compile(fn, args, one_chip).as_text()
+    assert ("sort(" in text) is False, "the direct layout sorts nothing"
+
+
 @pytest.mark.parametrize("family", ["spmd_shuffle", "spmd_fragment"])
 def test_exchange_shard_map_on_four_chips(topo, tpu_forks, monkeypatch,
                                           family):
