@@ -257,8 +257,7 @@ def mesh(devices, n: int) -> None:
     """Q1 and Q3 at sf1 through MeshRunner on `n` chips, row for row
     against LocalRunner on one chip; then proof that the shuffle was an
     all_to_all and that every chip held shards and did work."""
-    from presto_tpu.parallel import make_mesh
-    from presto_tpu.runner import LocalRunner, MeshRunner
+    from presto_tpu.runner import LocalRunner, runner_for
     from presto_tpu.telemetry.metrics import METRICS
     from tpch_queries import QUERIES
 
@@ -266,7 +265,9 @@ def mesh(devices, n: int) -> None:
     props = {"batch_rows": BATCH_ROWS,
              "fragment_result_cache_enabled": False}
     with phase(f"build runners ({n}-chip mesh, one-chip local)"):
-        dist = MeshRunner("tpch", SCHEMA, dict(props), mesh=make_mesh(n))
+        # the one way to ask for a mesh: the property the served
+        # coordinator reads (runner_for refuses more chips than visible)
+        dist = runner_for("tpch", SCHEMA, {**props, "mesh_devices": n})
         local = LocalRunner("tpch", SCHEMA, dict(props))
     for q in (1, 3):
         with phase(f"q{q} mesh cold"):
@@ -297,10 +298,9 @@ def mesh(devices, n: int) -> None:
     say(f"all_to_all waves: {waves}, rows: "
         f"{int(METRICS.total('presto_tpu_exchange_all_to_all_rows_total'))}")
     assert waves > 0, "the mesh ran no all_to_all wave"
-    # scan batches are made on the first chip and copied to each
-    # task's chip per query (planner/local_planner.py), so nothing
-    # stays on chips 1..n-1 between queries: the proof that each held
-    # shards is its high-water mark
+    # each task's scan batches are made on its own chip and stay in
+    # the page-source cache there (planner/local_planner.py); the
+    # high-water mark also covers what a task held only mid-query
     peaks = _device_peaks(devices[:n])
     say("peak_bytes_in_use per device: " + json.dumps(peaks))
     assert all(peaks.values()), \

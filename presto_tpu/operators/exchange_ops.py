@@ -668,9 +668,8 @@ class ExchangeSourceOperator(Operator):
     def get_output(self) -> Optional[Batch]:
         b = self.exchange.pop(self.consumer)
         if b is not None and self.device is not None:
-            from presto_tpu.telemetry import ledger as _ledger
-            with _ledger.span("h2d"):
-                b = jax.device_put(b, self.device)
+            from presto_tpu.parallel.mesh import place
+            b = place(b, self.device)
         return self._count_out(b) if b is not None else None
 
     def finish(self) -> None:

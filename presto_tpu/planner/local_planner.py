@@ -428,8 +428,14 @@ class LocalExecutionPlanner:
 
         # page-source cache (presto_tpu/cache level 3): raw connector
         # output per (table version, split, columns, constraint),
-        # cached BEFORE the per-query rename and device placement so
-        # every query shape can share the entry
+        # cached BEFORE the per-query rename so every query shape can
+        # share the entry. A mesh task's entry holds batches already
+        # on the task's chip and its key names the chip, so a warm
+        # scan moves no byte (split assignment is deterministic: a
+        # split's entry is always read by the same chip). The first
+        # device is where a connector's batches land anyway: its
+        # tasks, like the one-chip path (task.device None), keep the
+        # plain key and share their entries with it
         page_cache = None
         tv = None
         cache_box = {"hits": 0, "misses": 0}
@@ -444,9 +450,19 @@ class LocalExecutionPlanner:
                     self.session.properties).page
 
         def batch_iter():
+            import contextlib
             import jax as _jax
             from presto_tpu.cache import split_token
             from presto_tpu.execution.memory import batch_bytes
+            from presto_tpu.parallel.mesh import place
+            device = task.device
+            # a fresh batch is made on the task's chip, not on the
+            # first and copied over
+            on_chip = _jax.default_device(device) \
+                if device is not None else contextlib.nullcontext()
+            keyed_by = () if device is None \
+                or device == _jax.devices()[0] \
+                else (("device", device.id),)
             splits = conn.split_manager.get_splits(
                 handle, max(target_splits, task.count), constraint)
             if task.count > 1:
@@ -465,7 +481,7 @@ class LocalExecutionPlanner:
                             key = ("page", tv, handle.catalog,
                                    handle.schema, handle.table,
                                    st, tuple(columns),
-                                   batch_rows, constraint)
+                                   batch_rows, constraint) + keyed_by
                             hash(key)
                         except TypeError:
                             key = None  # unhashable constraint payload
@@ -489,14 +505,19 @@ class LocalExecutionPlanner:
                     # __next__ is where per-query datagen, file
                     # decode, and page assembly burn host time — the
                     # biggest slice of the caches-off glue gap
-                    if _ledger.current() is not None:
-                        with _ledger.span("scan"):
+                    with on_chip:
+                        if _ledger.current() is not None:
+                            with _ledger.span("scan"):
+                                b = next(it, _SCAN_DONE)
+                        else:
                             b = next(it, _SCAN_DONE)
-                    else:
-                        b = next(it, _SCAN_DONE)
                     if b is _SCAN_DONE:
                         exhausted = True
                         break
+                    if device is not None:
+                        # commits the batch to the chip; a copy (and a
+                        # charge) only where it was made elsewhere
+                        b = place(b, device)
                     if _faults.ARMED:
                         # fault site `page_source.next`: every batch a
                         # connector yields, cached or fresh
@@ -510,17 +531,7 @@ class LocalExecutionPlanner:
                             acc = None  # too big — stream uncached
                         else:
                             acc.append(b)
-                    out = b.rename(rename)
-                    if task.device is not None:
-                        with _ledger.span("h2d"):
-                            out = _jax.device_put(out, task.device)
-                        from presto_tpu.telemetry.metrics import (
-                            METRICS,
-                        )
-                        METRICS.inc(
-                            "presto_tpu_transfer_bytes_total",
-                            batch_bytes(out), direction="h2d")
-                    yield out
+                    yield b.rename(rename)
                 if exhausted:
                     # natural exhaustion only: an abandoned iterator
                     # (downstream LIMIT) must not commit a partial split

@@ -1161,11 +1161,13 @@ class LocalRunner:
             # unknown names reject like SET would — a typo must not
             # silently leave the real override in place
             self._reject_request_scoped_mutation()
-            from presto_tpu.session_properties import SESSION_PROPERTIES
-            if "." not in stmt.name \
-                    and stmt.name not in SESSION_PROPERTIES:
-                raise QueryError(
-                    f"unknown session property {stmt.name!r}")
+            from presto_tpu.session_properties import validate_set
+            try:
+                # NULL is RESET's value: the same gate as SET (unknown
+                # names, a deployment's fixed layout)
+                validate_set(stmt.name, None)
+            except ValueError as err:
+                raise QueryError(str(err)) from None
             self.session.properties.pop(stmt.name, None)
             return self._text_result("result", ["RESET SESSION"])
         if isinstance(stmt, T.CreateTableAs):

@@ -14,9 +14,12 @@ use the 8-virtual-device CPU mesh
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import time
 from typing import Any, Dict, List, Optional
 
+from presto_tpu import sanitize
 from presto_tpu.operators import exchange_ops
 from presto_tpu.operators.exchange_ops import MeshExchange, edge_key_dicts
 from presto_tpu.parallel.mesh import make_mesh
@@ -33,6 +36,22 @@ from presto_tpu.runner.local import (
 )
 
 
+#: one lock per mesh (keyed by its devices' ids, so two runners over
+#: the same chips share it): a wave is ONE SPMD program over every
+#: chip of the mesh, and two statements' waves interleaved on one mesh
+#: would each wait for the other's shards
+_MESH_LOCKS: Dict[tuple, Any] = {}
+_MESH_LOCKS_GUARD = sanitize.lock("runner.mesh_registry")
+
+
+def _mesh_lock(devices):
+    key = tuple(d.id for d in devices)
+    with _MESH_LOCKS_GUARD:
+        if key not in _MESH_LOCKS:
+            _MESH_LOCKS[key] = sanitize.lock("runner.mesh")
+        return _MESH_LOCKS[key]
+
+
 class MeshRunner(LocalRunner):
     def __init__(self, catalog: str = "tpch", schema: str = "tiny",
                  properties: Optional[Dict[str, Any]] = None,
@@ -43,6 +62,32 @@ class MeshRunner(LocalRunner):
         self.mesh = mesh if mesh is not None else make_mesh(n_workers)
         self.n_workers = int(self.mesh.devices.size)
         self._devices = list(self.mesh.devices.reshape(-1))
+        self._mesh_lock = _mesh_lock(self._devices)
+
+    @contextlib.contextmanager
+    def _whole_mesh(self):
+        """One statement's collectives at a time: the calling thread
+        holds the mesh for one _run_fragments. The wait is admission
+        by another name (the ledger's `queued`), counted in
+        presto_tpu_mesh_lock_wait_ns_total, and ends on the
+        statement's cancel or deadline like any drive round."""
+        from presto_tpu.runner.local import check_lifecycle
+        from presto_tpu.telemetry import ledger as _ledger
+        from presto_tpu.telemetry.metrics import METRICS
+        if not self._mesh_lock.acquire(blocking=False):
+            cancel, deadline = self._lifecycle()
+            t0 = time.perf_counter_ns()
+            try:
+                with _ledger.span("queued"):
+                    while not self._mesh_lock.acquire(timeout=0.05):
+                        check_lifecycle(cancel, deadline)
+            finally:
+                METRICS.inc("presto_tpu_mesh_lock_wait_ns_total",
+                            time.perf_counter_ns() - t0)
+        try:
+            yield
+        finally:
+            self._mesh_lock.release()
 
     # ------------------------------------------------------------------
 
@@ -86,7 +131,8 @@ class MeshRunner(LocalRunner):
         from presto_tpu.telemetry.metrics import METRICS
         while True:
             try:
-                out = self._run_fragments(fplan, session, profile)
+                with self._whole_mesh():
+                    out = self._run_fragments(fplan, session, profile)
                 METRICS.inc("presto_tpu_mesh_queries_total",
                             status="ok")
                 return out

@@ -291,6 +291,13 @@ class Coordinator(Node):
                     self._runner(), self.prewarm_sql)
             else:
                 self.prewarm_report = self._prewarm_workers()
+        from presto_tpu.session_properties import get_property
+        if self.single_node and int(get_property(
+                self.properties, "mesh_devices")) > 1:
+            # a deployment's layout is met at start or not at all: a
+            # mesh wider than the visible devices raises here, before
+            # the first client is told the server is up
+            self._runner()
         super().start()
         self._pruner.start()
         if self.membership is not None:
@@ -1186,12 +1193,15 @@ th{{background:#222}}
     def _runner(self):
         """The shared single-node runner (lazy; LocalRunner.execute is
         concurrency-safe — per-query pools, thread-local session
-        overrides)."""
+        overrides). With `mesh_devices` above 1 in the coordinator's
+        properties it is a MeshRunner over that many chips: the same
+        class behind the same calls, one statement's collectives at a
+        time (runner/mesh.py)."""
         with self._embedded_lock:
             if self._embedded_runner is None:
-                from presto_tpu.runner.local import LocalRunner
-                self._embedded_runner = LocalRunner(
-                    self.catalog, self.schema, dict(self.properties),
+                from presto_tpu.runner import runner_for
+                self._embedded_runner = runner_for(
+                    self.catalog, self.schema, self.properties,
                     access_control=self.access_control)
             return self._embedded_runner
 

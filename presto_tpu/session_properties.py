@@ -23,6 +23,9 @@ class PropertyDef:
     default: Any
     description: str
     validate: Optional[Callable[[Any], Optional[str]]] = None
+    #: a deployment's layout: read once, when the runner is built;
+    #: SET SESSION refuses it (a statement cannot re-shape the mesh)
+    fixed_at_start: bool = False
 
 
 def _positive(v) -> Optional[str]:
@@ -284,6 +287,17 @@ SESSION_PROPERTIES: Dict[str, PropertyDef] = {p.name: p for p in [
         "caches, charged to the cache manager's tagged MemoryPool; "
         "LRU entries evict when a new insert would exceed it",
         _positive),
+    PropertyDef(
+        "mesh_devices", "bigint", 1,
+        "Worker tasks of the deployment, one per chip: above 1 the "
+        "single-node coordinator (and runner.runner_for) builds a "
+        "MeshRunner over the first N of jax.devices(), whose "
+        "exchanges ride all_to_all over ICI; 1 = the one-chip "
+        "LocalRunner. A deployment's layout, read when the runner is "
+        "built: more than the visible devices fails at start, and "
+        "SET SESSION of it is refused (reference: the worker set of "
+        "'Deploying Presto'; docs/SHARDING.md)", _positive,
+        fixed_at_start=True),
 ]}
 
 
@@ -299,6 +313,10 @@ def validate_set(name: str, value: Any) -> Any:
         known = ", ".join(sorted(SESSION_PROPERTIES))
         raise ValueError(
             f"unknown session property {name!r} (known: {known})")
+    if p.fixed_at_start:
+        raise ValueError(
+            f"{name} is the deployment's layout, fixed when the "
+            "runner was built: set it in the coordinator's properties")
     if value is None:
         return p.default
     if p.type_name == "bigint":

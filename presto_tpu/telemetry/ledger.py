@@ -47,6 +47,10 @@ Category taxonomy (docs/OBSERVABILITY.md):
                   cache lookups (host-side expr compile included)
     scan          connector page-source next(): datagen, file decode
     h2d           host->device placement (device_put)
+    d2d           chip->chip placement: a scan or exchange-source
+                  batch copied to its task's device from another
+                  chip of the mesh (parallel/mesh.place; a batch
+                  already there charges nothing)
     compile       kernel calls that paid an XLA trace+compile
     dispatch      host wall issuing already-compiled kernels (async
                   dispatch — the device may still be working when the
@@ -104,7 +108,7 @@ from presto_tpu import sanitize
 
 #: the full category set, in rendering order
 CATEGORIES: Tuple[str, ...] = (
-    "queued", "planning", "scan", "h2d", "compile", "dispatch",
+    "queued", "planning", "scan", "h2d", "d2d", "compile", "dispatch",
     "device_wait", "d2h", "serde", "exchange", "exchange.all_to_all",
     "spool", "retry_backoff", "prefetch", "driver.step",
     "driver.reassembly", "driver.quantum",

@@ -247,9 +247,12 @@ METRICS.describe("presto_tpu_transport_retries_total",
 METRICS.describe("presto_tpu_backoff_sleep_ns_total",
                  "ns slept in transport retry backoff")
 METRICS.describe("presto_tpu_transfer_bytes_total",
-                 "host<->device transfer bytes by direction (d2h at "
-                 "exchange device_get, h2d at per-device scan "
-                 "placement)")
+                 "Transfer bytes by direction: d2h at exchange "
+                 "device_get; h2d where a scan or exchange-source "
+                 "batch is placed on its task's device from the host; "
+                 "d2d where it is copied there from another chip (a "
+                 "warm mesh scan makes neither: its page-cache entry "
+                 "lives on the chip that reads it)")
 METRICS.describe("presto_tpu_executor_quanta_total",
                  "TaskExecutor time slices by outcome (finished/"
                  "progress/blocked/idle/failed/stalled)")
@@ -284,7 +287,7 @@ METRICS.describe("presto_tpu_fleet_memory_sheds_total",
 METRICS.describe("presto_tpu_ledger_ns_total",
                  "Wall-attribution ledger ns by category "
                  "(telemetry/ledger.py: queued/planning/scan/h2d/"
-                 "compile/dispatch/device_wait/d2h/serde/exchange/"
+                 "d2d/compile/dispatch/device_wait/d2h/serde/exchange/"
                  "spool/retry_backoff/prefetch/driver.*), summed "
                  "over finished queries")
 METRICS.describe("presto_tpu_serde_bytes_total",
@@ -316,6 +319,10 @@ METRICS.describe("presto_tpu_exchange_all_to_all_bytes_total",
 METRICS.describe("presto_tpu_mesh_queries_total",
                  "Queries completed by the mesh (distributed) "
                  "runner, by status")
+METRICS.describe("presto_tpu_mesh_lock_wait_ns_total",
+                 "ns statements waited for their mesh: one "
+                 "statement's collectives run at a time on a mesh "
+                 "(runner/mesh.py), the wait is the ledger's `queued`")
 METRICS.describe("presto_tpu_mesh_retries_total",
                  "Mesh query re-executions by escalation kind "
                  "(max_groups/join_expansion/history_fusion/"
