@@ -449,7 +449,7 @@ class MeshExchange:
     def attach_chain(self, stages, chain_key, label: str) -> bool:
         """Absorb a fused-fragment chain into the wave program so the
         chain traces INSIDE the shard_map body (one jitted program per
-        shape bucket: chain + bucketize + all_to_all). Idempotent
+        shape bucket: chain + segment wave + all_to_all). Idempotent
         across the W producer tasks planning the same fragment: the
         first attach wins and later attaches must agree on the key."""
         if not self.chain_eligible() or chain_key is None:

@@ -6,16 +6,39 @@ ExchangeClient.java:81).
 
 A `ShardedBatch` is a Batch whose arrays carry a leading `workers` mesh
 axis: global shape [W, rows] sharded so each chip holds one [rows] slice.
-`hash_repartition` runs one shard_mapped program per chip:
+Every repartition entry point runs one shard_mapped program per chip
+(`_wave_body`), in which rows move as CONTIGUOUS SEGMENTS addressed by
+W counts, never lane by lane through an index array over W * rows lanes:
 
-  1. dest[i]   = hash(key columns)[i] mod W           (row -> consumer)
-  2. bucketize = stable sort by dest + segment offsets -> scatter rows
-                 into a [W, rows] send buffer (bucket d = rows for chip d;
-                 a chip holds <= rows live rows, so bucket capacity =
-                 rows is always overflow-free)
-  3. jax.lax.all_to_all over the `workers` axis swaps buckets so chip d
-     receives bucket d from every chip
-  4. flatten [W, rows] -> [W*rows] — the received batch
+  1. dest[i] = abs(hash(key columns)[i]) mod W, invalid rows to bucket W
+     (row -> consumer). One sort of dest gives `order`; the bucket
+     sizes are W masked sums `sum(dest == d)`, `offsets` their exclusive
+     prefix.
+  2. per array ONE gather g = a[order]: the rows grouped by
+     destination, source order kept inside a bucket. After it bucket d
+     is the contiguous run g[offsets[d] : offsets[d] + counts[d]].
+  3. the [W, rows] send buffer is W dynamic_slices of g of length
+     `rows` starting at offsets[d], each masked to its count (dead
+     lanes zeroed, so nothing a pad lane carried reaches the wire).
+     XLA clamps a dynamic_slice whose window runs past the end of its
+     operand, and a `rows`-lane window of a `rows`-lane operand can
+     only start at 0 — every bucket would silently read bucket 0's
+     rows — so the window is cut from g extended by `rows` dead lanes.
+     The bucket capacity stays `rows`: a chip holds <= rows live rows,
+     so no key skew can overflow a bucket (overflow-free by
+     construction: no fallback path, nothing to count).
+  4. jax.lax.all_to_all over the `workers` axis swaps buckets so chip d
+     receives bucket d from every chip, each already packed to its
+     front; the [W] counts cross the same way. row_valid does not
+     cross (a segment's count says which lanes are live) and the
+     validity masks cross as bits of shared 32-bit lanes.
+  5. the pack: a [W * rows] output per array written by W
+     dynamic_update_slices in ascending source order, segment s (all
+     `rows` lanes of it) at the sum of the received counts before s.
+     That start is <= s * rows, so no window clamps, and each write
+     overwrites the dead tail of the one before. row_valid =
+     iota < total, lanes from `total` on zeroed, count = total. The
+     consumer receives source 0's rows, then source 1's, ...
 
 Equal keys land on equal chips, which is the contract partial->final
 aggregation, partitioned joins, and distinct rely on. Presto's LZ4
@@ -109,48 +132,98 @@ def unshard_batch(sb: ShardedBatch) -> Batch:
 # The shuffle kernel (per-chip body run under shard_map)
 
 
-def _bucketize(dest: jnp.ndarray, valid: jnp.ndarray, n_parts: int,
-               arrays: Sequence[jnp.ndarray]
-               ) -> List[jnp.ndarray]:
-    """Scatter rows into [n_parts, rows] send buffers by dest bucket.
-
-    Rows with valid=False go nowhere. Stable sort keeps input order
-    within a bucket (not required by SQL, keeps results deterministic).
-    """
-    rows = dest.shape[0]
-    dest = jnp.where(valid, dest, n_parts)  # invalid -> dropped bucket
-    order = common.stable_argsort(dest)
-    sdest = dest[order]
-    # offset of each bucket's first row among the sorted rows
-    counts = jax.ops.segment_sum(jnp.ones_like(sdest), sdest,
-                                 num_segments=n_parts + 1)
-    offsets = jnp.concatenate([jnp.zeros(1, counts.dtype),
-                               jnp.cumsum(counts)[:-1]])
-    pos = jnp.arange(rows) - offsets[sdest]
-    out = []
-    for a in arrays:
-        buf = jnp.zeros((n_parts + 1, rows), a.dtype)
-        buf = buf.at[sdest, pos].set(a[order], mode="drop")
-        out.append(buf[:n_parts])
-    return out
+def _bucket_sizes(dest: jnp.ndarray, n_parts: int) -> jnp.ndarray:
+    """int32[n_parts]: how many rows go to each destination — one
+    masked sum a bucket (rows routed nowhere carry dest == n_parts and
+    are counted by none)."""
+    return jnp.stack([jnp.sum(dest == d, dtype=jnp.int32)
+                      for d in range(n_parts)])
 
 
-def _shuffle_core(n_parts: int, axis: str,
-                  row_valid: jnp.ndarray,
-                  key_datas, key_masks, datas, masks):
-    """Per-chip shuffle pipeline shared by every repartition entry
-    point: hash keys -> bucketize -> all_to_all -> flatten. Returns the
-    flat received (datas, masks, row_valid)."""
+def _send_segments(g: jnp.ndarray, offsets: jnp.ndarray,
+                   counts: jnp.ndarray) -> jnp.ndarray:
+    """The [n_parts, rows] send buffer of one array already grouped by
+    destination: bucket d is the contiguous run g[offsets[d]:][:counts[d]],
+    copied by one dynamic_slice of `rows` lanes and zeroed past its
+    count. The window is cut from `g` extended by `rows` dead lanes,
+    which no start in [0, rows] can run past: cut from `g` itself it
+    would clamp to start 0 (module docstring, step 3)."""
+    rows = g.shape[0]
+    ext = jnp.concatenate([g, jnp.zeros(rows, g.dtype)])
+    lane = jnp.arange(rows, dtype=jnp.int32)
+    dead = jnp.zeros((), g.dtype)
+    return jnp.stack([
+        jnp.where(lane < counts[d],
+                  jax.lax.dynamic_slice(ext, (offsets[d],), (rows,)),
+                  dead)
+        for d in range(counts.shape[0])])
+
+
+def _pack_segments(recv: jnp.ndarray, starts: jnp.ndarray,
+                   total: jnp.ndarray) -> jnp.ndarray:
+    """[n_parts * rows] from the received [n_parts, rows] segments, each
+    live at its front: segment s (all `rows` lanes of it) is written at
+    starts[s] = the live rows of the segments before it, in ascending
+    source order, so each write overwrites the dead tail of the one
+    before. starts[s] <= s * rows, so no window runs past the end and
+    none clamps. Lanes from `total` on are zeroed."""
+    w, rows = recv.shape
+    out = jnp.zeros(w * rows, recv.dtype)
+    for s in range(w):
+        out = jax.lax.dynamic_update_slice(out, recv[s], (starts[s],))
+    lane = jnp.arange(w * rows, dtype=jnp.int32)
+    return jnp.where(lane < total, out, jnp.zeros((), recv.dtype))
+
+
+#: validity masks cross the wave packed 32 to an unsigned lane
+_MASK_LANE_BITS = 32
+
+
+def _pack_masks(masks: Sequence[jnp.ndarray]) -> List[jnp.ndarray]:
+    lanes = []
+    for i in range(0, len(masks), _MASK_LANE_BITS):
+        lane = jnp.zeros(masks[0].shape, jnp.uint32)
+        for bit, m in enumerate(masks[i:i + _MASK_LANE_BITS]):
+            lane = lane | (m.astype(jnp.uint32) << bit)
+        lanes.append(lane)
+    return lanes
+
+
+def _unpack_masks(lanes: Sequence[jnp.ndarray], n: int
+                  ) -> Tuple[jnp.ndarray, ...]:
+    return tuple(
+        (lanes[i // _MASK_LANE_BITS] >> (i % _MASK_LANE_BITS)) & 1 != 0
+        for i in range(n))
+
+
+def _wave_body(n_parts: int, axis: str, row_valid, key_datas,
+               key_masks, datas, masks):
+    """The per-chip wave pipeline (module docstring, steps 1-5): rows
+    cross as contiguous segments addressed by n_parts counts. Shared
+    by the plain and the chained (fused-fragment) wave programs, by
+    hash_repartition and by their KernelContract trace points.
+    Returns (datas, masks, row_valid, count[1]) over n_parts * rows
+    lanes, the live rows packed to the front in source-chip order,
+    source order kept inside a chip's rows, dead lanes zeroed."""
+    rows = row_valid.shape[0]
     h = common.row_hash(list(zip(key_datas, key_masks)))
-    dest = jnp.abs(h) % n_parts
-    send = _bucketize(dest.astype(jnp.int32), row_valid, n_parts,
-                      list(datas) + list(masks) + [row_valid])
-    recv = [jax.lax.all_to_all(b, axis, 0, 0, tiled=True) for b in send]
-    flat = [b.reshape(-1) for b in recv]
+    dest = (jnp.abs(h) % n_parts).astype(jnp.int32)
+    dest = jnp.where(row_valid, dest, n_parts)  # invalid -> no bucket
+    order = common.stable_argsort(dest)
+    counts = _bucket_sizes(dest, n_parts)
+    offsets = jnp.cumsum(counts) - counts
+    # row_valid does not cross (the counts say which lanes are live)
     nd = len(datas)
-    return tuple(flat[:nd]), tuple(flat[nd:2 * nd]), flat[2 * nd]
-
-
+    send = [_send_segments(a[order], offsets, counts)
+            for a in list(datas) + _pack_masks(masks)]
+    recv = [jax.lax.all_to_all(b, axis, 0, 0, tiled=True) for b in send]
+    got = jax.lax.all_to_all(counts, axis, 0, 0, tiled=True)
+    starts = jnp.cumsum(got) - got
+    total = jnp.sum(got)
+    flat = [_pack_segments(b, starts, total) for b in recv]
+    out_valid = jnp.arange(n_parts * rows, dtype=jnp.int32) < total
+    return (tuple(flat[:nd]), _unpack_masks(flat[nd:], len(masks)),
+            out_valid, total.reshape(1))
 
 
 def hash_repartition(sb: ShardedBatch, key_names: Sequence[str]
@@ -159,8 +232,9 @@ def hash_repartition(sb: ShardedBatch, key_names: Sequence[str]
 
     Output rows_per_worker = W * input rows_per_worker (each chip can in
     the worst case receive every other chip's full slice; no overflow is
-    possible by construction). Callers that need the batch small again
-    compact after aggregation."""
+    possible by construction), each chip's live rows packed to the front
+    of its slice. Callers that need the batch small again compact after
+    aggregation (`wave_repartition` slices by the received counts)."""
     mesh, axis = sb.mesh, sb.axis
     w = sb.n_workers
     b = sb.batch
@@ -171,13 +245,13 @@ def hash_repartition(sb: ShardedBatch, key_names: Sequence[str]
     key_datas = tuple(datas[i] for i in key_idx)
     key_masks = tuple(masks[i] for i in key_idx)
 
-    body = functools.partial(_shuffle_core, w, axis)
+    body = functools.partial(_wave_body, w, axis)
     spec = P(axis)
     fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(spec,) * 5,
-        out_specs=(spec, spec, spec))
-    out_datas, out_masks, out_valid = fn(
+        out_specs=(spec, spec, spec, spec))
+    out_datas, out_masks, out_valid, _ = fn(
         b.row_valid, key_datas, key_masks, datas, masks)
     cols = {
         n: Column(d, m, b.columns[n].type, b.columns[n].dictionary)
@@ -198,28 +272,12 @@ def broadcast_batch(batch: Batch, mesh: Mesh,
 # Wave shuffle: the engine's exchange-operator entry point.
 #
 # One "wave" = one batch per worker. The compiled SPMD program (cached
-# per mesh/shape/signature so repeated waves never retrace) hashes,
-# all_to_alls, then PACKS the received rows to the front of each shard
-# and counts them — the host reads the [W] counts once per wave and
+# per mesh/shape/signature so repeated waves never retrace) runs
+# _wave_body: the received rows come out packed to the front of each
+# shard with their count — the host reads the [W] counts once per wave and
 # slices every consumer's shard down to its capacity bucket, which fixes
 # the W× capacity blow-up of chained shuffles (each consumer batch ends
 # up sized to its live rows, not to W * producer capacity).
-
-
-def _wave_body(n_parts: int, axis: str, row_valid, key_datas,
-               key_masks, datas, masks):
-    """The per-chip wave pipeline: shuffle core, then pack live rows
-    to the front (per-shard compaction) and count them — shared by
-    the plain and the chained (fused-fragment) wave programs and by
-    their KernelContract trace points."""
-    r_datas, r_masks, valid = _shuffle_core(
-        n_parts, axis, row_valid, key_datas, key_masks, datas, masks)
-    order = common.partition_perm(valid)
-    out_datas = tuple(f[order] for f in r_datas)
-    out_masks = tuple(f[order] for f in r_masks)
-    out_valid = valid[order]
-    count = jnp.sum(valid).reshape(1)
-    return out_datas, out_masks, out_valid, count
 
 
 @functools.lru_cache(maxsize=256)
@@ -244,7 +302,7 @@ def _wave_program(mesh: Mesh, axis: str, w: int, n_keys: int,
 # chain then traces inside the shard_map body, per shard, IN THE SAME
 # program as the hash + all_to_all — one dispatch per wave instead of
 # one per chain stage per producer, no per-batch deferred-compact host
-# round (the shuffle's bucketize drops dead lanes before the wire), and
+# round (the wave's segments leave dead lanes behind, before the wire), and
 # the output sharding is the consumer's input spec by construction.
 
 
@@ -496,8 +554,13 @@ def _spmd_shuffle_point(cap, variant):
 register_contract(KernelContract(
     family="spmd_shuffle", module=__name__,
     build=_spmd_shuffle_point,
-    notes="the wave program (_wave_program): hash -> bucketize -> "
-          "all_to_all -> pack + count, per shard"))
+    notes="the wave program (_wave_program), per shard: hash -> one "
+          "sort by destination + W masked counts -> one gather an "
+          "array -> W dynamic_slices into the [W, rows] send buffer "
+          "(zeroed past each count) -> all_to_all of segments and "
+          "counts -> W dynamic_update_slices pack the segments front "
+          "to front, zeroed from the total on; offsets and starts "
+          "come from counts of a clean dest, never from data"))
 
 
 def _spmd_fragment_point(cap, variant):
@@ -551,5 +614,7 @@ register_contract(KernelContract(
     family="spmd_fragment", module=__name__,
     build=_spmd_fragment_point,
     notes="the chained wave (_chained_wave_program): a fused-fragment "
-          "chain traced inside the shard_map body ahead of the "
-          "shuffle (planner/fusion.fuse_exchange_sinks)"))
+          "chain traced inside the shard_map body ahead of the same "
+          "segment wave as spmd_shuffle (_wave_body; "
+          "planner/fusion.fuse_exchange_sinks): rows the chain "
+          "filters out get no bucket and never reach the wire"))
