@@ -1,37 +1,21 @@
-"""Measured CPU baseline for the bench suite.
+"""The plain reference for TPC-H answers: the same five queries on the
+same generated data, executed by pyarrow's Acero engine, independent
+of the engine under test. The reference's own harness (presto-benchmark
+BenchmarkSuite / HandTpchQuery1, see BASELINE.md) cannot run in this
+image (no JVM, no network).
 
-The reference's own harness (presto-benchmark BenchmarkSuite /
-HandTpchQuery1, see BASELINE.md) cannot run in this image: there is no
-JVM (`which java` -> nothing) and no network egress to fetch one. The
-previous rounds therefore compared against hand-invented per-query
-"Java estimates" — unfalsifiable numbers. This module replaces them
-with a MEASURED proxy: the same five TPC-H queries, on the same
-generated data, executed by pyarrow's Acero engine (multithreaded
-C++ vectorized execution, the closest thing to a production columnar
-CPU engine available in this image). The proxy is deliberately
-engine-favourable:
-
-- tables are materialized to Arrow ONCE, untimed (the bench likewise
-  excludes datagen/transfer from warm timings);
 - dictionary-encoded VARCHAR filters compare int codes, not strings
   (what the Java engine's dictionary blocks do);
-- each query gets a warmup run, then best-of-2 timed runs.
+- dates stay int days.
 
-Run `python baseline_proxy.py [schema]` to (re)measure and write
-BASELINE_MEASURED.json; bench.py loads that file as the denominator
-and labels its output "baseline": "measured:pyarrow-acero-<ver>".
-
-Query semantics are pinned by tests/test_baseline_proxy.py, which
-cross-checks every proxy query against the SQL engine at sf0_01.
+Readers: tests/test_baseline_proxy.py cross-checks every query here
+against the SQL engine at sf0_01; tests/test_mesh_served.py and
+chip_smoke.py compare served answers with them. It times nothing.
 """
 
 from __future__ import annotations
 
 import datetime
-import json
-import os
-import sys
-import time
 
 import numpy as np
 
@@ -196,61 +180,3 @@ def q18(t, gen):
 QUERIES = {"q1": q1, "q3": q3, "q5": q5, "q6": q6, "q18": q18}
 TABLES = ["lineitem", "orders", "customer", "supplier", "nation",
           "region"]
-
-
-def measure(schema: str = "sf1", runs: int = 2) -> dict:
-    import pyarrow
-
-    from presto_tpu.connectors.tpch import TpchGenerator
-
-    sf = {"tiny": 0.001, "sf0_01": 0.01, "sf0_1": 0.1, "sf1": 1.0,
-          "sf10": 10.0}[schema]
-    gen = TpchGenerator(sf)
-    t0 = time.perf_counter()
-    tables = load_tables(gen, TABLES)
-    print(f"datagen+arrow ({schema}): {time.perf_counter() - t0:.1f}s",
-          file=sys.stderr)
-
-    import bench
-    rows_of = bench._scanned_rows(gen)
-
-    out = {}
-    for name, fn in QUERIES.items():
-        fn(tables, gen)  # warmup (plans/kernels/thread pool)
-        times = []
-        for _ in range(runs):
-            t0 = time.perf_counter()
-            res = fn(tables, gen)
-            nrows = res.num_rows
-            times.append(time.perf_counter() - t0)
-        best = min(times)
-        out[name] = {"rows_per_sec": round(rows_of[name] / best, 1),
-                     "wall_s": round(best, 4), "result_rows": nrows}
-        print(f"{name}: best {best:.3f}s "
-              f"({out[name]['rows_per_sec']:.3g} rows/s)",
-              file=sys.stderr)
-    return {
-        "engine": "pyarrow-acero",
-        "engine_version": pyarrow.__version__,
-        "schema": schema,
-        "threads": os.cpu_count(),
-        "note": ("measured CPU proxy; the reference's Java harness "
-                 "cannot run here (no JVM in image) — see BASELINE.md"),
-        "queries": out,
-    }
-
-
-def main() -> int:
-    schema = sys.argv[1] if len(sys.argv) > 1 else "sf1"
-    result = measure(schema)
-    path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                        "BASELINE_MEASURED.json")
-    with open(path, "w") as f:
-        json.dump(result, f, indent=2)
-    print(f"wrote {path}", file=sys.stderr)
-    print(json.dumps(result))
-    return 0
-
-
-if __name__ == "__main__":
-    sys.exit(main())

@@ -80,16 +80,6 @@ _TABLES: Dict[str, List] = {
         ("input_rows", BIGINT), ("selectivity", DOUBLE),
         ("wall_ms", DOUBLE), ("peak_bytes", BIGINT),
         ("observations", BIGINT), ("age_ms", DOUBLE)],
-    # the perf sentinel's streaming latency baselines: one row per
-    # (node, scope, key) sliding-window quantile sketch — scope
-    # "kernel" keys are kernel families, scope "query" keys are plan
-    # fingerprints. Local rows come from this process's tracker; on a
-    # coordinator, every live heartbeat-monitored worker's /v1/latency
-    # contributes its rows too (the fleet roll-up)
-    "runtime.latency": [
-        ("node", VARCHAR), ("scope", VARCHAR), ("key", VARCHAR),
-        ("count", BIGINT), ("p50_ms", DOUBLE), ("p95_ms", DOUBLE),
-        ("p99_ms", DOUBLE), ("mad_ms", DOUBLE), ("window", BIGINT)],
     "metadata.catalogs": [("catalog_name", VARCHAR)],
     "metadata.tables": [("table_catalog", VARCHAR),
                         ("table_schema", VARCHAR),
@@ -313,40 +303,6 @@ def runner_system_connector(runner) -> SystemConnector:
         store = get_history_store(create=False)
         return store.snapshot_rows() if store is not None else []
 
-    def latency():
-        # the sentinel tracker's sliding-window quantile rows, plus a
-        # fleet roll-up: every live heartbeat-monitored worker's
-        # /v1/latency contributes its rows under its own node id —
-        # one SQL query answers "which worker's scan family got slow"
-        from presto_tpu.telemetry import sentinel as _sentinel
-        out = [("local-0", r["scope"], r["key"], r["count"],
-                r["p50_ms"], r["p95_ms"], r["p99_ms"], r["mad_ms"],
-                r["window"])
-               for r in _sentinel.snapshot_rows()]
-        from presto_tpu import sanitize
-        for monitor in sanitize.tracked("heartbeat_monitor"):
-            try:
-                workers = monitor.snapshot()
-            except Exception:  # noqa: BLE001
-                continue
-            for w in workers:
-                if w.get("state") != "active":
-                    continue
-                host = w["url"].split("//", 1)[-1]
-                try:
-                    import json as _json
-                    from presto_tpu.server.node import http_get
-                    doc = _json.loads(http_get(
-                        f"{w['url']}/v1/latency", timeout=2))
-                    for r in doc.get("rows", []):
-                        out.append((
-                            f"worker-{host}", r["scope"], r["key"],
-                            r["count"], r["p50_ms"], r["p95_ms"],
-                            r["p99_ms"], r["mad_ms"], r["window"]))
-                except Exception:  # noqa: BLE001 — a scrape must not
-                    continue       # fail the SQL query
-        return out
-
     def tables():
         out = []
         for cat in runner.catalogs.catalogs():
@@ -369,7 +325,6 @@ def runner_system_connector(runner) -> SystemConnector:
         "runtime.queries": queries,
         "runtime.caches": caches,
         "runtime.plan_history": plan_history,
-        "runtime.latency": latency,
         "runtime.operator_stats": operator_stats,
         "metadata.catalogs": catalogs,
         "metadata.tables": tables,

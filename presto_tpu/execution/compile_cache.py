@@ -21,8 +21,8 @@ Three layers, from cheapest to deepest:
 ``prewarm`` replays representative statements at server start so the
 trace layer re-populates from the disk layer BEFORE traffic arrives:
 restart-warm serving then performs ZERO fresh compiles (the
-attribution counters prove it — see tools/serving_bench.py
---restart-warm and docs/COMPILATION.md)."""
+attribution counters prove it — see tests/test_compile_cache.py and
+docs/COMPILATION.md)."""
 
 from __future__ import annotations
 
@@ -117,7 +117,7 @@ def configure(cache_dir: Optional[str] = None) -> None:
 def clear_kernel_caches() -> None:
     """Drop every in-process compiled-kernel cache: the engine kernel
     LRUs AND jax's in-memory jit caches. This is the process-restart
-    simulation (tests, serving_bench --restart-warm): afterwards the
+    simulation (tests/test_compile_cache.py): afterwards the
     only warm layer left is the persistent on-disk cache."""
     from presto_tpu.operators import (
         aggregation, core, fused_fragment, join_ops,

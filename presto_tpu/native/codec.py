@@ -67,8 +67,7 @@ def checksum(data: bytes) -> int:
 
 def _count(stage: str, raw: int, framed: int) -> None:
     """Compression observability: raw (uncompressed payload) vs
-    framed (codec frame incl. 17-byte header) bytes per direction —
-    serving_bench reports the per-phase before/after delta."""
+    framed (codec frame incl. 17-byte header) bytes per direction."""
     from presto_tpu.telemetry.metrics import METRICS
     METRICS.inc("presto_tpu_serde_bytes_total", raw,
                 stage=stage, kind="raw")

@@ -34,9 +34,9 @@ from presto_tpu.telemetry import kernels as _tk
 from presto_tpu.telemetry import ledger as _ledger
 from presto_tpu.telemetry import trace as _trace
 
-#: process-wide batch-pump switch (A/B lever: serving_bench's byte-
-#: identity oracle and the pump test battery flip it); the env var is
-#: the subprocess-bench override
+#: process-wide batch-pump switch: the pump test battery flips it to
+#: get the pair loop as its byte-identity reference; the env var sets
+#: it for a subprocess
 _PUMP_ON = os.environ.get("PRESTO_TPU_PUMP", "1") != "0"
 
 

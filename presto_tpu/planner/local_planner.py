@@ -321,7 +321,7 @@ class LocalExecutionPlanner:
         # A node with several plan parents (DAG) is computed ONCE into a
         # Spool and replayed to each consumer — the reference dedups via
         # planner CSE; without this the shared subtree would execute once
-        # per parent (ADVICE r1: EXISTS probe ran twice).
+        # per parent.
         nid = id(node)
         if nid in self._shared:
             spool = self._spools.get(nid)

@@ -739,7 +739,6 @@ class LocalRunner:
         self._session_tl.lifecycle = (cancel, deadline)
         self._session_tl.op_stats = None  # this statement's snapshots
         self._session_tl.fusion_report = None  # planner/fusion.py
-        self._session_tl.query_fp = None  # latency-baseline key
         # kernel shape bucketing rides a thread-local gate (operators
         # have no session access): honored by every drive loop this
         # statement runs on THIS thread — remote tasks use the process
@@ -825,20 +824,6 @@ class LocalRunner:
             if entry is not None:
                 entry["unattributed_ms"] = led_doc["unattributed_ms"]
                 self._session_tl.history_entry = None
-            # perf sentinel feeds: the driver-share/unattributed
-            # window detectors eat the ledger doc, and the query's
-            # wall lands in its structural-fingerprint latency sketch
-            # (plan-shape key when the planner produced one, a
-            # normalized-SQL hash for everything else — SHOW/SET/DDL)
-            from presto_tpu.telemetry import sentinel as _sentinel
-            _sentinel.observe_ledger(led_doc)
-            _fp = getattr(self._session_tl, "query_fp", None)
-            if _fp is None:
-                import hashlib as _hashlib
-                _fp = "sql:" + _hashlib.blake2b(
-                    sql.strip().encode(),
-                    digest_size=8).hexdigest()
-            _sentinel.observe_query(_fp, led_doc["wall_ms"])
             import sys as _sys
             _exc = _sys.exc_info()[1]
             if _exc is not None:
@@ -1308,20 +1293,6 @@ class LocalRunner:
         import time as _time
         from presto_tpu.telemetry import ledger as _ledger
         session = self.session
-        # query STRUCTURAL fingerprint (history/fingerprint.py keys)
-        # for the streaming latency baselines: queries with the same
-        # plan shape share one sliding-window sketch, so the sentinel
-        # compares like against like (telemetry/sentinel.py). Memo
-        # scope is this call; the stash is per statement.
-        if getattr(self._session_tl, "query_fp", None) is None:
-            try:
-                from presto_tpu.history.fingerprint import (
-                    node_fingerprint,
-                )
-                fp = node_fingerprint(plan, self.catalogs, {})
-                self._session_tl.query_fp = fp[0] if fp else None
-            except Exception:  # noqa: BLE001 — baseline is advisory
-                self._session_tl.query_fp = None
         while True:
             with _ledger.span("planning"):
                 planner = LocalExecutionPlanner(self.catalogs, session)

@@ -302,17 +302,6 @@ class Coordinator(Node):
         self._pruner.start()
         if self.membership is not None:
             self.membership.start()
-            # regression sentinel: heartbeat RTT inflation is a fleet
-            # signal only the coordinator can see — hand the sentinel
-            # a live view of the membership snapshot's rtt_ms column
-            from presto_tpu.telemetry.sentinel import SENTINEL
-            mon = self.membership
-
-            def _rtts():
-                return [(w.get("url", "?"), w["rtt_ms"])
-                        for w in mon.snapshot()
-                        if w.get("rtt_ms") is not None]
-            SENTINEL.rtt_supplier = _rtts
 
     def stop(self) -> None:
         self._pruner_stop.set()
@@ -370,15 +359,6 @@ class Coordinator(Node):
                 self._prune_queries()
             except Exception:  # noqa: BLE001 — the sweep must outlive
                 pass           # any one bad query entry
-            try:
-                # the regression sentinel piggybacks on the prune
-                # sweep: one periodic thread per coordinator already
-                # exists, detectors are O(tracked windows) — no
-                # dedicated timer thread
-                from presto_tpu.telemetry.sentinel import SENTINEL
-                SENTINEL.check()
-            except Exception:  # noqa: BLE001 — detectors cannot
-                pass           # take down the pruner
 
     def _fire_event(self, payload: dict) -> None:
         for listener in self.event_listeners:
@@ -640,17 +620,6 @@ class Coordinator(Node):
             raise KeyError(qid)
         if path == "/v1/resourceGroups":
             return json.dumps(self.resource_groups.snapshot()).encode()
-        if path == "/v1/sentinel":
-            # the perf sentinel's live state: detector config, recent
-            # alerts, and the streaming latency baselines — a fresh
-            # detector pass runs on demand so a scrape never waits a
-            # prune period to see a regression
-            from presto_tpu.telemetry import sentinel as _sentinel
-            fired = _sentinel.SENTINEL.check()
-            doc = _sentinel.SENTINEL.snapshot()
-            doc["fired_now"] = fired
-            doc["latency"] = _sentinel.snapshot_rows()
-            return json.dumps(doc).encode()
         if path in ("/ui", "/ui/"):
             return self._ui_page()
         if path.startswith("/v1/statement/executing/"):
@@ -1001,23 +970,6 @@ th{{background:#222}}
                     "unattributed_frac": round(unattr / wall_ms, 4)
                     if wall_ms > 0 else 0.0,
                 }
-                if not self.single_node:
-                    # sentinel window feeds for the worker topology:
-                    # the single-node path feeds inside LocalRunner
-                    # (which this coordinator's queries pass through),
-                    # the distributed path closes its ledger only here
-                    try:
-                        from presto_tpu.telemetry import (
-                            sentinel as _sentinel)
-                        _sentinel.observe_ledger(q.stats["ledger"])
-                        import hashlib as _hl
-                        _sentinel.observe_query(
-                            "sql:" + _hl.blake2b(
-                                q.sql.strip().encode(),
-                                digest_size=8).hexdigest(),
-                            wall_ms)
-                    except Exception:  # noqa: BLE001 — advisory
-                        pass
             if q.trace and isinstance(q.stats, dict) \
                     and "critical_path" not in q.stats:
                 # blocking-chain extraction over the merged fleet

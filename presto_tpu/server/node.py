@@ -602,7 +602,7 @@ class Node:
             # kernel RESETS connections — the exact collapse mode the
             # overload story exists to prevent. Admission control is
             # the real gate; the listener must be deep enough that
-            # every client REACHES it (serving_bench --clients 256)
+            # every client REACHES it
             request_queue_size = 1024
             daemon_threads = True
         self.httpd = _Server((host, port), handler)
@@ -679,14 +679,6 @@ class Node:
                 **_flight.stats(),
                 "events": _flight.snapshot_dicts(),
             }).encode()
-        if path == "/v1/latency":
-            # this node's streaming latency baselines (per kernel
-            # family / query fingerprint sliding-window quantiles) —
-            # the coordinator's system.runtime.latency roll-up scrapes
-            # every live member here
-            from presto_tpu.telemetry import sentinel as _sentinel
-            return json.dumps({
-                "rows": _sentinel.snapshot_rows()}).encode()
         if path.startswith("/v1/task/") and path.endswith("/trace"):
             # span drain for LONG tasks: returns the spans buffered so
             # far and removes them from the recorder — the terminal

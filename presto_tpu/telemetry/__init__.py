@@ -31,20 +31,13 @@ TaskStats / QueryStats hierarchy, server/QueryResource, and the
              spans: which spans DETERMINED the wall, decomposed into
              the ledger's categories (EXPLAIN ANALYZE's "critical
              path" section, GET /v1/query/{id}, query_doctor)
-  sentinel — streaming latency baselines (sliding-window quantile
-             sketches per kernel family / query fingerprint) + the
-             noise-aware regression detectors that compare live
-             windows against tools/perf_baseline.json and the
-             previous window (GET /v1/sentinel,
-             system.runtime.latency, serving_bench
-             --check-regressions)
 
 Every hot-path hook is gated on a module-level bool (``trace.ACTIVE``,
 ``kernels.ENABLED``) exactly like execution/faults.ARMED, so disabled
 telemetry costs one attribute load + branch per site."""
 
 from presto_tpu.telemetry import (  # noqa: F401
-    critical_path, flight, kernels, ledger, metrics, sentinel, trace,
+    critical_path, flight, kernels, ledger, metrics, trace,
 )
 from presto_tpu.telemetry.stats import (  # noqa: F401
     build_query_stats, render_operator_stats, snapshot_drivers,

@@ -66,7 +66,6 @@ from presto_tpu import sanitize
 from presto_tpu.telemetry.metrics import METRICS
 from presto_tpu.telemetry import flight as _flight
 from presto_tpu.telemetry import ledger as _ledger
-from presto_tpu.telemetry import sentinel as _sentinel
 from presto_tpu.telemetry import trace as _trace
 
 #: device name -> kernel family, filled by `jit` below: the one map
@@ -149,29 +148,6 @@ _WRAPPERS: "weakref.WeakSet" = weakref.WeakSet()
 SIGNATURE_TRACKING = False
 _SIGNATURES: Dict[str, set] = {}
 _SIG_LOCK = sanitize.lock("telemetry.kernel_signatures")
-
-
-#: deliberately de-optimized kernel variants (tests + the injected-
-#: regression oracle): family -> added ms of host stall per call,
-#: applied INSIDE the timed window so the slowdown is observed
-#: exactly like a real dispatch regression — byte-identical results,
-#: shifted latency distribution. The faults registry can only RAISE
-#: (its errors are absorbed by retry tiers), so slowing a family
-#: without failing anything needs this separate lever. Empty = zero
-#: overhead beyond one dict-truthiness branch per call.
-_HANDICAP_MS: Dict[str, float] = {}
-
-
-def set_handicap(family: Optional[str] = None,
-                 ms: float = 0.0) -> None:
-    """Arm (ms > 0) or clear (ms == 0 / family None) a per-family
-    slowdown. `family=None` clears every handicap."""
-    if family is None:
-        _HANDICAP_MS.clear()
-    elif ms > 0:
-        _HANDICAP_MS[family] = float(ms)
-    else:
-        _HANDICAP_MS.pop(family, None)
 
 
 def arm_signature_tracking(on: bool = True) -> None:
@@ -307,10 +283,6 @@ def record(name: str, dur_ns: int, compiled: bool,
     else:
         METRICS.inc("presto_tpu_kernel_execute_ns_total", dur_ns,
                     kernel=name)
-        # streaming latency baseline: WARM calls only — a compile's
-        # wall would shift every family's p99 at each cold start and
-        # the sentinel would cry regression on every restart
-        _sentinel.observe_kernel(name, dur_ns / 1e6)
 
 
 _BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
@@ -393,10 +365,6 @@ def instrument_kernel(kernel, name: str, jits=None):
         before = _cache_sizes(jits)
         t0 = time.perf_counter_ns()
         try:
-            if _HANDICAP_MS:
-                stall = _HANDICAP_MS.get(name)
-                if stall:
-                    time.sleep(stall / 1e3)
             with TraceAnnotation(
                     warm_span if state["traced"] else cold_span):
                 out = kernel(*args, **kwargs)

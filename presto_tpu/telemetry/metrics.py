@@ -87,8 +87,7 @@ class MetricsRegistry:
                        if n == name)
 
     def by_label(self, name: str, label: str) -> Dict[str, float]:
-        """{label value -> summed count} for one counter family —
-        the bench tools' per-kernel-family delta source."""
+        """{label value -> summed count} for one counter family."""
         out: Dict[str, float] = {}
         with self._lock:
             for (n, labels), v in self._counters.items():
@@ -101,8 +100,7 @@ class MetricsRegistry:
     def delta_by_label(self, name: str, label: str,
                        before: Dict[str, float]) -> Dict[str, int]:
         """Positive per-label-value growth since a by_label snapshot
-        — THE `distinct_compiles` shape every bench tool reports
-        (serving_bench phases, kernel_bench entries, bench.py)."""
+        (chip_smoke.py's compiles per family, the tests' deltas)."""
         now = self.by_label(name, label)
         return {k: int(v - before.get(k, 0))
                 for k, v in sorted(now.items())
@@ -337,28 +335,11 @@ METRICS.describe_histogram(
     "Per-query fraction of wall the attribution ledger left "
     "unattributed (coverage regressions shift this right)",
     buckets=(0.01, 0.02, 0.05, 0.10, 0.20, 0.50, 1.0))
-METRICS.describe("presto_tpu_sentinel_alerts_total",
-                 "Regression-sentinel alerts fired, by detector "
-                 "(telemetry/sentinel.py detector catalogue; each "
-                 "alert also lands a flight-recorder event)")
 METRICS.describe("presto_tpu_flight_dropped_total",
                  "Flight-recorder events not retained, by reason: "
                  "ring_full (oldest event overwritten at capacity) "
                  "vs sampled (skipped by the per-kind sampling "
                  "lever)")
-METRICS.describe_histogram(
-    "presto_tpu_kernel_latency_ms",
-    "Warm (execute-classified) per-call kernel latency by family — "
-    "the streaming-baseline input; compile calls are excluded so "
-    "cold starts cannot masquerade as dispatch regressions",
-    buckets=(0.1, 0.5, 1.0, 2.5, 5.0, 10.0, 25.0, 50.0, 100.0,
-             250.0, 500.0, 1000.0, 2500.0))
-METRICS.describe_histogram(
-    "presto_tpu_query_latency_ms",
-    "Per-query wall latency (queued + execution) at attribution-"
-    "ledger close — the query-fingerprint baseline's histogram face",
-    buckets=(1.0, 5.0, 10.0, 25.0, 50.0, 100.0, 250.0, 500.0,
-             1000.0, 2500.0, 5000.0, 10000.0, 30000.0))
 
 
 def render_prometheus() -> str:

@@ -16,9 +16,7 @@ Usage:
 
 `--assert-fused` exits non-zero unless EVERY query fuses at least one
 leaf fragment — the serving-mix regression guard (the same check runs
-in the fast test tier). bench.py and serving_bench embed the same
-per-query summaries in their JSON via `--fusion-report` /
-`fusion` keys (docs/FRAGMENT_COMPILATION.md)."""
+in the fast test tier; docs/FRAGMENT_COMPILATION.md)."""
 
 from __future__ import annotations
 

@@ -44,7 +44,6 @@ TRACE_SCOPE = (
     "presto_tpu/ops/", "presto_tpu/operators/", "presto_tpu/expr/",
     "presto_tpu/parallel/", "presto_tpu/batch.py",
     "presto_tpu/execution/dynamic_filters.py",
-    "presto_tpu/tools/kernel_bench.py",
 )
 #: prefixes the concurrency rules cover (layers crossed by many
 #: threads: executor workers, HTTP handlers, shared caches)

@@ -2,8 +2,8 @@
 class. Scope: the kernel layer — `ops/`, `operators/`, `expr/`,
 `batch.py`, `parallel/`, and the jitted parts of `execution/`.
 
-Why these exist: the 16.8s compile wall of BENCH_SERVING_r09 was
-caused by silent per-shape retraces, and the telemetry PR's
+Why these exist: silent per-shape retraces once held a compile wall
+nobody had asked for, and the telemetry PR's
 "uninstrumented module-level jit" gap (compile time booked as execute)
 was found BY HAND. Every rule here makes one of those hazard shapes
 machine-checked:

@@ -56,3 +56,34 @@ def test_coordinator_enforces_identity():
             proc.wait(timeout=10)
         except subprocess.TimeoutExpired:
             proc.kill()
+
+
+def test_single_node_coordinator_enforces_per_user_access():
+    """The shared single-node runner must evaluate access control as
+    the REQUESTING user (X-Presto-User), not the runner's default
+    identity — and the plan cache must not leak an allowed user's
+    plan to a denied one."""
+    from presto_tpu.cache import reset_cache_manager
+    from presto_tpu.server.coordinator import (
+        Coordinator, StatementClient,
+    )
+    reset_cache_manager()
+    ac = AccessControlManager([
+        AccessRule(user="intruder", table="nation",
+                   allow_select=False),
+        AccessRule(),
+    ])
+    coord = Coordinator([], "tpch", "tiny", single_node=True,
+                        access_control=ac)
+    coord.start()
+    try:
+        sql = "select count(*) from nation"
+        ok = StatementClient(coord.url, user="analyst")
+        assert ok.execute(sql)[1] == [[25]]
+        assert ok.execute(sql)[1] == [[25]]  # warm the plan cache
+        denied = StatementClient(coord.url, user="intruder")
+        with pytest.raises(RuntimeError, match="cannot select"):
+            denied.execute(sql)
+    finally:
+        coord.stop()
+    reset_cache_manager()

@@ -41,6 +41,11 @@ SQL_JOIN = ("select o.orderpriority, count(*) c "
             "from orders o join customer c on o.custkey = c.custkey "
             "group by o.orderpriority order by o.orderpriority")
 
+#: the dashboard mix: an aggregation-heavy repeat workload (scan+agg
+#: q1/q6, a 3-way join q3, a join+group q13) — the shape a BI
+#: dashboard refresh sends at a serving cluster
+DEFAULT_MIX = ("q1", "q3", "q6", "q13")
+
 
 @pytest.fixture
 def pump_state():
@@ -76,7 +81,6 @@ def _run_suite(names, pump: bool):
 def test_pump_identity_serving_mix(pump_state):
     """The serving mix answers byte-identically pump-on vs pump-off,
     and the on-run really engaged the pump."""
-    from presto_tpu.tools.serving_bench import DEFAULT_MIX
     off = _run_suite(DEFAULT_MIX, pump=False)
     n0 = METRICS.get("presto_tpu_pump_drivers_total", status="pump")
     on = _run_suite(DEFAULT_MIX, pump=True)
@@ -121,7 +125,6 @@ def test_pump_zero_new_kernels(pump_state):
     """The zero-new-kernels oracle: every kernel family the pump-on
     run compiles was already minted by the pump-off run — the pump
     must never change WHAT is computed, only when batches move."""
-    from presto_tpu.tools.serving_bench import DEFAULT_MIX
     _run_suite(DEFAULT_MIX, pump=False)
     fam_off = set(METRICS.by_label(
         "presto_tpu_kernel_compiles_total", "kernel"))

@@ -452,6 +452,55 @@ def test_ft_sigkill_worker_mid_query(local_rows):
         _kill(w2, signal.SIGKILL)
 
 
+def test_ft_worker_rejoins_on_its_port_and_serves(local_rows):
+    """A worker SIGKILLed between statements and started again on the
+    same port: the statement sent while it is gone is answered by the
+    other worker alone, the heartbeat re-admits the newcomer in place
+    (a flap of that member, not a new one), and the next statement
+    gives it tasks. All three answers equal the fault-free one."""
+    from presto_tpu.server.coordinator import Coordinator
+    from presto_tpu.server.node import http_get
+    w1, u1 = _spawn_worker()
+    w2, u2 = _spawn_worker()
+    coord = Coordinator([u1, u2], "tpch", "tiny", dict(FT_PROPS),
+                        heartbeat_interval_s=0.3)
+
+    def member(url):
+        return next(w for w in coord.membership.snapshot()
+                    if w["url"] == url)
+
+    def wait_for(holds, seconds, what):
+        deadline = time.monotonic() + seconds
+        while not holds() and time.monotonic() < deadline:
+            time.sleep(0.05)
+        assert holds(), what
+
+    try:
+        coord.start()
+        coord.check_workers()
+        want = local_rows(SQL_AGG)
+        assert coord.execute(SQL_AGG).rows() == want
+        _kill(w2, signal.SIGKILL)
+        wait_for(lambda: not coord.membership.is_alive(u2), 15,
+                 "the dead worker was never removed")
+        assert coord.execute(SQL_AGG).rows() == want
+        w2, again = _spawn_worker(port=int(u2.rsplit(":", 1)[1]))
+        assert again == u2
+        wait_for(lambda: member(u2)["state"] == "active", 30,
+                 "the restarted worker was never re-admitted")
+        assert member(u2)["flaps"] >= 1
+        assert len(coord.membership.snapshot()) == 2
+        assert coord.execute(SQL_AGG).rows() == want
+        # a process that has run no task has called no kernel
+        assert "presto_tpu_kernel_calls_total" in http_get(
+            f"{u2}/v1/metrics").decode()
+        assert _fleet_audit() == []
+    finally:
+        coord.stop()
+        _kill(w1)
+        _kill(w2)
+
+
 # ---------------------------------------------------------------------------
 # fleet memory gate + distributed prewarm + degradation-tolerant probe
 

@@ -40,6 +40,26 @@ def test_disabled_gate_is_noop():
         flight.ENABLED = True
 
 
+def test_a_statement_answers_the_same_with_the_recorder_off():
+    """The recorder observes and decides nothing: the same statement
+    gives the same rows with the gate off, and leaves no event."""
+    from presto_tpu.runner import LocalRunner
+    from presto_tpu.telemetry import flight
+    sql = ("select returnflag, count(*), sum(quantity) from lineitem "
+           "group by returnflag order by returnflag")
+    props = {"fragment_result_cache_enabled": False}
+    on = LocalRunner("tpch", "tiny", dict(props)).execute(sql).rows()
+    assert flight.stats()["total"] > 0
+    flight.reset()
+    flight.ENABLED = False
+    try:
+        off = LocalRunner("tpch", "tiny", dict(props)).execute(sql).rows()
+        assert flight.stats()["total"] == 0
+    finally:
+        flight.ENABLED = True
+    assert off == on
+
+
 def test_injected_fault_snapshot_rides_error_payload():
     """The satellite contract: a query failed by an injected fault
     carries the recorder's recent window on its exception — the fault

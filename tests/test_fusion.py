@@ -64,7 +64,7 @@ def test_tpch_fused_vs_unfused_identical(runners, qn):
 
 
 def test_serving_mix_fuses_leaf_fragments(runners):
-    """q1/q3/q6/q13 — the serving_bench mix — each fuse >= 1 leaf
+    """q1/q3/q6/q13 — the dashboard mix — each fuse >= 1 leaf
     fragment (the regression guard tools/fusion_report.py
     --assert-fused runs from the command line)."""
     on, _ = runners

@@ -382,23 +382,3 @@ def test_history_report_tool(capsys):
     # dump mode renders the store populated by the diff runs
     assert main(["--dump"]) == 0
     assert "rows=" in capsys.readouterr().out
-
-
-def test_serving_bench_history_phase():
-    from presto_tpu.cache import reset_cache_manager
-    from presto_tpu.tools.serving_bench import run_serving_bench
-    reset_cache_manager()
-    doc = run_serving_bench(clients=2, schema="tiny",
-                            mix=("q6", "q1"), warm_rounds=1,
-                            verify_off=False, history_phase=True)
-    h = doc["history"]
-    for key in ("plans_changed", "fusion_upgraded",
-                "results_identical", "history_estimates",
-                "fusion_first_vs_second", "store_entries",
-                "counters"):
-        assert key in h, key
-    assert h["results_identical"] is True
-    assert h["store_entries"] > 0
-    assert h["counters"]["presto_tpu_history_records_total"] > 0
-    assert h["counters"]["presto_tpu_history_hits_total"] > 0
-    assert "q6" in h["plans_changed"]

@@ -17,7 +17,7 @@ from presto_tpu.runner.local import LocalRunner, Session
 from presto_tpu.types import BIGINT, BOOLEAN
 from tests.tpch_queries import QUERIES
 
-#: the serving_bench dashboard mix (tools/serving_bench.DEFAULT_MIX)
+#: the dashboard mix (scan+agg q1/q6, 3-way join q3, join+group q13)
 SERVING_MIX = (1, 3, 6, 13)
 
 

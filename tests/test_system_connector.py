@@ -64,24 +64,3 @@ def test_joins_against_system_tables(runner):
         "where t.table_catalog = 'tpch' "
         "group by t.table_schema order by t.table_schema").rows()
     assert all(c == 8 for _, c in rows)  # 8 tpch tables per schema
-
-
-def test_runtime_latency_rows(runner):
-    """system.runtime.latency surfaces the sentinel's streaming
-    sketches: one row per tracked (scope, key), quantiles in ms."""
-    from presto_tpu.telemetry import sentinel
-    sentinel.observe_kernel("latency_table_probe", 7.0)
-    rows = runner.execute(
-        "select node, scope, key, count, p50_ms, p95_ms, p99_ms, "
-        "mad_ms, window from system.runtime.latency "
-        "where scope = 'kernel' and key = 'latency_table_probe'"
-    ).rows()
-    assert rows, "the probe family must appear"
-    node, scope, key, count, p50, p95, p99, mad, window = rows[0]
-    assert node == "local-0"
-    assert (scope, key) == ("kernel", "latency_table_probe")
-    assert count >= 1 and isinstance(count, int)
-    assert p50 == pytest.approx(7.0)
-    assert p99 >= p95 >= p50 > 0
-    assert mad >= 0.0
-    assert window == sentinel.WINDOW

@@ -1,10 +1,11 @@
-"""Verifier + benchmark tooling (reference: presto-verifier
-AbstractVerification checksum comparison; presto-benchmark suite)."""
+"""Verifier and test-budget tooling (reference: presto-verifier
+AbstractVerification checksum comparison)."""
 
 import json
 
 import pytest
 
+from presto_tpu.tools import test_budget
 from presto_tpu.tools.verifier import (
     result_checksum, row_checksum, verify_queries,
 )
@@ -64,15 +65,46 @@ def test_verifier_local_vs_mesh_cli(capsys):
     assert out.count("match") == 3
 
 
-@pytest.mark.slow
-def test_benchmark_suite(tmp_path):
-    from presto_tpu.tools import benchmark
-    out = tmp_path / "bench.json"
-    rc = benchmark.main(["--suite", "tpch", "--schema", "tiny",
-                         "--runs", "1", "--warmup", "0",
-                         "--out", str(out)])
-    assert rc == 0
-    doc = json.loads(out.read_text())
-    assert doc["summary"]["queries"] == 22
-    assert doc["summary"]["succeeded"] == 22
-    assert doc["summary"]["geomean_best_s"] > 0
+# -- test_budget -------------------------------------------------------
+
+DURATIONS = """\
+============= slowest 50 durations =============
+12.34s call     tests/test_serving.py::test_warm_mix
+3.21s call     tests/test_fleet.py::test_churn[2]
+0.45s setup    tests/test_serving.py::test_warm_mix
+0.10s teardown tests/test_serving.py::test_warm_mix
+(142 durations < 0.005s hidden.  Use -vv to show these durations.)
+= 900 passed in 700.00s =
+"""
+
+
+def test_budget_parses_and_sorts():
+    rows = test_budget.parse_durations(DURATIONS)
+    assert rows[0] == (12.34, "call", "tests/test_serving.py::"
+                                      "test_warm_mix")
+    assert [r[1] for r in rows] == ["call", "call", "setup",
+                                    "teardown"]
+
+
+def test_budget_ceiling_counts_call_phase_only():
+    rows = test_budget.parse_durations(DURATIONS)
+    # the 0.45s setup shares a fixture — never double-charged
+    assert test_budget.over_ceiling(rows, 10.0) == \
+        [(12.34, "call", "tests/test_serving.py::test_warm_mix")]
+    assert test_budget.over_ceiling(rows, 20.0) == []
+    text = test_budget.report(rows)
+    assert "test_warm_mix" in text and "15.6s total" in text
+
+
+def test_budget_cli(tmp_path, capsys):
+    f = tmp_path / "durations.txt"
+    f.write_text(DURATIONS)
+    assert test_budget.main(["--file", str(f), "--ceiling",
+                             "20"]) == 0
+    capsys.readouterr()  # drain the plain-text report
+    assert test_budget.main(["--file", str(f), "--ceiling", "5",
+                             "--json"]) == 1
+    doc = json.loads(capsys.readouterr().out)
+    assert doc["tests_measured"] == 2
+    assert [b["test"] for b in doc["breaches"]] == \
+        ["tests/test_serving.py::test_warm_mix"]
