@@ -11,16 +11,18 @@ from __future__ import annotations
 
 import collections
 import functools
-from typing import List, Optional, Sequence, Tuple
+from typing import Callable, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from presto_tpu.batch import (
-    Batch, Column, bucket_capacity, operator_capacity, pad_for_kernel,
-    remap_column,
+    COMPACT_FLOOR, COMPACT_MIN, Batch, Column, begin_deferred_compact,
+    bucket_capacity, end_deferred_compact, operator_capacity,
+    pad_for_kernel, remap_column, start_async_copy,
 )
+from presto_tpu.native.pages import to_host
 from presto_tpu.operators.base import (
     DriverContext, Operator, OperatorContext, OperatorFactory,
 )
@@ -28,6 +30,9 @@ from presto_tpu.ops import common as ops_common
 from presto_tpu.ops import join as join_ops
 from presto_tpu.telemetry import kernels as _kernels
 from presto_tpu.telemetry.metrics import METRICS
+
+#: lanes the lookup join's probes searched / gathered output columns at
+_LANES = "presto_tpu_join_probe_lanes_total"
 
 
 class JoinCapacityExceeded(Exception):
@@ -271,7 +276,6 @@ class HashBuildOperator(Operator):
                     c = merged.columns[key]
                     vals, n, ovf = df.distinct_set(
                         c.data, c.mask & merged.row_valid)
-                    from presto_tpu.native.pages import to_host
                     if not bool(to_host(ovf)):
                         dset = (vals, n)
                 reg.publish(df_id, mn, mx, dset)
@@ -301,7 +305,6 @@ class HashBuildOperator(Operator):
                 self._spill, self.key_dicts)
             return
         # one device->host sync for the whole build side (not per batch)
-        from presto_tpu.native.pages import to_host
         key_stats = to_host(self._key_stats) \
             if self._key_stats is not None else None
         if key_stats is not None:
@@ -378,6 +381,23 @@ _PROBE_KERNEL_CACHE: "collections.OrderedDict" = collections.OrderedDict()
 _PROBE_KERNEL_CACHE_MAX = 256
 
 
+class ProbeKernel(NamedTuple):
+    """One join shape's probe programs (make_probe_kernel).
+
+    `whole(table, batch, matched, out_capacity)` -> (Batch, overflow,
+    live, matched) is the probe in one dispatch. An aligned probe
+    (ops/join.py:aligned_expansion) whose live count is worth waiting
+    for runs as two instead, the count read on the host between them:
+    `front(table, batch, matched)` -> (side, brow, verified, overflow,
+    live, matched) searches at the batch's width and gathers no build
+    column; `back(build, side, brow, verified, live, width)` -> Batch
+    gathers both sides' columns at the STATIC `width` the count
+    allows, and runs the fused downstream filter/projections there."""
+    whole: Callable
+    front: Callable
+    back: Callable
+
+
 def make_probe_kernel(key_names: Tuple[str, ...], join_type: str,
                       probe_output: Tuple[str, ...],
                       build_output: Tuple[str, ...],
@@ -388,18 +408,17 @@ def make_probe_kernel(key_names: Tuple[str, ...], join_type: str,
                       input_dicts=None,
                       verify: str = "hash",
                       pre=None, pre_key=None, pre_key_dicts=None):
-    """Build the jitted fused probe->project kernel:
+    """Build the jitted fused probe->project programs (a ProbeKernel).
 
-        kernel(table, batch, matched, out_capacity[static])
-            -> (Batch, overflow, live, matched)
-
-    The candidate search, row expansion, build-side rename, and the
-    DOWNSTREAM filter/projection forest all trace into ONE dispatch, so
-    expanded join rows are materialized once — not gathered at the
-    probe and then re-read by a separate FilterProject pass over the
-    same out_capacity-wide arrays. `matched` is the FULL join's
-    per-build-row flag array (pass None otherwise; it passes through
-    untouched).
+    In `whole` the candidate search, row expansion, build-side rename,
+    and the DOWNSTREAM filter/projection forest all trace into ONE
+    dispatch, so expanded join rows are materialized once — not
+    gathered at the probe and then re-read by a separate FilterProject
+    pass over the same out_capacity-wide arrays. `front` and `back`
+    are the same bodies cut where the live rows are known (the aligned
+    layout only): rename, filter and projections move to the back.
+    `matched` is the FULL join's per-build-row flag array (pass None
+    otherwise; it passes through untouched).
 
     `pre` extends the fusion UPSTREAM (the whole-fragment compiler,
     operators/fused_fragment.py): a traceable batch -> batch chain —
@@ -480,32 +499,48 @@ def make_probe_kernel(key_names: Tuple[str, ...], join_type: str,
         out, live = _project(out)
         return out, overflow, live, matched
 
+    def _front(table, batch, lo_enc, matched):
+        with jax.named_scope("join_probe"):
+            return join_ops.aligned_front(
+                table, batch, key_names, lo_enc, matched, join_type,
+                probe_output, build_keys, verify)
+
+    def _materialize(build, side, brow, verified, live, width: int):
+        with jax.named_scope("join_probe"):
+            packed = join_ops.aligned_back(
+                build, side, brow, verified, live, width, join_type,
+                build_output)
+        # the fused filter narrows row_valid at `width` lanes: no
+        # second compaction follows
+        return _project(packed)[0]
+
     # a probe with a fused upstream chain is a whole-fragment program:
-    # family `fragment`, device name `fragment_join_probe`
+    # family `fragment`, device name `fragment_join_probe`. The back
+    # half is `join_probe_materialize` under either front: the
+    # join_probe group's device time is the whole probe's
     family, part = ("fragment", "join_probe") if pre is not None \
         else ("join_probe", None)
     jit_list = None
+    back = _kernels.jit(_materialize, "join_probe", "materialize",
+                        static_argnums=(5,))
     if ops_common.cpu_backend():
-        # two dispatches: the candidate search materializes ONCE (see
+        # staged: the candidate search materializes ONCE (see
         # ops/join.py on XLA:CPU fusion re-materialization); the probe
         # hash2 rides across the boundary so expand needn't rehash
-        stage2 = _kernels.jit(
-            _expand_project, family,
-            "stage2" if part is None else f"{part}_stage2",
-            static_argnums=(5,))
-
+        def staged(p):
+            return p if part is None else f"{part}_{p}"
+        stage1 = _kernels.jit(_front, family, staged("stage1"))
+        stage2 = _kernels.jit(_expand_project, family, staged("stage2"),
+                              static_argnums=(5,))
         if _pre_batch is None:
-            def kernel(table, batch, matched, out_capacity: int):
+            def search(table, batch):
                 if table.layout == "direct":
-                    lo_enc = join_ops._direct_jit(table, batch,
-                                                  key_names)
-                    return stage2(table, batch, lo_enc, None, matched,
-                                  out_capacity)
+                    return batch, join_ops._direct_jit(
+                        table, batch, key_names), None
                 h, h2 = join_ops._hash_jit(batch, key_names)
-                lo_enc = join_ops._search_jit(table, h, h2, verify)
-                return stage2(table, batch, lo_enc, h2, matched,
-                              out_capacity)
-            jit_list = [stage2, join_ops._hash_jit,
+                return batch, join_ops._search_jit(table, h, h2,
+                                                   verify), h2
+            jit_list = [stage1, stage2, join_ops._hash_jit,
                         join_ops._search_jit, join_ops._direct_jit]
         else:
             # the upstream chain + remap fold into the HASH dispatch
@@ -527,35 +562,52 @@ def make_probe_kernel(key_names: Tuple[str, ...], join_type: str,
                 b = _pre_batch(batch)
                 return b, join_ops._direct_enc(table, b, key_names)
 
-            def kernel(table, batch, matched, out_capacity: int):
+            def search(table, batch):
                 if table.layout == "direct":
-                    b, lo_enc = stage0_direct(table, batch)
-                    return stage2(table, b, lo_enc, None, matched,
-                                  out_capacity)
+                    return stage0_direct(table, batch) + (None,)
                 b, h, h2 = stage0(batch)
-                lo_enc = join_ops._search_jit(table, h, h2, verify)
-                return stage2(table, b, lo_enc, h2, matched,
-                              out_capacity)
-            jit_list = [stage0, stage0_direct, stage2,
+                return b, join_ops._search_jit(table, h, h2, verify), h2
+            jit_list = [stage0, stage0_direct, stage1, stage2,
                         join_ops._search_jit]
+
+        def whole(table, batch, matched, out_capacity: int):
+            b, lo_enc, h2 = search(table, batch)
+            return stage2(table, b, lo_enc, h2, matched, out_capacity)
+
+        def front(table, batch, matched):
+            b, lo_enc, _ = search(table, batch)
+            return stage1(table, b, lo_enc, matched)
     else:
-        @functools.partial(_kernels.jit, family=family, part=part,
-                           static_argnums=(3,))
-        def kernel(table, batch, matched, out_capacity: int):
+        def _search(table, batch):
             if _pre_batch is not None:
                 batch = _pre_batch(batch)
             with jax.named_scope("join_probe"):
-                lo_enc = join_ops._candidates_enc(
+                return batch, join_ops._candidates_enc(
                     table, batch, key_names, verify)
+
+        @functools.partial(_kernels.jit, family=family, part=part,
+                           static_argnums=(3,))
+        def whole(table, batch, matched, out_capacity: int):
+            batch, lo_enc = _search(table, batch)
             return _expand_project(table, batch, lo_enc, None, matched,
                                    out_capacity)
 
-    # compile-vs-execute attribution rides the cached kernel. The CPU
-    # form is a host wrapper over THREE jits — the per-probe stages
-    # plus the shared module-level search jit — so all executable
-    # caches are polled for compile detection. A probe with a fused
-    # upstream chain is a whole-fragment program (`fragment` family).
-    kernel = _kernels.instrument_kernel(kernel, family, jits=jit_list)
+        @functools.partial(_kernels.jit, family=family, part=part)
+        def front(table, batch, matched):
+            batch, lo_enc = _search(table, batch)
+            return _front(table, batch, lo_enc, matched)
+
+    # compile-vs-execute attribution rides the cached kernels. The CPU
+    # forms are host wrappers over several jits — the per-probe stages
+    # plus the shared module-level search jits — so all executable
+    # caches are polled for compile detection (one list for both: a
+    # stage the other form compiled reads as this one's at worst). A
+    # probe with a fused upstream chain is a whole-fragment program
+    # (`fragment` family).
+    kernel = ProbeKernel(
+        _kernels.instrument_kernel(whole, family, jits=jit_list),
+        _kernels.instrument_kernel(front, family, jits=jit_list),
+        _kernels.instrument_kernel(back, "join_probe"))
 
     if key is not None:
         _PROBE_KERNEL_CACHE[key] = kernel
@@ -573,7 +625,14 @@ class LookupJoinOperator(Operator):
     probe row matches at most one build row); the kernel's on-device
     overflow flag accumulates across batches and is fetched once per
     query by the drive loop — tripping it retries the query with a 4x
-    factor via JoinCapacityExceeded."""
+    factor via JoinCapacityExceeded.
+
+    An aligned probe (unique-run or direct build, output as wide as
+    the batch) above COMPACT_FLOOR materializes LATE: the dispatch is
+    the search and the count alone, and the batch's second dispatch,
+    one driver pass later, gathers both sides' columns at the live
+    rows' bucket (ProbeKernel.front / .back). It takes the place of
+    the deferred shrink, which no longer follows such a probe."""
 
     def __init__(self, ctx: OperatorContext, bridge: JoinBridge,
                  key_names: Tuple[str, ...], join_type: str,
@@ -654,7 +713,10 @@ class LookupJoinOperator(Operator):
         return self.bridge.ready and len(self._pending) < 2 \
             and not self._finishing
 
-    def _probe(self, table, batch: Batch) -> Batch:
+    def _probe(self, table, batch: Batch) -> Callable[[], Batch]:
+        """Dispatch one batch's probe; the returned call emits its
+        output a driver pass later, when the live count is on the
+        host."""
         # a direct table's keys are unique: no probe row expands, so
         # the output is aligned to the probe batch whatever the factor
         cap = batch.capacity if table.layout == "direct" else \
@@ -662,18 +724,44 @@ class LookupJoinOperator(Operator):
         if self.join_type == "full" and self._matched is None:
             self._matched = jnp.zeros(table.sorted_hash.shape[0],
                                       dtype=bool)
-        out, ovf, total, matched = self._kernel(
-            table, batch, self._matched, cap)
+        METRICS.inc(_LANES, batch.capacity, stage="searched")
+        # selective joins emit few rows into a fat capacity; left
+        # uncompacted that padding would ride every downstream
+        # exchange/pad/spool. An aligned probe gathers its columns
+        # only once the count is known, at the live rows' width; any
+        # other hands its count to the deferred-compact protocol.
+        late = cap > COMPACT_FLOOR and join_ops.aligned_expansion(
+            table, self.join_type, cap, batch.capacity)
+        if late:
+            side, brow, verified, ovf, live, matched = \
+                self._kernel.front(table, batch, self._matched)
+            emit = functools.partial(
+                self._materialize, table.batch, side, brow, verified,
+                start_async_copy(live))
+        else:
+            out, ovf, total, matched = self._kernel.whole(
+                table, batch, self._matched, cap)
+            METRICS.inc(_LANES, cap, stage="materialized")
+            emit = functools.partial(
+                end_deferred_compact,
+                *begin_deferred_compact(out, total))
         if self.join_type == "full":
             self._matched = matched
         self._overflow = ovf if self._overflow is None \
             else self._overflow | ovf
-        # selective joins emit few rows into a fat capacity; left
-        # uncompacted that padding would ride every downstream
-        # exchange/pad/spool. The probe kernel already computed the
-        # live count — hand it to the deferred-compact protocol.
-        from presto_tpu.batch import begin_deferred_compact
-        return begin_deferred_compact(out, total)
+        return emit
+
+    def _materialize(self, build: Batch, side: Batch, brow, verified,
+                     live) -> Batch:
+        """The aligned probe's back half, at the width
+        end_deferred_compact would shrink to: the count's copy started
+        at dispatch, so this read is normally a cache hit."""
+        n = int(to_host(live))
+        width = min(operator_capacity(n, floor=COMPACT_MIN),
+                    side.capacity)
+        METRICS.inc(_LANES, width, stage="materialized")
+        return self._kernel.back(build, side, brow, verified, live,
+                                 width)
 
     def add_input(self, batch: Batch) -> None:
         self._count_in(batch)
@@ -709,17 +797,10 @@ class LookupJoinOperator(Operator):
         self._pending.append(self._probe(
             self._cur_table, batch.filter(part == 0)))
 
-    def _emit(self, pending) -> Batch:
-        from presto_tpu.batch import end_deferred_compact
-        out, total = pending
-        return end_deferred_compact(out, total)
-
     def _emit_outer(self) -> Batch:
         """FULL OUTER tail: the never-matched build rows, NULL probe
         side. One blocking compact — once per query, after the last
         probe batch, so there is nothing left to overlap with."""
-        from presto_tpu.batch import (begin_deferred_compact,
-                                      end_deferred_compact)
         assert self.probe_schema is not None, \
             "full join needs the probe schema for its NULL side"
         table = self.bridge.table
@@ -744,7 +825,7 @@ class LookupJoinOperator(Operator):
         # full probe dispatch
         if self._pending and (len(self._pending) > 1
                               or self._finishing):
-            return self._count_out(self._emit(self._pending.pop(0)))
+            return self._count_out(self._pending.pop(0)())
         if self._pending or not self._finishing:
             return None
         if self.join_type == "full" and not self._outer_emitted:
@@ -757,8 +838,8 @@ class LookupJoinOperator(Operator):
         while self._cur_part < sp.n_parts:
             if self._probe_bufs[self._cur_part]:
                 host = self._probe_bufs[self._cur_part].pop(0)
-                out = self._probe(self._cur_table, jax.device_put(host))
-                return self._count_out(self._emit(out))
+                emit = self._probe(self._cur_table, jax.device_put(host))
+                return self._count_out(emit())
             if self._cur_part + 1 >= sp.n_parts:
                 break
             self._cur_part += 1
@@ -811,7 +892,6 @@ class SemiJoinOperator(Operator):
             and not self._finishing
 
     def add_input(self, batch: Batch) -> None:
-        from presto_tpu.batch import begin_deferred_compact
         self._count_in(batch)
         # pad first so the mark kernel keys on the bucket AND the
         # filtered output batch shares the padded capacity
@@ -825,7 +905,6 @@ class SemiJoinOperator(Operator):
     def get_output(self) -> Optional[Batch]:
         if self._pending and (len(self._pending) > 1
                               or self._finishing):
-            from presto_tpu.batch import end_deferred_compact
             out, total = self._pending.pop(0)
             return self._count_out(end_deferred_compact(out, total))
         return None

@@ -36,11 +36,14 @@ compiles once):
 
 - ALIGNED: when every build hash run has length 1 (`unique_runs` —
   any unique-key/FK->PK build) and the output capacity equals the
-  probe capacity, output slot i IS probe row i: probe columns pass
-  through untouched, the build side is two gathers, and inner misses
-  just mask their slot dead. No prefix sum, no scatter, no
-  expand-by-counts — the deferred-compact protocol downstream packs
-  the survivors once per batch.
+  probe capacity, output slot i IS probe row i: inner misses just
+  mask their slot dead. No prefix sum, no scatter, no
+  expand-by-counts. It is two bodies, `aligned_front` (search, count)
+  and `aligned_back` (gather both sides' columns at a static width):
+  under one program at the batch's width the back moves nothing; an
+  operator that reads the count between them gathers at the live
+  rows' bucket only (LATE materialization, docs/JOIN_KERNEL.md), and
+  no deferred shrink follows.
 - GENERAL: duplicate-key builds (or caller-grown capacities) take the
   prefix-sum + expand-by-counts path with a host-chosen capacity and
   the on-device overflow flag.
@@ -698,6 +701,16 @@ def _expand_dispatch(table, probe, key_names, lo_enc, h2, matched,
                             build_output, build_keys, verify, h2=h2)
 
 
+def aligned_expansion(table: BuildTable, join_type: str,
+                      out_capacity: int, probe_capacity: int) -> bool:
+    """STATIC: does this probe take the aligned layout (output slot i
+    is probe row i)? Every build hash run has length 1 and the output
+    is as wide as the probe batch."""
+    return (table.unique_runs
+            and join_type in ("inner", "left", "full")
+            and out_capacity == probe_capacity)
+
+
 def _expand_from_enc(table, probe, key_names, lo_enc, matched,
                      out_capacity, join_type, probe_output,
                      build_output, build_keys, verify, h2=None):
@@ -706,62 +719,103 @@ def _expand_from_enc(table, probe, key_names, lo_enc, matched,
     `h2` carries stage 1's probe hash2 across the CPU dispatch
     boundary so the hash-verify doesn't rehash the key columns (None
     on the fused TPU path, where XLA CSEs the recompute away)."""
-    aligned = (
-        table.unique_runs
-        and join_type in ("inner", "left", "full")
-        and out_capacity == probe.row_valid.shape[0]
-    )
+    aligned = aligned_expansion(table, join_type, out_capacity,
+                                probe.row_valid.shape[0])
     # a direct table has no hash runs to expand: its builder promised
     # a consumer that reads it aligned (HashBuildOperator.finish)
     assert aligned or table.layout != "direct", \
         f"direct build table probed unaligned: {join_type} join, " \
         f"capacity {out_capacity} for {probe.row_valid.shape[0]} rows"
     if aligned:
-        out, overflow, brow, verified = _expand_aligned(
-            table, probe, key_names, lo_enc, join_type, probe_output,
-            build_output, build_keys, verify)
-    else:
-        found = lo_enc >= 0
-        lo = jnp.maximum(lo_enc, 0)
-        counts = jnp.where(found, table.run_len[lo], 0)
-        out, overflow, brow, verified = _expand_general(
-            table, probe, key_names, lo, counts, out_capacity,
-            join_type, probe_output, build_output, "", "", build_keys,
-            verify, h2=h2)
+        # both halves under the caller's one program, at the batch's
+        # own width: every slot stays where its probe row is
+        side, brow, verified, overflow, live, matched = aligned_front(
+            table, probe, key_names, lo_enc, matched, join_type,
+            probe_output, build_keys, verify)
+        out = aligned_back(table.batch, side, brow, verified, live,
+                           out_capacity, join_type, build_output)
+        return out, overflow, live, matched
+    found = lo_enc >= 0
+    lo = jnp.maximum(lo_enc, 0)
+    counts = jnp.where(found, table.run_len[lo], 0)
+    out, overflow, brow, verified = _expand_general(
+        table, probe, key_names, lo, counts, out_capacity,
+        join_type, probe_output, build_output, "", "", build_keys,
+        verify, h2=h2)
     if join_type == "full" and matched is not None:
         matched = matched.at[brow].max(verified, mode="drop")
     return out, overflow, jnp.sum(out.row_valid), matched
 
 
-def _expand_aligned(table, probe, key_names, lo_enc, join_type,
-                    probe_output, build_output, build_keys, verify):
-    """Output slot i == probe row i (unique-run build, capacity
-    match). Probe columns pass through with a narrowed mask; the
-    build side is one gather per column pair. An inner miss is a dead
-    slot; a left/full miss keeps the probe side with a NULL build
-    side. Total output never exceeds probe rows, so overflow is
-    impossible."""
+# -- the aligned layout, materialized late ------------------------------
+#
+# A unique-run (or direct) build answers each probe row with at most
+# one build row, so output slot i IS probe row i and nothing expands.
+# The work splits where the live rows become known: the FRONT searches
+# at the batch's width and counts, the BACK gathers probe and build
+# columns at the width the count allows. An operator that waits for
+# the count between them (LookupJoinOperator) pays the payload gathers
+# over the live rows' bucket only; under one program at the batch's
+# width the back is the identity pack (docs/JOIN_KERNEL.md).
+
+
+def aligned_front(table, probe, key_names, lo_enc, matched, join_type,
+                  probe_output, build_keys, verify):
+    """The search's half: per probe row the build row (`brow`, int32)
+    and whether it matched (`verified`), the FULL join's matched-flag
+    scatter, and the count of output rows. No build OUTPUT column is
+    read; under verify="full" the build KEY columns are (the compare
+    is the search's business). An inner miss is a dead slot; a
+    left/full miss keeps its probe row. Returns (the probe side's
+    output columns, brow, verified, overflow, live count, matched);
+    the aligned layout emits at most one row a probe row, so the
+    overflow flag is constant False."""
     verified = lo_enc >= 0
-    brow = jnp.maximum(lo_enc, 0)
-    if verify == "full" and table.unique_runs:
+    brow = jnp.maximum(lo_enc, 0).astype(jnp.int32)
+    if verify == "full":
         # collision-fallback oracle: one candidate per row, compare
         # the actual key columns (stage 1 verified nothing)
         for kn, bn in zip(key_names, build_keys):
             pd, pm = probe.columns[kn].astuple()
             bd, bm = table.batch.columns[bn].astuple()
             verified = verified & (pd == bd[brow]) & pm & bm[brow]
+    if join_type == "full" and matched is not None:
+        matched = matched.at[brow].max(verified, mode="drop")
     live = probe.row_valid if join_type in ("left", "full") \
         else verified
+    return probe.select(probe_output), brow, verified, \
+        jnp.asarray(False), jnp.sum(live), matched
+
+
+def aligned_back(build: Batch, side: Batch, brow, verified, live,
+                 width: int, join_type: str, build_output) -> Batch:
+    """The payload's half, at STATIC `width` lanes: the first `live`
+    output rows of the front, in probe order. `side`, `brow`,
+    `verified` and `live` are aligned_front's; `build` is the table's
+    batch. At the probe batch's own width nothing moves (slot i stays
+    probe row i); below it the live rows pack to the front
+    (`first_true_indices` is ascending), the caller having chosen a
+    width that holds them. Probe columns are gathered at the live
+    rows, build columns at their build rows; a left/full miss keeps a
+    NULL build side."""
+    cap = side.row_valid.shape[0]
+    live_mask = side.row_valid if join_type in ("left", "full") \
+        else verified
+    if width == cap:
+        idx, row_valid = slice(None), live_mask
+    else:
+        idx = common.first_true_indices(live_mask, width, cap - 1)
+        row_valid = jnp.arange(width) < live
+        brow, verified = brow[idx], verified[idx] & row_valid
     cols: Dict[str, Column] = {}
-    for name in probe_output:
-        c = probe.columns[name]
-        cols[name] = Column(c.data, c.mask & live, c.type,
-                            c.dictionary)
+    for name, c in side.columns.items():
+        cols[name] = Column(c.data[idx], c.mask[idx] & row_valid,
+                            c.type, c.dictionary)
     for name in build_output:
-        c = table.batch.columns[name]
+        c = build.columns[name]
         cols[name] = Column(c.data[brow], c.mask[brow] & verified,
                             c.type, c.dictionary)
-    return Batch(cols, live), jnp.asarray(False), brow, verified
+    return Batch(cols, row_valid)
 
 
 def _expand_general(table, probe, key_names, lo, counts, out_capacity,
@@ -1101,6 +1155,22 @@ def _probe_direct_point(cap, variant):
         (t, p), (rt, rp))
 
 
+def _materialize_point(cap, variant):
+    from presto_tpu.analysis.contracts import sds
+    import numpy as _np
+    t, rt = _abstract_direct_table(4096, 32768)
+    p, rp = abstract_batch(cap, _probe_schema())
+    jt = variant.get("join_type", "inner")
+    # the front's outputs: its own contracts prove brow addresses a
+    # live build row and verified is False wherever the probe is dead
+    return TracePoint(
+        lambda bb, pp, brow, verified, live: aligned_back(
+            bb, pp, brow, verified, live, cap // 4, jt, ("bv",)),
+        (t.batch, p, sds((cap,), _np.int32), sds((cap,), _np.bool_),
+         sds((), _np.int64)),
+        (rt.batch, rp, "clean", "mask", "clean"))
+
+
 def _build_point(cap, variant):
     b, rb = abstract_batch(cap, _probe_schema())
     which = variant.get("entry", "sorted")
@@ -1205,6 +1275,20 @@ register_contract(KernelContract(
     family="join_probe", module=__name__,
     build=lambda cap, v: _probe_point(cap, {"join_type": "full"}),
     notes="FULL probe: matched-flag scatter rides the trace"))
+register_contract(KernelContract(
+    family="join_probe", module=__name__, build=_materialize_point,
+    structure_varies=True,
+    structure_reason="first_true_indices binary-searches the rank "
+                     "prefix: log2(capacity) unrolled rounds on the "
+                     "CPU side of fast_searchsorted",
+    notes="the aligned probe's back half at a quarter of the batch's "
+          "width: live rows packed, both sides' columns gathered"))
+register_contract(KernelContract(
+    family="join_probe", module=__name__,
+    build=lambda cap, v: _materialize_point(cap, {"join_type": "left"}),
+    structure_varies=True,
+    structure_reason="first_true_indices, as the inner back half",
+    notes="left back half: the live rows are the probe's own"))
 register_contract(KernelContract(
     family="semi_join", module=__name__, build=_semi_point,
     notes="duplicate-run scan path (bounded unroll + while_loop)"))

@@ -226,6 +226,14 @@ METRICS.describe("presto_tpu_join_direct_fallback_total",
                  "anti), multi_key, dtype (not "
                  "an integer), spread (max - min + 1 over 8 x capacity "
                  "or 2^27), duplicate (two live rows share a key)")
+METRICS.describe("presto_tpu_join_probe_lanes_total",
+                 "Lanes of the lookup join's probe batches by stage: "
+                 "searched = lanes the candidate search ran over, "
+                 "materialized = lanes at which probe and build "
+                 "columns were gathered. An aligned probe above "
+                 "COMPACT_FLOOR gathers at the bucket of its live "
+                 "count; any other at its output capacity. Static "
+                 "shapes, counted on the host")
 METRICS.describe("presto_tpu_protocol_ns_total",
                  "Client-protocol ns on the coordinator by phase: "
                  "accept = POST /v1/statement in to response out, "

@@ -207,6 +207,9 @@ READS = [
     _case("host_copy", TRANSFER % "h2d", why="by-place-alone"),
     _case("mesh_small_batches", TRANSFER % "d2d"),
     _case("one_chip", 'presto_tpu_join_builds_total{layout="direct"}'),
+    *[_case(run, 'presto_tpu_join_probe_lanes_total{stage="%s"}' % s)
+      for run in ("one_chip", "mesh")
+      for s in ("searched", "materialized")],
     _case("mesh", "presto_tpu_exchange_all_to_all_rows_total"),
     _case("mesh", "presto_tpu_exchange_all_to_all_bytes_total"),
     _case("mesh", "presto_tpu_exchange_all_to_all_waves_total"),

@@ -28,7 +28,9 @@ DEVICE_NAMES = {
     "join_build_apply_perm": "join_build",
     "join_build_direct": "join_build", "join_build_stats": "join_build",
     "join_probe_direct": "join_probe",
-    "join_probe": "join_probe", "join_probe_stage2": "join_probe",
+    "join_probe": "join_probe", "join_probe_stage1": "join_probe",
+    "join_probe_stage2": "join_probe",
+    "join_probe_materialize": "join_probe",
     "join_probe_hash": "join_probe", "join_probe_search": "join_probe",
     "join_probe_counts": "join_probe",
     "join_probe_expand": "join_probe",
@@ -44,6 +46,7 @@ DEVICE_NAMES = {
     "fragment_join_probe": "fragment",
     "fragment_join_probe_stage0": "fragment",
     "fragment_join_probe_stage0_direct": "fragment",
+    "fragment_join_probe_stage1": "fragment",
     "fragment_join_probe_stage2": "fragment",
     "agg_step": "agg_step", "agg_finalize": "agg_finalize",
     "agg_count": "agg_count", "agg_shrink": "agg_shrink",

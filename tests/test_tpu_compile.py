@@ -203,6 +203,42 @@ def test_direct_join_kernels_at_q3_size(one_chip, tpu_forks, program):
     assert ("sort(" in text) is False, "the direct layout sorts nothing"
 
 
+@pytest.mark.parametrize("half", ["front", "back"])
+def test_late_materialized_probe_at_q3_size(one_chip, tpu_forks, half):
+    """The aligned probe's two programs at the shapes of Q3's lineitem
+    join at sf1: the front searches a 1M-row batch and gathers no
+    build column; the back packs 1,048,576 lanes into 65,536 and
+    gathers 3 probe and 4 build columns there."""
+    from presto_tpu.ops import join
+    from presto_tpu.types import BIGINT, DATE, DOUBLE, INTEGER
+    table, _ = join._abstract_direct_table(BATCH, 1 << 21)
+    build, _ = join.abstract_batch(BATCH, [
+        ("bk", BIGINT), ("custkey", BIGINT), ("orderdate", DATE),
+        ("shippriority", INTEGER)])
+    probe, _ = join.abstract_batch(BATCH, [
+        ("pk", BIGINT), ("extendedprice", DOUBLE),
+        ("discount", DOUBLE)])
+    if half == "front":
+        def fn(t, p):
+            return join.aligned_front(
+                t, p, ("pk",), join._candidates_enc(t, p, ("pk",)),
+                None, "inner", tuple(p.names), ("bk",), "hash")
+        args = (table, probe)
+    else:
+        def fn(b, p, brow, verified, live):
+            return join.aligned_back(b, p, brow, verified, live,
+                                     1 << 16, "inner", tuple(b.names))
+        args = (build, probe, _sds(BATCH, jnp.int32),
+                _sds(BATCH, jnp.bool_),
+                jax.ShapeDtypeStruct((), jnp.int64))
+    text = _compile(fn, args, one_chip).as_text()
+    assert ("sort(" in text) is False, "nothing here sorts"
+    if half == "back":
+        out = jax.eval_shape(fn, *args)
+        assert {x.shape for x in jax.tree_util.tree_leaves(out)} \
+            == {(1 << 16,)}
+
+
 @pytest.mark.parametrize("family", ["spmd_shuffle", "spmd_fragment"])
 def test_exchange_shard_map_on_four_chips(topo, tpu_forks, monkeypatch,
                                           family):
