@@ -226,6 +226,29 @@ METRICS.describe("presto_tpu_join_direct_fallback_total",
                  "anti), multi_key, dtype (not "
                  "an integer), spread (max - min + 1 over 8 x capacity "
                  "or 2^27), duplicate (two live rows share a key)")
+METRICS.describe("presto_tpu_join_build_rows_total",
+                 "Live rows of the build sides indexed at "
+                 "HashBuildOperator.finish, by layout (the count its one "
+                 "fetch brings: NULL keys included, they occupy the "
+                 "merged batch)")
+METRICS.describe("presto_tpu_join_build_lanes_total",
+                 "Capacity of the merged build batch each finish "
+                 "indexed, by layout: the ladder rung the live rows "
+                 "land on. rows / lanes is the share of the rung that "
+                 "is live")
+METRICS.describe("presto_tpu_join_build_batches_total",
+                 "Input batches each finish concatenated into its "
+                 "merged build batch, by layout (0 for an empty build)")
+METRICS.describe("presto_tpu_join_direct_table_slots_total",
+                 "Length of the slot_of table of each direct build: "
+                 "the key spread rounded up to a power of two, 4 bytes "
+                 "a slot")
+METRICS.describe("presto_tpu_join_build_finish_ns_total",
+                 "Host wall ns inside HashBuildOperator.finish, first "
+                 "line to the bridge's hand-over (concat, dynamic "
+                 "filters, the table, their syncs; spilled builds too). "
+                 "The same frame is the host span join_build:finish on "
+                 "jax.profiler's timeline; it charges no ledger category")
 METRICS.describe("presto_tpu_join_probe_lanes_total",
                  "Lanes of the lookup join's probe batches by stage: "
                  "searched = lanes the candidate search ran over, "

@@ -210,6 +210,14 @@ READS = [
     *[_case(run, 'presto_tpu_join_probe_lanes_total{stage="%s"}' % s)
       for run in ("one_chip", "mesh")
       for s in ("searched", "materialized")],
+    # the three join_build_* metrics (PR 34): rows over lanes, and the
+    # finish's wall; batches and slots are read by whoever asks
+    *[_case(run, 'presto_tpu_join_build_%s_total{layout="direct"}' % n)
+      for run in ("one_chip", "mesh")
+      for n in ("rows", "lanes", "batches")],
+    *[_case(run, name) for run in ("one_chip", "mesh")
+      for name in ("presto_tpu_join_direct_table_slots_total",
+                   "presto_tpu_join_build_finish_ns_total")],
     _case("mesh", "presto_tpu_exchange_all_to_all_rows_total"),
     _case("mesh", "presto_tpu_exchange_all_to_all_bytes_total"),
     _case("mesh", "presto_tpu_exchange_all_to_all_waves_total"),

@@ -14,7 +14,10 @@ nowhere else: only one process may load the TPU's library, so nothing
 at import time, nothing in conftest.py, no child process, one file.
 """
 
+import collections
 import os
+import re
+import time
 
 import jax
 import jax.numpy as jnp
@@ -203,6 +206,22 @@ def test_direct_join_kernels_at_q3_size(one_chip, tpu_forks, program):
     assert ("sort(" in text) is False, "the direct layout sorts nothing"
 
 
+def _aligned_front(table, probe):
+    """The aligned probe's first program: the search, no build column."""
+    from presto_tpu.ops import join
+    return join.aligned_front(
+        table, probe, ("pk",), join._candidates_enc(table, probe, ("pk",)),
+        None, "inner", tuple(probe.names), ("bk",), "hash")
+
+
+def _aligned_back(build, probe, brow, verified, live):
+    """Its second: both sides' columns gathered at 65,536 lanes, the
+    bucket of a 1M-lane lineitem batch's live count in Q3."""
+    from presto_tpu.ops import join
+    return join.aligned_back(build, probe, brow, verified, live, 1 << 16,
+                             "inner", tuple(build.names))
+
+
 @pytest.mark.parametrize("half", ["front", "back"])
 def test_late_materialized_probe_at_q3_size(one_chip, tpu_forks, half):
     """The aligned probe's two programs at the shapes of Q3's lineitem
@@ -219,15 +238,9 @@ def test_late_materialized_probe_at_q3_size(one_chip, tpu_forks, half):
         ("pk", BIGINT), ("extendedprice", DOUBLE),
         ("discount", DOUBLE)])
     if half == "front":
-        def fn(t, p):
-            return join.aligned_front(
-                t, p, ("pk",), join._candidates_enc(t, p, ("pk",)),
-                None, "inner", tuple(p.names), ("bk",), "hash")
-        args = (table, probe)
+        fn, args = _aligned_front, (table, probe)
     else:
-        def fn(b, p, brow, verified, live):
-            return join.aligned_back(b, p, brow, verified, live,
-                                     1 << 16, "inner", tuple(b.names))
+        fn = _aligned_back
         args = (build, probe, _sds(BATCH, jnp.int32),
                 _sds(BATCH, jnp.bool_),
                 jax.ShapeDtypeStruct((), jnp.int64))
@@ -254,3 +267,110 @@ def test_exchange_shard_map_on_four_chips(topo, tpu_forks, monkeypatch,
     compiled = _compile(point.fn, point.args,
                         NamedSharding(mesh, P(worker_axis)))
     assert "all-to-all" in compiled.as_text()
+
+
+#: TPC-H Q3 at sf10: the orders build side is 16 input batches of
+#: BATCH lanes, merged into one batch on the ladder's 16M rung, and its
+#: direct table has 2^24 slots (64 MB: past the chip's fast memory)
+SF10_BUILD_LANES = 16 * BATCH
+SF10_TABLE_SLOTS = 1 << 24
+
+
+def _moves(text):
+    """{(gather | scatter, lanes): count} over a compiled program's
+    text: what it moves by index, and how wide."""
+    found = collections.Counter()
+    for m in re.finditer(
+            r"= \w+\[(\d+)\][^=\n]*? (gather|scatter)\(", text):
+        found[m.group(2), int(m.group(1))] += 1
+    return dict(found)
+
+
+def _sf10_orders(lanes):
+    from presto_tpu.ops import join
+    from presto_tpu.types import BIGINT, DATE, INTEGER
+    return join.abstract_batch(lanes, [
+        ("bk", BIGINT), ("custkey", BIGINT), ("orderdate", DATE),
+        ("shippriority", INTEGER)])[0]
+
+
+def _unjitted(fn):
+    while hasattr(fn, "__wrapped__"):
+        fn = fn.__wrapped__
+    return fn
+
+
+@pytest.mark.parametrize("program", [
+    "key_stats", "concat_pack", "direct_build", "distinct_set", "front",
+    "back"])
+def test_q3_build_of_sixteen_batches_at_sf10_size(
+        one_chip, tpu_forks, record_property, program):
+    """The programs Q3's lineitem-orders join runs at sf10 and never
+    at sf1 (rehearsal, PR 34: compile seconds on this sandbox beside
+    each): the stats fold of a 1M-lane input (2 s), `_compact_jit`
+    over the 16,777,216-lane concat of the 16 inputs x 4 orders
+    columns (15 s), the direct build into 2^24 slots (14 s), the
+    dynamic filter's distinct set over the merged key column (its
+    sort: 84 s, the longest), and the aligned probe's front against
+    the 64 MB table (0.5 s) and back from 1M to 65,536 lanes over the
+    16M-lane build batch (0.6 s)."""
+    from presto_tpu import batch as batch_mod
+    from presto_tpu.execution import dynamic_filters
+    from presto_tpu.ops import join
+    from presto_tpu.types import BIGINT, DOUBLE
+    wide = SF10_BUILD_LANES
+    lineitem = join.abstract_batch(BATCH, [
+        ("pk", BIGINT), ("extendedprice", DOUBLE),
+        ("discount", DOUBLE)])[0]
+    if program == "key_stats":
+        fn = _unjitted(join.key_stats_step)
+        args = (_sds(3, jnp.int64), _sds(BATCH, jnp.int64),
+                _sds(BATCH, jnp.bool_), _sds(BATCH, jnp.bool_))
+        want = {}
+    elif program == "concat_pack":
+        # one scatter (partition_perm), then per column a gather of
+        # the data (two 32-bit halves for a BIGINT) and of the mask,
+        # and row_valid's: all at the merged width
+        fn, args = _unjitted(batch_mod._compact_jit), (_sf10_orders(wide),)
+        want = {("scatter", wide): 1, ("gather", wide): 11}
+    elif program == "direct_build":
+        fn = lambda b, st: _unjitted(join._build_direct)(  # noqa: E731
+            b, "bk", st, SF10_TABLE_SLOTS)
+        args = (_sf10_orders(wide), _sds(3, jnp.int64))
+        want = {("scatter", SF10_TABLE_SLOTS): 1, ("gather", wide): 1}
+    elif program == "distinct_set":
+        fn = _unjitted(dynamic_filters.distinct_set)
+        args = (_sds(wide, jnp.int64), _sds(wide, jnp.bool_))
+        # the sorted key's two halves and its mask, then the packed
+        # DF_SET_MAX slots
+        want = {("gather", wide): 3,
+                ("gather", dynamic_filters.DF_SET_MAX): 3}
+    elif program == "front":
+        table, _ = join._abstract_direct_table(wide, SF10_TABLE_SLOTS)
+        fn, args = _aligned_front, (table, lineitem)
+        want = {("gather", BATCH): 1}   # slot_of[key - min], no other
+    else:
+        fn = _aligned_back
+        args = (_sf10_orders(wide), lineitem, _sds(BATCH, jnp.int32),
+                _sds(BATCH, jnp.bool_),
+                jax.ShapeDtypeStruct((), jnp.int64))
+        want = {("gather", 1 << 16): 22}        # none at a wider lane
+    t0 = time.perf_counter()
+    compiled = _compile(fn, args, one_chip)
+    seconds = time.perf_counter() - t0
+    record_property("compile_seconds", round(seconds, 1))
+    print(f"{program}: compiled for a described v5e in {seconds:.1f} s")
+    text = compiled.as_text()
+    assert _moves(text) == want
+    # at 16M lanes XLA:TPU lowers a scatter through a sort of its
+    # (index, lane) pairs, which it does not at sf1's 1M lanes
+    # (test_direct_join_kernels_at_q3_size): the pack's and the
+    # build's one scatter each; the distinct set's sort is its own
+    assert text.count(" sort(") == (
+        1 if program in ("concat_pack", "direct_build", "distinct_set")
+        else 0)
+    # one program's share of the chip's 16 GB: arguments, outputs and
+    # temporaries (the merged batch is 0.49 GB of each)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes \
+        + mem.output_size_in_bytes < 2 << 30
