@@ -389,15 +389,10 @@ class Batch:
         return Batch(cols, jnp.pad(out.row_valid, (0, pad)))
 
     @staticmethod
-    def concat(batches: Sequence["Batch"], capacity: int,
-               live_rows: Optional[int] = None) -> "Batch":
-        """Concatenate live rows of compatible batches into one batch.
-
-        Fully device-side: pad-concat every (padded) batch, then compact
-        live rows to the front — no host materialization. A device->host
-        roundtrip here costs a full pipeline flush, which used to
-        dominate ORDER BY.
-        """
+    def _concatenated(batches: Sequence["Batch"]) -> "Batch":
+        """Every lane of compatible batches, in arrival order: one
+        eager concatenate per column's data and mask, and row_valid's.
+        Nothing moves within a batch; the capacity is the inputs' sum."""
         assert batches
         names = batches[0].names
         first = batches[0]
@@ -408,7 +403,6 @@ class Batch:
                     raise ValueError(
                         f"concat with mismatched dictionaries on {n!r}; "
                         "unify dictionaries first")
-        total_cap = sum(b.capacity for b in batches)
         cols: Dict[str, Column] = {}
         for n in names:
             typ = first.columns[n].type
@@ -418,8 +412,38 @@ class Batch:
                 [b.columns[n].mask for b in batches])
             cols[n] = Column(data, mask, typ, dics[n])
         rv = jnp.concatenate([b.row_valid for b in batches])
-        big = Batch(cols, rv)
-        if total_cap == capacity:
+        return Batch(cols, rv)
+
+    @staticmethod
+    def concat_lanes(batches: Sequence["Batch"],
+                     capacity: int) -> "Batch":
+        """Concatenate compatible batches WITHOUT packing: every lane
+        stays where it arrived (dead lanes between the live ones
+        included), and `capacity - sum(b.capacity)` dead lanes follow.
+        For a consumer that reads rows through `row_valid` or by index
+        and so has no use for a packed prefix (the join build); the
+        inputs must fit `capacity`."""
+        big = Batch._concatenated(batches)
+        if big.capacity > capacity:
+            raise ValueError(
+                f"concat_lanes: {big.capacity} input lanes do not fit "
+                f"{capacity}")
+        if big.capacity == capacity:
+            return big
+        return _pad_batch(big, capacity - big.capacity)
+
+    @staticmethod
+    def concat(batches: Sequence["Batch"], capacity: int,
+               live_rows: Optional[int] = None) -> "Batch":
+        """Concatenate live rows of compatible batches into one batch.
+
+        Fully device-side: concatenate every (padded) batch, then
+        compact live rows to the front — no host materialization. A
+        device->host roundtrip here costs a full pipeline flush, which
+        used to dominate ORDER BY.
+        """
+        big = Batch._concatenated(batches)
+        if big.capacity == capacity:
             return _compact(big)
         return big.compact(capacity, known_valid=live_rows)
 

@@ -329,8 +329,19 @@ class HashBuildOperator(Operator):
         # compiled probe (padding-clip keeps the dead tail out of
         # every search span, see ops/join.py)
         cap = operator_capacity(total)
-        if self._batches:
+        # packing live rows to the front pays only when it lets the
+        # merged batch shrink onto a smaller rung. When the inputs'
+        # lanes already fit the rung it would move rows inside a batch
+        # of the same shape, and no reader needs the order: the direct
+        # layout addresses rows by index (`slot_of`), the sorted one
+        # permutes every row itself and clips its spans at the first
+        # dead one, `distinct_set` and FULL's unmatched rows go by
+        # `row_valid`. So the lanes stay where they arrived
+        packed = sum(b.capacity for b in self._batches) > cap
+        if packed:
             merged = Batch.concat(self._batches, cap, live_rows=total)
+        elif self._batches:
+            merged = Batch.concat_lanes(self._batches, cap)
         elif self.schema_cols is not None:
             # a pruned/empty build side is a legal input (e.g. a fully
             # pushed-down scan): index an all-invalid batch
@@ -354,6 +365,10 @@ class HashBuildOperator(Operator):
                     layout=table.layout)
         METRICS.inc("presto_tpu_join_build_batches_total",
                     len(self._batches), layout=table.layout)
+        # by 0 all the same: the series then says "never packed"
+        METRICS.inc("presto_tpu_join_build_packed_lanes_total",
+                    merged.capacity if packed else 0,
+                    layout=table.layout)
         if table.layout == "direct":
             METRICS.inc("presto_tpu_join_direct_table_slots_total",
                         table.slot_of.shape[0])

@@ -239,6 +239,13 @@ METRICS.describe("presto_tpu_join_build_lanes_total",
 METRICS.describe("presto_tpu_join_build_batches_total",
                  "Input batches each finish concatenated into its "
                  "merged build batch, by layout (0 for an empty build)")
+METRICS.describe("presto_tpu_join_build_packed_lanes_total",
+                 "Capacity of the merged build batch each finish PACKED "
+                 "(live rows moved to the front: Batch.concat), by "
+                 "layout; grows by 0 for a build whose input lanes "
+                 "already fit the rung and stay in arrival order "
+                 "(Batch.concat_lanes). packed / lanes is the share of "
+                 "build lanes a pack passed over")
 METRICS.describe("presto_tpu_join_direct_table_slots_total",
                  "Length of the slot_of table of each direct build: "
                  "the key spread rounded up to a power of two, 4 bytes "

@@ -2,6 +2,7 @@
 presto-common block tests, e.g. TestDictionaryBlock / TestPage)."""
 
 import numpy as np
+import pytest
 
 from presto_tpu import Batch, Column, BIGINT, DOUBLE, VARCHAR, BOOLEAN
 from presto_tpu.batch import bucket_capacity, unify_dictionaries
@@ -58,6 +59,64 @@ def test_concat():
     b2 = Batch.from_pydict({"x": ([3, None], BIGINT)})
     out = Batch.concat([b1, b2], capacity=16)
     assert out.to_pydict()["x"] == [1, 2, 3, None]
+
+
+def _lanes(b):
+    """Every lane of a batch, dead ones included: (live, not NULL,
+    data)."""
+    c = b.columns["x"]
+    return list(zip(np.asarray(b.row_valid).tolist(),
+                    (np.asarray(c.mask) & np.asarray(b.row_valid)).tolist(),
+                    np.asarray(c.data).tolist()))
+
+
+def _concat_inputs():
+    import jax.numpy as jnp
+    b1 = Batch.from_pydict({"x": ([1, 2], BIGINT)})          # test_concat's
+    b2 = Batch.from_pydict({"x": ([3, None], BIGINT)})
+    # dead lanes between live ones, and one that still holds a value
+    b3 = Batch.from_pydict({"x": ([7, 8, 9, 10], BIGINT)}).filter(
+        jnp.asarray(np.array([True, False, True, False] + [True] * 12)))
+    return b1, b2, b3
+
+
+@pytest.mark.parametrize("capacity", (48, 64))
+def test_concat_lanes_keeps_every_lane_where_it_arrived(capacity):
+    b1, b2, b3 = _concat_inputs()
+    out = Batch.concat_lanes([b1, b3, b2], capacity)
+    assert out.capacity == capacity
+    assert _lanes(out) == _lanes(b1) + _lanes(b3) + _lanes(b2) \
+        + [(False, False, 0)] * (capacity - 48)
+    assert out.to_pydict()["x"] == [1, 2, 7, 9, 3, None]
+
+
+def test_concat_lanes_refuses_inputs_past_the_capacity():
+    b1, b2, b3 = _concat_inputs()
+    with pytest.raises(ValueError, match="do not fit"):
+        Batch.concat_lanes([b1, b2, b3], 32)
+    with pytest.raises(ValueError, match="dictionaries"):
+        Batch.concat_lanes([
+            Batch.from_pydict({"s": (["a"], VARCHAR)}),
+            Batch.from_pydict({"s": (["b"], VARCHAR)})], 32)
+
+
+@pytest.mark.parametrize("capacity", (16, 32, 48, 64))
+@pytest.mark.parametrize("which", ((0, 1), (0, 2, 1), (2, 2)))
+def test_concat_is_concat_lanes_then_pack(which, capacity):
+    """Batch.concat's answer for its other callers (sort, window,
+    array_agg, the spilled build): live rows first, in arrival order,
+    on `capacity` lanes: what packing concat_lanes' batch gives, lane
+    for lane, where the inputs fit, and the shrink where they do not."""
+    batches = [_concat_inputs()[i] for i in which]
+    live = [v for b in batches for v in b.to_pydict()["x"]]
+    out = Batch.concat(batches, capacity)
+    assert out.capacity == capacity
+    assert out.to_pydict()["x"] == live
+    assert np.asarray(out.row_valid).tolist() == (
+        np.arange(capacity) < len(live)).tolist()
+    if sum(b.capacity for b in batches) <= capacity:
+        assert _lanes(out) == _lanes(
+            Batch.concat_lanes(batches, capacity).compact())
 
 
 def test_unify_dictionaries():

@@ -218,6 +218,12 @@ READS = [
     *[_case(run, name) for run in ("one_chip", "mesh")
       for name in ("presto_tpu_join_direct_table_slots_total",
                    "presto_tpu_join_build_finish_ns_total")],
+    # join_build_packed_lane_share (PR 35): Q3's builds fit their rung,
+    # so nothing is packed and the series grows by 0: it has to be there
+    *[_case(run, 'presto_tpu_join_build_packed_lanes_total'
+            '{layout="direct"}', _present,
+            why="present:builds-that-fit-their-rung-pack-nothing")
+      for run in ("one_chip", "mesh")],
     _case("mesh", "presto_tpu_exchange_all_to_all_rows_total"),
     _case("mesh", "presto_tpu_exchange_all_to_all_bytes_total"),
     _case("mesh", "presto_tpu_exchange_all_to_all_waves_total"),

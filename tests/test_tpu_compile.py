@@ -301,15 +301,19 @@ def _unjitted(fn):
 
 
 @pytest.mark.parametrize("program", [
-    "key_stats", "concat_pack", "direct_build", "distinct_set", "front",
-    "back"])
+    "key_stats", "concat_lanes", "concat_pack", "direct_build",
+    "distinct_set", "front", "back"])
 def test_q3_build_of_sixteen_batches_at_sf10_size(
         one_chip, tpu_forks, record_property, program):
     """The programs Q3's lineitem-orders join runs at sf10 and never
     at sf1 (rehearsal, PR 34: compile seconds on this sandbox beside
-    each): the stats fold of a 1M-lane input (2 s), `_compact_jit`
-    over the 16,777,216-lane concat of the 16 inputs x 4 orders
-    columns (15 s), the direct build into 2^24 slots (14 s), the
+    each): the stats fold of a 1M-lane input (2 s), the 16-way
+    concatenation of the inputs x 4 orders columns into 16,777,216
+    lanes, which is all the build's merge does since PR 35
+    (`Batch.concat_lanes`: copies, nothing moved by index),
+    `_compact_jit` over a batch that wide (15 s; the sort and window
+    operators' merge still packs, `Batch.concat`), the direct build
+    over the un-packed batch into 2^24 slots (14 s), the
     dynamic filter's distinct set over the merged key column (its
     sort: 84 s, the longest), and the aligned probe's front against
     the 64 MB table (0.5 s) and back from 1M to 65,536 lanes over the
@@ -327,6 +331,11 @@ def test_q3_build_of_sixteen_batches_at_sf10_size(
         args = (_sds(3, jnp.int64), _sds(BATCH, jnp.int64),
                 _sds(BATCH, jnp.bool_), _sds(BATCH, jnp.bool_))
         want = {}
+    elif program == "concat_lanes":
+        fn = lambda *bs: batch_mod.Batch.concat_lanes(  # noqa: E731
+            bs, wide)
+        args = tuple(_sf10_orders(BATCH) for _ in range(16))
+        want = {}                       # no gather, no scatter: copies
     elif program == "concat_pack":
         # one scatter (partition_perm), then per column a gather of
         # the data (two 32-bit halves for a BIGINT) and of the mask,
