@@ -214,9 +214,13 @@ def make_agg_step_kernel(key_exprs: Sequence[CompiledExpr],
                 tuple(merge))
 
     # a kernel with a fused upstream chain is a whole-fragment program:
-    # family `fragment`, device name `fragment_agg_step`
-    family, part = ("fragment", "agg_step") if pre is not None \
-        else ("agg_step", None)
+    # family `fragment`, device name `fragment_agg_step`; the unfused
+    # presorted grouping (the streaming operator's step) keeps its
+    # family and gets a device name of its own, `agg_step_presorted`
+    if pre is not None:
+        family, part = "fragment", "agg_step"
+    else:
+        family, part = "agg_step", "presorted" if presorted else None
 
     if domains is not None:
         @functools.partial(_kernels.jit, family=family, part=part)
@@ -742,6 +746,9 @@ class StreamingAggregationOperator(Operator):
     max_groups table, no overflow retry — the property the reference
     operator exists for. Output batches hold groups in key order, so
     an ORDER BY on the group keys above this operator is a no-op."""
+
+    row_series = ("presto_tpu_agg_stream_rows_total",
+                  "presto_tpu_agg_stream_groups_total")
 
     def __init__(self, ctx: OperatorContext, key_names: Sequence[str],
                  key_exprs: Sequence[CompiledExpr],

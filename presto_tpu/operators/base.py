@@ -7,7 +7,7 @@ from __future__ import annotations
 import abc
 import dataclasses
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 from presto_tpu.batch import Batch
 
@@ -209,6 +209,11 @@ class Operator(abc.ABC):
     - `is_finished()` true when no more output will be produced
     - `is_blocked()` returns False or a reason string (driver yields)
     """
+
+    #: (counter of input rows, counter of output rows) that a drained
+    #: statement adds this operator's armed row counts to
+    #: (telemetry/stats.py:count_streamed_rows); None: no such series
+    row_series: Optional[Tuple[str, str]] = None
 
     def __init__(self, ctx: OperatorContext):
         self.ctx = ctx

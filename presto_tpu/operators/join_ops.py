@@ -906,6 +906,9 @@ class SemiJoinOperator(Operator):
     56-live-row batches at 64k capacity feeding the final
     aggregation)."""
 
+    row_series = ("presto_tpu_semi_join_probe_rows_total",
+                  "presto_tpu_semi_join_matched_rows_total")
+
     def __init__(self, ctx: OperatorContext, bridge: JoinBridge,
                  key_names: Tuple[str, ...], negate: bool,
                  build_keys: Optional[Tuple[str, ...]] = None,

@@ -1410,11 +1410,13 @@ class LocalRunner:
             # lightweight counters (batches, busy, compile/execute,
             # cache) on plain runs, plus rows/bytes under profile
             from presto_tpu.telemetry import (
-                render_operator_stats, snapshot_drivers,
+                count_streamed_rows, render_operator_stats,
+                snapshot_drivers,
             )
             with _ledger.span("driver.reassembly"):
                 snap = snapshot_drivers(drivers, pool)
                 self._session_tl.op_stats = snap
+                count_streamed_rows(drivers)
                 # the history recording tap: ONLY here — past every
                 # deferred overflow check, after drivers closed
                 # cleanly. Failed/cancelled/shed runs raised out

@@ -40,5 +40,6 @@ from presto_tpu.telemetry import (  # noqa: F401
     critical_path, flight, kernels, ledger, metrics, trace,
 )
 from presto_tpu.telemetry.stats import (  # noqa: F401
-    build_query_stats, render_operator_stats, snapshot_drivers,
+    build_query_stats, count_streamed_rows, render_operator_stats,
+    snapshot_drivers,
 )

@@ -264,6 +264,24 @@ METRICS.describe("presto_tpu_join_probe_lanes_total",
                  "COMPACT_FLOOR gathers at the bucket of its live "
                  "count; any other at its output capacity. Static "
                  "shapes, counted on the host")
+METRICS.describe("presto_tpu_agg_stream_rows_total",
+                 "Live rows into the streaming aggregations of drained "
+                 "statements (the operator's input, before a fused "
+                 "upstream filter). Added at the statement's drain from "
+                 "the operator's armed row counter: profile, or the "
+                 "history recorder's interesting_ops")
+METRICS.describe("presto_tpu_agg_stream_groups_total",
+                 "Groups the streaming aggregations of drained "
+                 "statements emitted (the operator's output rows; "
+                 "counted as presto_tpu_agg_stream_rows_total is)")
+METRICS.describe("presto_tpu_semi_join_probe_rows_total",
+                 "Live rows the semi joins of drained statements "
+                 "probed (the operator's input rows; counted as "
+                 "presto_tpu_agg_stream_rows_total is)")
+METRICS.describe("presto_tpu_semi_join_matched_rows_total",
+                 "Rows the semi joins of drained statements kept: "
+                 "probe rows with a match, or for NOT IN / NOT EXISTS "
+                 "those without one (the operator's output rows)")
 METRICS.describe("presto_tpu_protocol_ns_total",
                  "Client-protocol ns on the coordinator by phase: "
                  "accept = POST /v1/statement in to response out, "

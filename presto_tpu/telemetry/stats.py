@@ -11,6 +11,8 @@ from __future__ import annotations
 
 from typing import Any, Dict, List, Optional
 
+from presto_tpu.telemetry.metrics import METRICS
+
 
 def snapshot_drivers(drivers, pool=None) -> List[List[Dict[str, Any]]]:
     """Materialize per-operator stats into JSON-able dicts, one list
@@ -30,6 +32,23 @@ def snapshot_drivers(drivers, pool=None) -> List[List[Dict[str, Any]]]:
             ops.append(s)
         out.append(ops)
     return out
+
+
+def count_streamed_rows(drivers) -> None:
+    """Add a drained statement's streaming-aggregation and semi-join
+    rows to the process's counters (the operators that name a
+    `row_series`). Call it after snapshot_drivers, which has
+    materialized the stats: it reads no device value of its own. Only
+    operators whose row counters were armed (profile, or the history
+    recorder's interesting_ops) count."""
+    for d in drivers:
+        for op in d.operators:
+            stats = op.ctx.stats
+            if op.row_series is None or not stats.count_rows:
+                continue
+            rows_in, rows_out = op.row_series
+            METRICS.inc(rows_in, stats.input_rows)
+            METRICS.inc(rows_out, stats.output_rows)
 
 
 def _ms(ns: int) -> float:

@@ -93,6 +93,14 @@ def one_chip():
 
 
 @pytest.fixture(scope="module")
+def q18():
+    """Q18 twice: the statement of the cell sf1_q18_serial. On tiny
+    no order's lines pass QUANTITY = 300, so the semi join keeps no
+    row; everything before it runs as at any scale."""
+    return _serve({}, [QUERIES[18]] * 2)
+
+
+@pytest.fixture(scope="module")
 def mesh():
     """Q3 twice on the served four-device mesh of test_mesh_served.py;
     the second arrives while the mesh is held, so it waits for it."""
@@ -224,6 +232,18 @@ READS = [
             '{layout="direct"}', _present,
             why="present:builds-that-fit-their-rung-pack-nothing")
       for run in ("one_chip", "mesh")],
+    # the cell sf1_q18_serial (PR 37): the semi join's build is the
+    # one that stays sorted, the streaming aggregation and the semi
+    # join add their rows at the statement's drain
+    _case("q18", 'presto_tpu_join_builds_total{layout="sorted"}'),
+    _case("q18", 'presto_tpu_join_direct_fallback_total'
+          '{reason="join_type"}'),
+    _case("q18", 'presto_tpu_kernel_calls_total{kernel="agg_stream"}'),
+    _case("q18", "presto_tpu_agg_stream_rows_total"),
+    _case("q18", "presto_tpu_agg_stream_groups_total"),
+    _case("q18", "presto_tpu_semi_join_probe_rows_total"),
+    _case("q18", "presto_tpu_semi_join_matched_rows_total", _present,
+          why="present:no-order-passes-300-on-tiny"),
     _case("mesh", "presto_tpu_exchange_all_to_all_rows_total"),
     _case("mesh", "presto_tpu_exchange_all_to_all_bytes_total"),
     _case("mesh", "presto_tpu_exchange_all_to_all_waves_total"),

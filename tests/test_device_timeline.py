@@ -48,7 +48,8 @@ DEVICE_NAMES = {
     "fragment_join_probe_stage0_direct": "fragment",
     "fragment_join_probe_stage1": "fragment",
     "fragment_join_probe_stage2": "fragment",
-    "agg_step": "agg_step", "agg_finalize": "agg_finalize",
+    "agg_step": "agg_step", "agg_step_presorted": "agg_step",
+    "agg_finalize": "agg_finalize",
     "agg_count": "agg_count", "agg_shrink": "agg_shrink",
     "agg_stream": "agg_stream", "hashagg_merge": "hashagg_merge",
     "array_agg_collect": "array_agg", "array_agg_eval": "array_agg",

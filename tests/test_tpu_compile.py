@@ -383,3 +383,136 @@ def test_q3_build_of_sixteen_batches_at_sf10_size(
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes \
         + mem.output_size_in_bytes < 2 << 30
+
+
+#: TPC-H Q18 at sf1 (the cell sf1_q18_serial): about half of lineitem's
+#: scan batches overshoot 2^20 rows by a few thousand and ride this rung
+#: a quarter live; the 1,500,000-row orders build sits on it too
+WIDE = 4 * BATCH
+#: the semi join's build side (the orders whose lines pass QUANTITY =
+#: 300: under a hundred rows at sf1) lands on the ladder's smallest rung
+SMALLEST_RUNG = 1 << 12
+#: the 150,000-row customer build's rung
+CUSTOMER_LANES = 1 << 18
+
+
+def _sorted_table(lanes):
+    """An abstract sorted-hash BuildTable over one unique BIGINT key
+    (the semi join's build side carries no payload)."""
+    from presto_tpu.ops import join
+    from presto_tpu.types import BIGINT
+    batch = join.abstract_batch(lanes, [("bk", BIGINT)])[0]
+    k = join.choose_radix_bits(lanes)
+    return join.BuildTable(
+        _sds(lanes, jnp.int64), _sds(lanes, jnp.int64),
+        _sds((1 << k) + 1, jnp.int64), _sds(lanes, jnp.int64),
+        jax.ShapeDtypeStruct((), jnp.int64), batch, radix_bits=k,
+        search_depth=8, unique_runs=True)
+
+
+@pytest.mark.parametrize("program", [
+    "presorted_step_4m", "presorted_step_1m", "agg_stream", "sorted_build", "semi_probe_4m",
+    "back_full_width", "customer_back_full_width", "five_key_agg_step"])
+def test_q18_programs_at_the_cells_size(one_chip, tpu_forks,
+                                        record_property, program):
+    """The programs Q18 runs in the cell sf1_q18_serial that no cell of
+    the benchmark ran before PR 37 (rehearsal, PR 37: compile seconds
+    on this sandbox in CHANGES.md): the streaming aggregation's
+    presorted grouping of a lineitem batch at 4,194,304 and at
+    1,048,576 lanes (a cummax, a prefix sum and the searchsorted
+    reduces: no sort) and its boundary fold with a carried group
+    (`agg_stream`); the sorted-hash build of the semi join's build side
+    on the smallest rung and the `semi_join` probe of a 4M-lane batch
+    against it; the aligned probe's back at K = capacity (every
+    lineitem row finds its order: 4M -> 4M lanes, nothing packed, 3
+    orders columns gathered out of the 4,194,304-lane build batch; then
+    1 customer column out of a 262,144-lane one); and the final
+    aggregation's step over five keys, one a dictionary VARCHAR's int32
+    code and one a DOUBLE."""
+    from presto_tpu.operators import aggregation
+    from presto_tpu.ops import hashagg, join
+    from presto_tpu.types import BIGINT, DATE, DOUBLE, VARCHAR
+    aggs = (hashagg.make_sum(DOUBLE, DOUBLE),)
+    sorts = 0
+    if program.startswith("presorted_step"):
+        n = WIDE if program.endswith("4m") else BATCH
+
+        def fn(valid, k, km, x, xm):
+            return hashagg.presorted_aggregate(
+                valid, [(k, km)], [x], [valid & xm], aggs, n)
+        args = (_sds(n, jnp.bool_), _sds(n, jnp.int64),
+                _sds(n, jnp.bool_), _sds(n, jnp.float64),
+                _sds(n, jnp.bool_))
+    elif program == "agg_stream":
+        fn = lambda c, p: _unjitted(  # noqa: E731
+            aggregation._stream_step_jit)(c, p, aggs)
+        args = (jax.eval_shape(lambda: hashagg.init_state(
+                    [BIGINT], aggs, 1)),
+                jax.eval_shape(lambda: hashagg.init_state(
+                    [BIGINT], aggs, BATCH)))
+    elif program == "sorted_build":
+        fn = lambda b: _unjitted(join._build_sorted)(  # noqa: E731
+            b, ("bk",), join.choose_radix_bits(SMALLEST_RUNG))
+        args = (join.abstract_batch(SMALLEST_RUNG, [("bk", BIGINT)])[0],)
+        sorts = 1                       # the order by hash
+    elif program == "semi_probe_4m":
+        fn = lambda t, p: _unjitted(  # noqa: E731
+            join._semi_unique_fused)(t, p, ("pk",))
+        args = (_sorted_table(SMALLEST_RUNG),
+                join.abstract_batch(WIDE, [
+                    ("pk", BIGINT), ("quantity", DOUBLE)])[0])
+    elif program == "back_full_width":
+        fn = lambda b, s, brow, ok, live: join.aligned_back(  # noqa: E731
+            b, s, brow, ok, live, WIDE, "inner",
+            ("custkey", "orderdate", "totalprice"))
+        args = (join.abstract_batch(WIDE, [
+                    ("bk", BIGINT), ("custkey", BIGINT),
+                    ("orderdate", DATE), ("totalprice", DOUBLE)])[0],
+                join.abstract_batch(WIDE, [
+                    ("pk", BIGINT), ("quantity", DOUBLE)])[0],
+                _sds(WIDE, jnp.int32), _sds(WIDE, jnp.bool_),
+                jax.ShapeDtypeStruct((), jnp.int64))
+    elif program == "customer_back_full_width":
+        fn = lambda b, s, brow, ok, live: join.aligned_back(  # noqa: E731
+            b, s, brow, ok, live, WIDE, "inner", ("name",))
+        args = (join.abstract_batch(CUSTOMER_LANES, [
+                    ("bk", BIGINT), ("name", VARCHAR)])[0],
+                join.abstract_batch(WIDE, [
+                    ("pk", BIGINT), ("quantity", DOUBLE),
+                    ("custkey", BIGINT), ("orderdate", DATE),
+                    ("totalprice", DOUBLE)])[0],
+                _sds(WIDE, jnp.int32), _sds(WIDE, jnp.bool_),
+                jax.ShapeDtypeStruct((), jnp.int64))
+    else:
+        n = SMALLEST_RUNG
+
+        def fn(valid, name, custkey, orderkey, orderdate, totalprice, x):
+            return hashagg.batch_aggregate(
+                valid, [(k, valid) for k in (name, custkey, orderkey,
+                                             orderdate, totalprice)],
+                [x], [valid], aggs, n)
+        args = (_sds(n, jnp.bool_), _sds(n, jnp.int32),
+                _sds(n, jnp.int64), _sds(n, jnp.int64),
+                _sds(n, jnp.int32), _sds(n, jnp.float64),
+                _sds(n, jnp.float64))
+        sorts = None                    # the grouping sorts: not counted
+    t0 = time.perf_counter()
+    compiled = _compile(fn, args, one_chip)
+    seconds = time.perf_counter() - t0
+    record_property("compile_seconds", round(seconds, 1))
+    print(f"{program}: compiled for a described v5e in {seconds:.1f} s")
+    text = compiled.as_text()
+    if sorts is not None:
+        assert text.count(" sort(") == sorts
+    if program.endswith("back_full_width"):
+        # K = capacity: the probe's own columns stay where they are
+        # and only the build's are gathered, at the batch's width
+        # (a 64-bit column is two 32-bit gathers on this chip)
+        moves = _moves(text)
+        assert set(moves) == {("gather", WIDE)}, moves
+        out = jax.eval_shape(fn, *args)
+        assert {x.shape for x in jax.tree_util.tree_leaves(out)} \
+            == {(WIDE,)}
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes \
+        + mem.output_size_in_bytes < 2 << 30
