@@ -377,7 +377,7 @@ def boundaries(sorted_keys: Sequence[CVal],
     `hashes` (already in the same sorted order) extends the adjacent
     compare for HASH-ordered grouping: rows are grouped by (hashes,
     keys), so equal-key adjacency only needs the hash sort, not a full
-    lexicographic key sort (see hashagg._group_reduce's CPU path)."""
+    lexicographic key sort (see hashagg._group_reduce)."""
     n = sorted_valid.shape[0]
     first = jnp.zeros(n, bool).at[0].set(True)
     change = first

@@ -503,57 +503,48 @@ def init_state(key_types: Sequence[Type], aggs: Sequence[AggFunction],
                         jnp.asarray(False))
 
 
-def _use_searchsorted() -> bool:
-    """Platform fork, decided at TRACE time (kernels compile per
-    backend): on TPU, a prefix sum + two searchsorted gathers stand in
-    for the scatter-lowered segment_sum (which of the two runs faster
-    there is not measured; both compile in seconds); on XLA:CPU
-    searchsorted lowers to a per-slot binary-search loop (~86ms per
-    1M slots measured) while the sorted-hint segment ops run a fast
-    linear pass (~4ms)."""
-    return jax.default_backend() == "tpu"
-
-
 def _first_rows(bnd: jnp.ndarray, gid_m: jnp.ndarray, out_cap: int
                 ) -> jnp.ndarray:
-    """Index of the first row of each packed group (clipped into
-    range), given monotone group ids and the boundary mask. TPU:
-    binary search on the monotone gid. CPU: segment_min of the
-    boundary rows' indices (dead/overflow rows contribute n)."""
-    n = gid_m.shape[0]
-    if _use_searchsorted():
-        slots = jnp.arange(out_cap, dtype=gid_m.dtype)
-        return jnp.clip(
-            jnp.searchsorted(gid_m, slots, side="left"), 0, n - 1)
-    idx = jnp.where(bnd, jnp.arange(n), n)
-    first = jax.ops.segment_min(
-        idx, jnp.clip(gid_m, 0, out_cap).astype(jnp.int32),
-        num_segments=out_cap + 1, indices_are_sorted=True)[:out_cap]
-    return jnp.clip(first, 0, n - 1)
+    """first[g], g in 0..out_cap: the row where packed group g starts,
+    n where the batch holds no such group (int32, non-decreasing).
+    `gid_m` is `prefix_sum(bnd) - 1`, so it is unique over the boundary
+    rows: ONE scatter of their indices by their own id, every other
+    row dropped past the end. `out_cap + 1` entries, so that the last
+    kept group has an end: group g is rows first[g] .. first[g + 1]
+    (dead rows inside it and after the last group included; they carry
+    the reduce identity). One form on every backend. On a v5e the
+    scatter is 24 ms at 4M lanes (the sort of the indices XLA:TPU puts
+    before it included; 5.7 at 1M) where a binary search per slot was
+    691 ms, three times a presorted step; the sorted segment_min of
+    the same indices is 38 ms (PERF.md, PR 38)."""
+    n = bnd.shape[0]
+    rows = jnp.arange(n, dtype=jnp.int32)
+    at = jnp.where(bnd, gid_m, out_cap + 1).astype(jnp.int32)
+    return jnp.full(out_cap + 1, n, jnp.int32).at[at].set(
+        rows, mode="drop")
 
 
-def _sorted_reduce(sarr: jnp.ndarray, gid: jnp.ndarray, out_cap: int,
+def _sorted_reduce(sarr: jnp.ndarray, gid: jnp.ndarray,
+                   first: jnp.ndarray, out_cap: int,
                    reduce: str) -> jnp.ndarray:
     """Reduce a contribution array ALREADY SORTED by ascending group id
-    into `out_cap` packed slots (dead rows carry gid == out_cap).
+    into `out_cap` packed slots (dead rows carry the reduce identity,
+    and gid == out_cap where they belong to no kept group).
 
-    On TPU, integer sums use a prefix sum + two searchsorted gathers
-    of size out_cap instead of the scatter-lowered segment_sum, exact
-    under wrapping arithmetic. Floats keep segment_sum: a
-    cumsum-difference would leak one group's NaN into every later
-    group's total. min/max stay segment ops (sorted hint). On CPU,
-    everything takes the segment ops (see _use_searchsorted)."""
+    Integer sums are a prefix-sum difference at the segment ends
+    `first` (_first_rows) in place of the scatter-lowered segment_sum,
+    exact under wrapping arithmetic: below[g] is the running sum of
+    every row before group g, a group's sum is below[g + 1] - below[g],
+    and a slot with no group reads first[g] == first[g + 1] == n, so 0.
+    Floats keep segment_sum: a cumsum-difference would leak one
+    group's NaN into every later group's total, and re-associate the
+    additions. min/max stay segment ops (sorted hint)."""
     if reduce == "sum" and sarr.ndim == 1 \
-            and jnp.issubdtype(sarr.dtype, jnp.integer) \
-            and _use_searchsorted():
+            and jnp.issubdtype(sarr.dtype, jnp.integer):
         cs = common.prefix_sum(sarr, sarr.dtype)
-        slots = jnp.arange(out_cap, dtype=gid.dtype)
-        starts = jnp.searchsorted(gid, slots, side="left")
-        ends = jnp.searchsorted(gid, slots, side="right")
-        hi = cs[jnp.maximum(ends - 1, 0)]
-        lo = jnp.where(starts > 0, cs[jnp.maximum(starts - 1, 0)], 0)
-        return jnp.where(ends > starts, hi - lo,
-                         jnp.zeros((), sarr.dtype))
+        below = jnp.where(first > 0, cs[jnp.maximum(first - 1, 0)],
+                          jnp.zeros((), sarr.dtype))
+        return below[1:] - below[:-1]
     if reduce == "sum":
         red = jax.ops.segment_sum(sarr, gid, num_segments=out_cap + 1,
                                   indices_are_sorted=True)
@@ -624,20 +615,23 @@ def _group_reduce(keys: Sequence[CVal], valid: jnp.ndarray,
     svalid = valid[perm]
     bnd = common.boundaries(skeys, svalid,
                             hashes=(h1[perm], h2[perm]))
-    gid = common.prefix_sum(bnd) - 1
+    gid_m = common.prefix_sum(bnd) - 1
     num_groups = jnp.sum(bnd)
+    # segment ends once for every state and the keys; the invalid rows
+    # sort last, past the last group's end, and carry the identity
+    first = _first_rows(bnd, gid_m, out_cap)
     # invalid rows -> overflow segment out_cap (sliced away)
-    gid = jnp.where(svalid, jnp.minimum(gid, out_cap), out_cap)
+    gid = jnp.where(svalid, jnp.minimum(gid_m, out_cap), out_cap)
 
     new_states: List[Tuple[jnp.ndarray, ...]] = []
     for st, agg in zip(contribs, aggs):
         new_states.append(tuple(
-            _sorted_reduce(arr[perm], gid, out_cap, r)
+            _sorted_reduce(arr[perm], gid, first, out_cap, r)
             for arr, r in zip(st, agg.reduces)))
 
-    # representative key row per packed group (platform-specialized)
+    # representative key row per packed group
     slots = jnp.arange(out_cap)
-    first_row = _first_rows(bnd, gid, out_cap)
+    first_row = jnp.clip(first[:out_cap], 0, gid.shape[0] - 1)
     new_valid = slots < num_groups
     new_keys = [(d[first_row], m[first_row] & new_valid)
                 for d, m in skeys]
@@ -780,15 +774,17 @@ def presorted_reduce(row_valid: jnp.ndarray,
     gid_m = common.prefix_sum(bnd) - 1
     num_groups = jnp.sum(bnd)
     gid = jnp.clip(gid_m, 0, out_cap)
+    # segment ends once for every state and the keys: group 0 starts
+    # at its first boundary, the leading dead rows before it carry the
+    # identity
+    first = _first_rows(bnd, gid_m, out_cap)
     new_states: List[Tuple[jnp.ndarray, ...]] = []
     for st, agg in zip(contribs, aggs):
         new_states.append(tuple(
-            _sorted_reduce(arr, gid, out_cap, r)
+            _sorted_reduce(arr, gid, first, out_cap, r)
             for arr, r in zip(st, agg.reduces)))
-    # first row of group g (platform-specialized; on TPU the leading
-    # -1s make searchsorted(…, 0) land exactly on the first boundary)
     slots = jnp.arange(out_cap)
-    first_row = _first_rows(bnd, gid_m, out_cap)
+    first_row = jnp.clip(first[:out_cap], 0, n - 1)
     new_valid = slots < num_groups
     new_keys = [(d[first_row], m[first_row] & new_valid)
                 for d, m in key_cols]

@@ -1,6 +1,7 @@
 """Kernel tests against pandas/numpy oracles (reference analog:
 presto-main operator tests asserting output pages, OperatorAssertion.java:53)."""
 
+import jax.numpy as jnp
 import numpy as np
 import pandas as pd
 import pytest
@@ -85,6 +86,141 @@ def test_global_aggregation():
     out = hashagg.finalize(st, [], [], [], ["s", "c"], aggs)
     assert out.to_pylist()[:1] == [(12, 3)]
     assert out.num_valid() == 1
+
+
+def _boundary_mask(valid, keys):
+    """Boundary mask and monotone ids of rows already in key order, as
+    presorted_reduce derives them: a live row starts a group when its
+    key differs from the previous LIVE row's."""
+    bnd = np.zeros(len(valid), bool)
+    prev = None
+    for i, (v, k) in enumerate(zip(valid, keys)):
+        if v:
+            bnd[i] = prev is None or k != prev
+            prev = k
+    return bnd, np.cumsum(bnd).astype(np.int32) - 1
+
+
+#: name -> (row_valid, keys in order, out_cap)
+_SEGMENT_CASES = {
+    "leading_dead_rows": ([0, 0, 0, 1, 1, 1, 1, 1],
+                          [9, 9, 9, 1, 1, 2, 3, 3], 8),
+    "dead_rows_inside_groups": ([1, 0, 1, 1, 0, 0, 1, 0],
+                                [1, 5, 1, 2, 7, 2, 2, 2], 8),
+    "no_live_row": ([0] * 8, [1, 2, 3, 4, 5, 6, 7, 8], 8),
+    "one_group_spans_the_batch": ([1] * 8, [4] * 8, 8),
+    "every_row_its_own_group": ([1] * 8, list(range(8)), 8),
+    "out_cap_under_the_group_count": ([0, 1, 1, 1, 0, 1, 1, 1],
+                                      [0, 1, 2, 2, 2, 3, 4, 5], 3),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_SEGMENT_CASES))
+def test_segment_ends_from_the_boundary_mask(case):
+    """_first_rows against a NumPy searchsorted over the monotone ids
+    (what the kernel did per slot before PR 38), and the integer sum
+    _sorted_reduce derives from it against a per-group loop."""
+    valid, keys, out_cap = _SEGMENT_CASES[case]
+    valid = np.asarray(valid, bool)
+    n = len(valid)
+    bnd, gid_m = _boundary_mask(valid, keys)
+    first = np.asarray(hashagg._first_rows(
+        jnp.asarray(bnd), jnp.asarray(gid_m), out_cap))
+    slots = np.arange(out_cap + 1)
+    assert first.tolist() == np.searchsorted(
+        gid_m, slots, side="left").tolist()
+    groups = int(bnd.sum())
+    starts, ends = first[:-1], first[1:]
+    assert (ends >= starts).all()
+    for g in range(out_cap):
+        if g < groups:
+            assert bnd[starts[g]] and gid_m[starts[g]] == g
+        else:
+            assert starts[g] == ends[g] == n
+    # an integer contribution, identity on every dead row, near the
+    # wrap-around of int64: the prefix-sum difference is exact
+    big = np.iinfo(np.int64).max // 3
+    contrib = np.where(valid, big + np.arange(n), 0).astype(np.int64)
+    gid = np.clip(gid_m, 0, out_cap)
+    got = np.asarray(hashagg._sorted_reduce(
+        jnp.asarray(contrib), jnp.asarray(gid), jnp.asarray(first),
+        out_cap, "sum"))
+    with np.errstate(over="ignore"):
+        want = [contrib[gid == g].sum(dtype=np.int64) if g < groups
+                else 0 for g in range(out_cap)]
+    assert got.tolist() == [int(w) for w in want]
+
+
+def _run_core(core, valid, keys, inputs, weights, aggs, out_cap):
+    """One batch through the presorted core (rows in key order) or the
+    hash-sorted one; -> {key: tuple of finalized values}, overflow."""
+    valid = jnp.asarray(np.asarray(valid, bool))
+    kcol = (jnp.asarray(np.asarray(keys, np.int64)),
+            jnp.ones(len(keys), bool))
+    fn = hashagg.presorted_aggregate if core == "presorted" \
+        else hashagg.batch_aggregate
+    st = fn(valid, [kcol],
+            [None if x is None else jnp.asarray(x) for x in inputs],
+            [valid & jnp.asarray(np.asarray(w, bool)) for w in weights],
+            aggs, out_cap)
+    out = hashagg.finalize(st, ["k"], [BIGINT], [None],
+                           [f"a{i}" for i in range(len(aggs))], aggs)
+    live = np.asarray(out.row_valid)
+    cols = [np.asarray(out.columns[n].data)[live]
+            for n in out.columns]
+    return ({int(k): tuple(c[i] for c in cols[1:])
+             for i, k in enumerate(cols[0])},
+            bool(np.asarray(st.overflow)))
+
+
+@pytest.mark.parametrize("core", ["presorted", "hash_sorted"])
+def test_int64_sums_near_wrap_around(core):
+    """BIGINT sums (and the DOUBLE sum's int64 count) come from one
+    prefix sum over the whole batch: it wraps, the differences do not
+    notice."""
+    top = np.iinfo(np.int64).max
+    valid = [0, 1, 1, 1, 0, 1, 1, 1, 1, 0]
+    keys = [7, 1, 1, 2, 2, 2, 3, 3, 3, 3]
+    v = np.asarray([5, top - 1, -3, top, 9, top, -top, 4, top, 8],
+                   np.int64)
+    aggs = [hashagg.make_sum(BIGINT, BIGINT), hashagg.make_count(None)]
+    got, overflow = _run_core(core, valid, keys, [v, None],
+                              [[1] * 10, [1] * 10], aggs, 16)
+    with np.errstate(over="ignore"):
+        want = {1: (v[1] + v[2], 2), 2: (v[3] + v[5], 2),
+                3: (v[6] + v[7] + v[8], 3)}
+    assert got == {k: (int(s), c) for k, (s, c) in want.items()}
+    assert not overflow
+
+
+@pytest.mark.parametrize("core", ["presorted", "hash_sorted"])
+def test_nan_stays_in_its_group(core):
+    """A DOUBLE sum keeps segment_sum: a NaN (or an infinity) in one
+    group reaches no other group's total."""
+    valid = [1] * 8
+    keys = [1, 1, 2, 2, 2, 3, 3, 4]
+    x = np.asarray([1.5, 2.5, 1.0, np.nan, 2.0, np.inf, 1.0, 0.25])
+    aggs = [hashagg.make_sum(DOUBLE, DOUBLE), hashagg.make_count(None)]
+    got, _ = _run_core(core, valid, keys, [x, None],
+                       [[1] * 8, [1] * 8], aggs, 8)
+    assert got[1] == (4.0, 2) and got[4] == (0.25, 1)
+    assert np.isnan(got[2][0]) and got[2][1] == 3
+    assert got[3] == (np.inf, 2)
+
+
+@pytest.mark.parametrize("core", ["presorted", "hash_sorted"])
+def test_groups_past_out_cap_set_the_flag_and_spare_the_kept(core):
+    valid = [0, 1, 1, 1, 0, 1, 1, 1, 1, 1]
+    keys = [0, 1, 1, 2, 2, 3, 3, 4, 5, 5]
+    v = np.arange(10, dtype=np.int64) * 10
+    aggs = [hashagg.make_sum(BIGINT, BIGINT)]
+    got, overflow = _run_core(core, valid, keys, [v], [[1] * 10],
+                              aggs, 3)
+    want = {1: 30, 2: 30, 3: 110, 4: 70, 5: 170}
+    assert overflow and len(got) == 3
+    assert all(got[k] == (want[k],) for k in got)
+    if core == "presorted":            # packed in key order
+        assert sorted(got) == [1, 2, 3]
 
 
 def test_inner_join_vs_pandas():

@@ -1,13 +1,12 @@
 """Compile guards: the main path's kernels must COMPILE for a TPU v5e.
 
 Tier-1 runs on the CPU, so every trace-time platform fork
-(ops/common.cpu_backend, ops/hashagg._use_searchsorted) only ever
-shows the suite its CPU side, and XLA:TPU refuses programs XLA:CPU
-accepts (every bitcast from f64, for one). These cases steer the forks
-to their TPU side with monkeypatch and AOT-compile each kernel for a
-DESCRIBED v5e:2x2 — no chip attached, nothing runs, no result or time
-is checked. A compile that passes here is not a chip run
-(`python chip_smoke.py` is).
+(ops/common.cpu_backend) only ever shows the suite its CPU side, and
+XLA:TPU refuses programs XLA:CPU accepts (every bitcast from f64, for
+one). These cases steer the forks to their TPU side with monkeypatch
+and AOT-compile each kernel for a DESCRIBED v5e:2x2 — no chip
+attached, nothing runs, no result or time is checked. A compile that
+passes here is not a chip run (`python chip_smoke.py` is).
 
 The topology is described inside the module-scoped fixture below and
 nowhere else: only one process may load the TPU's library, so nothing
@@ -58,9 +57,8 @@ def one_chip(topo):
 @pytest.fixture
 def tpu_forks(monkeypatch):
     """Steer every trace-time platform fork to its TPU side."""
-    from presto_tpu.ops import common, hashagg
+    from presto_tpu.ops import common
     monkeypatch.setattr(common, "cpu_backend", lambda: False)
-    monkeypatch.setattr(hashagg, "_use_searchsorted", lambda: True)
 
 
 def _place(tree, sharding):
@@ -151,8 +149,8 @@ def test_q1_fused_step(one_chip, tpu_forks):
 
 def test_sorted_aggregation_step(one_chip, tpu_forks):
     """The high-cardinality (sort-based) aggregation Q3 runs: hash
-    sort, boundaries, prefix-sum group ids, and the TPU side of
-    _sorted_reduce / _first_rows."""
+    sort, boundaries, prefix-sum group ids, and the segment ends of
+    _first_rows: one scatter, no search loop at any shape."""
     from presto_tpu.ops import hashagg
     from presto_tpu.types import BIGINT, DOUBLE
     n, cap = 1 << 14, 1 << 12  # the shapes Q3 traces at sf1
@@ -163,8 +161,10 @@ def test_sorted_aggregation_step(one_chip, tpu_forks):
         return hashagg.batch_aggregate(
             valid, [(k1, true), (k2, true)], [x, None],
             [valid, valid], aggs, cap)
-    _compile(fn, (_sds(n, jnp.bool_), _sds(n, jnp.int64),
-                  _sds(n, jnp.int32), _sds(n, jnp.float64)), one_chip)
+    compiled = _compile(fn, (_sds(n, jnp.bool_), _sds(n, jnp.int64),
+                             _sds(n, jnp.int32), _sds(n, jnp.float64)),
+                        one_chip)
+    assert " while(" not in compiled.as_text()
 
 
 @pytest.mark.parametrize("family,cap", [("join_build", 1 << 16),
@@ -419,8 +419,10 @@ def test_q18_programs_at_the_cells_size(one_chip, tpu_forks,
     the benchmark ran before PR 37 (rehearsal, PR 37: compile seconds
     on this sandbox in CHANGES.md): the streaming aggregation's
     presorted grouping of a lineitem batch at 4,194,304 and at
-    1,048,576 lanes (a cummax, a prefix sum and the searchsorted
-    reduces: no sort) and its boundary fold with a carried group
+    1,048,576 lanes (a cummax, a prefix sum, one scatter of the
+    boundary rows for the segment ends: no sort, and since PR 38 no
+    `while`, the per-slot binary searches being gone) and its
+    boundary fold with a carried group
     (`agg_stream`); the sorted-hash build of the semi join's build side
     on the smallest rung and the `semi_join` probe of a 4M-lane batch
     against it; the aligned probe's back at K = capacity (every
@@ -436,6 +438,10 @@ def test_q18_programs_at_the_cells_size(one_chip, tpu_forks,
     sorts = 0
     if program.startswith("presorted_step"):
         n = WIDE if program.endswith("4m") else BATCH
+        # no sort of the rows; XLA:TPU lowers the scatter of 4,194,304
+        # segment-end indices through a sort of its own (of the
+        # indices: 12 s of compile), at 1,048,576 lanes it does not
+        sorts = 1 if n == WIDE else 0
 
         def fn(valid, k, km, x, xm):
             return hashagg.presorted_aggregate(
@@ -504,6 +510,10 @@ def test_q18_programs_at_the_cells_size(one_chip, tpu_forks,
     text = compiled.as_text()
     if sorts is not None:
         assert text.count(" sort(") == sorts
+    if program.startswith("presorted_step") \
+            or program == "five_key_agg_step":
+        # segment ends come from the boundary mask: no search loop
+        assert " while(" not in text
     if program.endswith("back_full_width"):
         # K = capacity: the probe's own columns stay where they are
         # and only the build's are gathered, at the batch's width
