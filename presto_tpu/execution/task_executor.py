@@ -475,7 +475,9 @@ class TaskExecutor:
                 # opens a nested `driver.step` frame — what remains
                 # here is exactly the executor's quantum bookkeeping
                 from presto_tpu.telemetry import ledger as _ledger
-                with _ledger.span("driver.quantum"):
+                with _ledger.span(
+                        "driver.quantum", detail="executor",
+                        query_id=getattr(task.ledger, "query_id", "")):
                     from presto_tpu.execution import faults
                     if faults.ARMED:
                         # fault site `executor.quantum`: every

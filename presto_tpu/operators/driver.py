@@ -18,9 +18,15 @@ identical by construction — the same operator methods run in the same
 per-operator order, the `operator.add_input` fault site still fires on
 every hand-off, and quantum deadlines still checkpoint every split —
 so pump-on and pump-off runs are byte-identical (tests/
-test_batch_pump.py holds that oracle). Profiled or traced runs, and
-any pipeline containing an operator the pump cannot model (exchanges,
-merges, writers), keep the generic loop."""
+test_batch_pump.py holds that oracle). Profiled runs (they block on
+every batch by design), and any pipeline containing an operator the
+pump cannot model (exchanges, merges, writers), keep the generic loop.
+
+Every call of an operator's method, in either loop, goes through one
+hand-off (`_Handoff`): the only place an operator is bound for kernel
+attribution, timed, and named on the ledger and the profiler's
+timeline. A `query_trace_enabled` statement therefore runs the loop
+every other statement runs."""
 
 from __future__ import annotations
 
@@ -47,6 +53,61 @@ def set_pump(on: bool) -> None:
 
 def pump_enabled() -> bool:
     return _PUMP_ON
+
+
+class _Handoff:
+    """One call of one operator's method (`get_output`, `add_input`,
+    `finish`), the only place the driver binds an operator: kernel
+    calls inside credit the operator's stats (telemetry/kernels.py),
+    the call is the ledger frame `<category>/<operator kind>.<method>`
+    (a host event on the profiler's timeline), the frame's elapsed
+    time is the operator's busy time, and a per-query recorder, when
+    one is current, gets the `op:<kind>.<method>` event. The binding
+    cannot outlive the call: width-retry control flow
+    (GroupLimitExceeded etc.) raises straight out of add_input, and a
+    stale binding would credit kernel time to a dead operator."""
+
+    __slots__ = ("ctx", "method", "category", "recorded", "_frame",
+                 "_start_ns")
+
+    def __init__(self, op: Operator, method: str,
+                 category: str = "driver.step"):
+        self.ctx = op.ctx
+        self.method = method
+        self.category = category
+        #: cleared by a site whose call moved nothing (a get_output
+        #: that returned None): the recorder holds hand-offs, not polls
+        self.recorded = True
+
+    def __enter__(self):
+        ctx = self.ctx
+        if _tk.ENABLED:
+            _tk.set_current_op(ctx.stats)
+        frame = self._frame = _ledger.span(
+            self.category, detail=f"{ctx.name}.{self.method}"
+        ).__enter__()
+        # a thread without a ledger opens no frame: its own clock
+        self._start_ns = frame.start_ns if frame is not None \
+            else time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc):
+        frame = self._frame
+        if frame is not None:
+            frame.__exit__(*exc)
+            dur = frame.elapsed_ns
+        else:
+            dur = time.perf_counter_ns() - self._start_ns
+        ctx = self.ctx
+        ctx.stats.busy_seconds += dur / 1e9
+        if _tk.ENABLED:
+            _tk.set_current_op(None)
+        if self.recorded and _trace.ACTIVE:
+            rec = _trace.current()
+            if rec is not None:
+                rec.add(f"op:{ctx.name}.{self.method}", "operator",
+                        self._start_ns, dur)
+        return False
 
 
 def _pump_op_sets():
@@ -130,6 +191,13 @@ class Driver:
         self._prefetched = None
         self._pump_drained = False
         self._pump_splits = 0
+        #: passes over the chain, by whether one moved a batch (a pump
+        #: split is a pass that moved; a pass that found an operator
+        #: blocked, or nothing to do, is one that did not): added to
+        #: presto_tpu_driver_passes_total in close(), whichever loop
+        #: (executor quantum, pump, the mesh's process()) made them
+        self._passes_moved = 0
+        self._passes_idle = 0
 
     def is_finished(self) -> bool:
         return self._closed or self.operators[-1].is_finished()
@@ -187,20 +255,15 @@ class Driver:
 
     def _pump_ok(self) -> bool:
         """Pump this quantum? Cheap after the first call: eligibility
-        is a cached shape property; the per-quantum part is only the
-        global switch, the drained flag, and the trace gate."""
+        is a cached shape property (profiled runs want device-
+        inclusive per-operator timing and keep the pair loop: static
+        per driver context, checked once); the per-quantum part is
+        only the global switch and the drained flag."""
         if not _PUMP_ON or self._pump_drained or self._pump is False:
             return False
         if self._pump is None:
             self._pump = self._pump_eligible()
-            if not self._pump:
-                return False
-        # traced runs want per-hand-off spans; profiled runs want
-        # device-inclusive per-operator timing — both keep the pair
-        # loop (profile is static per driver context, checked once)
-        if _trace.ACTIVE and _trace.current() is not None:
-            return False
-        return True
+        return self._pump
 
     def _pump_eligible(self) -> bool:
         from presto_tpu.telemetry.metrics import METRICS
@@ -228,6 +291,7 @@ class Driver:
                 return self.FINISHED, progressed
             for op in ops:
                 if op.is_blocked():
+                    self._passes_idle += 1
                     return self.BLOCKED, progressed
                 if op is not src and op.is_finished():
                     # early termination (LIMIT hit mid-chain): the
@@ -239,6 +303,7 @@ class Driver:
                 buf = self._pump_pull()      # prime the double buffer
                 if buf is None:
                     if not src.is_finished():
+                        self._passes_idle += 1
                         return self.IDLE, progressed
                     self._pump_drained = True
                     return None, progressed
@@ -255,6 +320,7 @@ class Driver:
             self._pump_feed(buf)
             progressed = True
             self._pump_splits += 1
+            self._passes_moved += 1
             # ... which is exactly when split N+1's scan + h2d runs
             # (the double buffer: device computes N, host readies N+1)
             if not src.is_finished():
@@ -270,17 +336,9 @@ class Driver:
         nested scan/h2d spans charge themselves, so `prefetch` is the
         overlap machinery's own self time."""
         src = self.operators[0]
-        timing = _tk.ENABLED
-        if timing:
-            _tk.set_current_op(src.ctx.stats)
-        t0 = time.perf_counter()
-        try:
-            with _ledger.span("prefetch"):
-                batch = src.get_output()
-        finally:
-            src.ctx.stats.busy_seconds += time.perf_counter() - t0
-            if timing:
-                _tk.set_current_op(None)
+        with _Handoff(src, "get_output", "prefetch") as h:
+            batch = src.get_output()
+            h.recorded = batch is not None
         return batch
 
     def _pump_feed(self, batch) -> None:
@@ -289,35 +347,35 @@ class Driver:
         fault site fires, kernel time binds to the consuming
         operator's stats, and busy_seconds accumulate."""
         ops = self.operators
-        timing = _tk.ENABLED
         armed = faults.ARMED
         x = batch
-        for i in range(1, len(ops)):
-            op = ops[i]
+        for op in ops[1:]:
             if armed:
                 faults.fire("operator.add_input", op=op,
                             name=op.ctx.name)
-            if timing:
-                _tk.set_current_op(op.ctx.stats)
-            t0 = time.perf_counter()
-            op.add_input(x)
-            if i < len(ops) - 1:
+            with _Handoff(op, "add_input"):
+                op.add_input(x)
+            if op is ops[-1]:
+                break
+            with _Handoff(op, "get_output") as h:
                 x = op.get_output()
-            op.ctx.stats.busy_seconds += time.perf_counter() - t0
-            if timing:
-                _tk.set_current_op(None)
-            if i < len(ops) - 1 and x is None:
+                h.recorded = x is not None
+            if x is None:
                 # absorbed by a fold (or pipelined inside a deferred-
                 # compact window): nothing to move further downstream
                 return
-        # self-driving tail (sink flush), mirroring the pair loop
-        tail = ops[-1]
-        if not tail.is_finished() and not tail.is_blocked():
-            if timing:
-                _tk.set_current_op(tail.ctx.stats)
-            tail.get_output()
-            if timing:
-                _tk.set_current_op(None)
+        self._drain_tail()
+
+    def _drain_tail(self) -> bool:
+        """The self-driving tail (a sink's flush): one get_output of
+        the last operator, in both loops. True if it emitted."""
+        tail = self.operators[-1]
+        if tail.is_finished() or tail.is_blocked():
+            return False
+        with _Handoff(tail, "get_output") as h:
+            out = tail.get_output()
+            h.recorded = out is not None
+        return out is not None
 
     def process(self, max_iterations: int = 1) -> bool:
         """Run up to `max_iterations` passes over the operator chain
@@ -332,28 +390,20 @@ class Driver:
         return progress
 
     def _process_once(self) -> bool:
-        # the finally guards the thread-local operator binding: width-
-        # retry control flow (GroupLimitExceeded etc.) raises straight
-        # out of add_input, and the binding must not outlive the
-        # hand-off it belongs to (a stale binding would credit kernel
-        # time to a dead operator and pin its stats)
-        if not _tk.ENABLED:
-            return self._process_once_inner()
-        try:
-            return self._process_once_inner()
-        finally:
-            _tk.set_current_op(None)
+        """One pass of the pair walk, counted by whether it moved a
+        batch (every caller's passes: the executor's quantum, the
+        mesh's process(), run_to_completion)."""
+        moved = self._process_once_inner()
+        if moved:
+            self._passes_moved += 1
+        else:
+            self._passes_idle += 1
+        return moved
 
     def _process_once_inner(self) -> bool:
         ops = self.operators
         moved = False
         profile = ops[0].ctx.driver_context.profile
-        # telemetry attribution: bind the operator whose method runs to
-        # the thread so kernel calls inside it credit compile/execute
-        # ns to the right OperatorStats (telemetry/kernels.py); spans
-        # only exist when a trace recorder is current on this thread
-        timing = _tk.ENABLED
-        tracing = _trace.ACTIVE and _trace.current() is not None
         # walk adjacent pairs, moving at most one batch per pair
         # (Driver.processInternal:371)
         for i in range(len(ops) - 1):
@@ -370,31 +420,26 @@ class Driver:
             if profile:
                 self._note_blocked(current, nxt)  # closes open windows
             if nxt.needs_input() and not cur_finished:
-                if timing:
-                    _tk.set_current_op(current.ctx.stats)
-                t0 = time.perf_counter()
-                if i == 0 and self._prefetched is not None:
-                    # a batch the pump prefetched but could not feed
-                    # (backed-up stage at a quantum boundary): it MUST
-                    # leave the buffer before the source is pulled
-                    # again, or batches would reorder
-                    batch = self._prefetched
-                    self._prefetched = None
-                else:
-                    batch = current.get_output()
-                if profile and batch is not None:
-                    # device-inclusive timing: charge this operator for
-                    # the async work its output depends on (profiled
-                    # runs trade pipeline overlap for attribution, like
-                    # the reference's EXPLAIN ANALYZE overhead)
-                    import jax
-                    jax.block_until_ready(batch)
-                dt = time.perf_counter() - t0
-                current.ctx.stats.busy_seconds += dt
-                if tracing and batch is not None:
-                    _trace.current().add(
-                        f"op:{current.ctx.name}.get_output",
-                        "operator", int(t0 * 1e9), int(dt * 1e9))
+                with _Handoff(current, "get_output") as h:
+                    if i == 0 and self._prefetched is not None:
+                        # a batch the pump prefetched but could not
+                        # feed (backed-up stage at a quantum
+                        # boundary): it MUST leave the buffer before
+                        # the source is pulled again, or batches
+                        # would reorder
+                        batch = self._prefetched
+                        self._prefetched = None
+                    else:
+                        batch = current.get_output()
+                    if profile and batch is not None:
+                        # device-inclusive timing: charge this
+                        # operator for the async work its output
+                        # depends on (profiled runs trade pipeline
+                        # overlap for attribution, like the
+                        # reference's EXPLAIN ANALYZE overhead)
+                        import jax
+                        jax.block_until_ready(batch)
+                    h.recorded = batch is not None
                 if batch is not None:
                     if faults.ARMED:
                         # fault site `operator.add_input`: the ONE
@@ -403,33 +448,16 @@ class Driver:
                         # any pipeline here without monkeypatching
                         faults.fire("operator.add_input", op=nxt,
                                     name=nxt.ctx.name)
-                    if timing:
-                        _tk.set_current_op(nxt.ctx.stats)
-                    t0 = time.perf_counter()
-                    nxt.add_input(batch)
-                    dt = time.perf_counter() - t0
-                    nxt.ctx.stats.busy_seconds += dt
-                    if tracing:
-                        _trace.current().add(
-                            f"op:{nxt.ctx.name}.add_input",
-                            "operator", int(t0 * 1e9), int(dt * 1e9))
+                    with _Handoff(nxt, "add_input"):
+                        nxt.add_input(batch)
                     moved = True
-                if timing:
-                    _tk.set_current_op(None)
             # unwind finished prefix (Driver.java:438-447)
             if cur_finished:
-                nxt.finish()
+                with _Handoff(nxt, "finish") as h:
+                    h.recorded = False
+                    nxt.finish()
         # drain the tail operator if it is a sink that self-drives
-        tail = self.operators[-1]
-        if not tail.is_finished() and not tail.is_blocked():
-            if timing:
-                _tk.set_current_op(tail.ctx.stats)
-            out = tail.get_output()
-            if timing:
-                _tk.set_current_op(None)
-            if out is not None:
-                moved = True
-        return moved
+        return self._drain_tail() or moved
 
     @staticmethod
     def _note_blocked(current, nxt) -> None:
@@ -491,11 +519,18 @@ class Driver:
     def close(self) -> None:
         if not self._closed:
             self._prefetched = None  # drop any in-flight lookahead
+            from presto_tpu.telemetry.metrics import METRICS
             if self._pump_splits:
-                from presto_tpu.telemetry.metrics import METRICS
                 METRICS.inc("presto_tpu_pump_splits_total",
                             self._pump_splits)
                 self._pump_splits = 0
+            if self._passes_moved or self._passes_idle:
+                # both series, so that a share always has its base
+                METRICS.inc("presto_tpu_driver_passes_total",
+                            self._passes_moved, moved="yes")
+                METRICS.inc("presto_tpu_driver_passes_total",
+                            self._passes_idle, moved="no")
+                self._passes_moved = self._passes_idle = 0
             now = time.perf_counter()
             for op in self.operators:
                 # close any open blocked window: an operator still

@@ -472,12 +472,17 @@ class MeshExchange:
         return (self.n_producers == w and self.n_consumers == w
                 and w > 1)
 
-    def _place(self, batch: Batch, consumer: int) -> Batch:
+    def _place(self, batch, consumer: int):
+        """A batch (or one array) onto its consumer's chip, charged
+        like every placement (parallel/mesh.place: the ledger's `d2d`
+        and the bytes, nothing when it is already there) under a
+        direction of the exchange's own, `exchange_d2d`."""
         dev = self.devices[consumer] if consumer < len(self.devices) \
             else self.devices[0]
         if dev is None:
             return batch
-        return jax.device_put(batch, dev)
+        from presto_tpu.parallel.mesh import place
+        return place(batch, dev, counted_as="exchange_")
 
     def _hash_split(self, batch: Batch) -> None:
         """Non-collective repartition (producer/consumer counts differ
@@ -494,11 +499,7 @@ class MeshExchange:
                 self._enqueue(c, part)
             else:
                 self._deliver_buckets(c, part.columns, part.row_valid,
-                                      jax.device_put(
-                                          g_of_row,
-                                          self.devices[c])
-                                      if self.devices[c] is not None
-                                      else g_of_row)
+                                      self._place(g_of_row, c))
 
     def _pad_batch(self, cap: int, producer: int) -> Batch:
         t = self._template
@@ -508,7 +509,7 @@ class MeshExchange:
             for n, c in t.columns.items()
         }
         b = Batch(cols, jnp.zeros((cap,), bool))
-        return jax.device_put(b, self.devices[producer])
+        return self._place(b, producer)
 
     def _try_wave(self) -> None:
         from presto_tpu.batch import quantized_capacity

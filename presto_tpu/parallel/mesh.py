@@ -34,24 +34,26 @@ def make_mesh(n_devices: Optional[int] = None,
     return Mesh(np.array(devices), (axis,))
 
 
-def place(batch, device):
-    """Commit a Batch to `device`, charged as what the copy is: nothing
-    when it already lives there (device_put then only commits, no byte
-    moves), the ledger's `d2d` from another chip, `h2d` from the host;
-    the bytes that moved are counted under the same direction of
-    presto_tpu_transfer_bytes_total. A batch is judged by its
-    row_valid: its arrays are made and moved together."""
-    probe = batch.row_valid
+def place(batch, device, counted_as: str = ""):
+    """Commit a Batch (or one array) to `device`, charged as what the
+    copy is: nothing when it already lives there (device_put then only
+    commits, no byte moves), the ledger's `d2d` from another chip,
+    `h2d` from the host; the bytes that moved are counted under the
+    same direction of presto_tpu_transfer_bytes_total, prefixed by
+    `counted_as` (the exchange counts `exchange_d2d`, apart from the
+    scans' `d2d`). A batch is judged by its row_valid: its arrays are
+    made and moved together."""
+    probe = getattr(batch, "row_valid", batch)
     home = probe.devices() if isinstance(probe, jax.Array) else None
     if home == {device}:
         return batch if probe.committed \
             else jax.device_put(batch, device)
-    from presto_tpu.execution.memory import batch_bytes
     from presto_tpu.telemetry import ledger
     from presto_tpu.telemetry.metrics import METRICS
     direction = "h2d" if home is None else "d2d"
     with ledger.span(direction):
         out = jax.device_put(batch, device)
-    METRICS.inc("presto_tpu_transfer_bytes_total", batch_bytes(out),
-                direction=direction)
+    METRICS.inc("presto_tpu_transfer_bytes_total",
+                sum(a.nbytes for a in jax.tree_util.tree_leaves(out)),
+                direction=counted_as + direction)
     return out

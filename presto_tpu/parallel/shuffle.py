@@ -59,8 +59,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from presto_tpu.batch import Batch, Column, bucket_capacity
 from presto_tpu.ops import common
-from presto_tpu.parallel.mesh import worker_axis
+from presto_tpu.parallel.mesh import place, worker_axis
 from presto_tpu.telemetry import kernels as _kernels
+from presto_tpu.telemetry import ledger
 
 
 class ShardedBatch:
@@ -393,15 +394,13 @@ def batch_row_bytes(batch: Batch) -> int:
 
 def _as_global(arrays, mesh: Mesh, axis: str, cap: int):
     """Assemble per-device shards into one sharded global array
-    (zero-copy when each shard already lives on its mesh device)."""
+    (zero-copy when each shard already lives on its mesh device; one
+    that has to move is charged as the exchange's chip-to-chip copy:
+    parallel/mesh.place)."""
     w = len(arrays)
     sh = NamedSharding(mesh, P(axis))
-    devs = list(mesh.devices.reshape(-1))
-    placed = []
-    for a, d in zip(arrays, devs):
-        if a.devices() != {d}:
-            a = jax.device_put(a, d)
-        placed.append(a)
+    placed = [place(a, d, counted_as="exchange_")
+              for a, d in zip(arrays, mesh.devices.reshape(-1))]
     return jax.make_array_from_single_device_arrays(
         (w * cap,) + placed[0].shape[1:], sh, placed)
 
@@ -440,14 +439,19 @@ def wave_repartition(mesh: Mesh, batches, key_names,
     names = batches[0].names
     tmpl = batches[0]
 
-    g_datas = tuple(
-        _as_global([b.columns[n].data for b in batches], mesh, axis,
-                   cap) for n in names)
-    g_masks = tuple(
-        _as_global([b.columns[n].mask for b in batches], mesh, axis,
-                   cap) for n in names)
-    g_valid = _as_global([b.row_valid for b in batches], mesh, axis,
-                         cap)
+    # the wave's host side in four frames of the caller's category
+    # (MeshExchange._run_wave's exchange.all_to_all): assembling the
+    # global arrays, the program's call, the sync on the counts, and
+    # slicing each consumer's batch out of the outputs
+    with ledger.span("exchange.all_to_all", detail="assemble"):
+        g_datas = tuple(
+            _as_global([b.columns[n].data for b in batches], mesh,
+                       axis, cap) for n in names)
+        g_masks = tuple(
+            _as_global([b.columns[n].mask for b in batches], mesh,
+                       axis, cap) for n in names)
+        g_valid = _as_global([b.row_valid for b in batches], mesh,
+                             axis, cap)
 
     if chain is not None:
         remap_flags = tuple(
@@ -457,40 +461,45 @@ def wave_repartition(mesh: Mesh, batches, key_names,
             mesh, axis, w, chain, tmpl, tuple(key_names), remap_flags)
         tables = tuple(key_remaps[i]
                        for i, f in enumerate(remap_flags) if f)
-        out_datas, out_masks, out_valid, counts = fn(
-            g_valid, g_datas, g_masks, tables)
+        with ledger.span("exchange.all_to_all", detail="dispatch"):
+            out_datas, out_masks, out_valid, counts = fn(
+                g_valid, g_datas, g_masks, tables)
     else:
         key_datas, key_masks = [], []
-        for i, k in enumerate(key_names):
-            datas, masks = [], []
-            for b in batches:
-                c = b.columns[k]
-                d = c.data
-                if key_remaps is not None \
-                        and key_remaps[i] is not None:
-                    d = key_remaps[i][d]
-                datas.append(d)
-                masks.append(c.mask)
-            key_datas.append(_as_global(datas, mesh, axis, cap))
-            key_masks.append(_as_global(masks, mesh, axis, cap))
+        with ledger.span("exchange.all_to_all", detail="assemble"):
+            for i, k in enumerate(key_names):
+                datas, masks = [], []
+                for b in batches:
+                    c = b.columns[k]
+                    d = c.data
+                    if key_remaps is not None \
+                            and key_remaps[i] is not None:
+                        d = key_remaps[i][d]
+                    datas.append(d)
+                    masks.append(c.mask)
+                key_datas.append(_as_global(datas, mesh, axis, cap))
+                key_masks.append(_as_global(masks, mesh, axis, cap))
         fn = _wave_program(mesh, axis, w, len(key_names), len(names))
-        out_datas, out_masks, out_valid, counts = fn(
-            g_valid, tuple(key_datas), tuple(key_masks), g_datas,
-            g_masks)
+        with ledger.span("exchange.all_to_all", detail="dispatch"):
+            out_datas, out_masks, out_valid, counts = fn(
+                g_valid, tuple(key_datas), tuple(key_masks), g_datas,
+                g_masks)
         out_meta = tuple((n, tmpl.columns[n].type,
                           tmpl.columns[n].dictionary) for n in names)
 
-    counts = np.asarray(counts)  # ONE host sync per wave
+    with ledger.span("exchange.all_to_all", detail="sync"):
+        counts = np.asarray(counts)  # ONE host sync per wave
     out = []
-    for c in range(w):
-        shard_len = _shard(out_valid, c).shape[0]
-        cap2 = min(quantized_capacity(int(counts[c])), shard_len)
-        cols = {}
-        for (n, typ, dic), gd, gm in zip(out_meta, out_datas,
-                                         out_masks):
-            cols[n] = Column(_shard(gd, c)[:cap2],
-                             _shard(gm, c)[:cap2], typ, dic)
-        out.append(Batch(cols, _shard(out_valid, c)[:cap2]))
+    with ledger.span("exchange.all_to_all", detail="slice"):
+        for c in range(w):
+            shard_len = _shard(out_valid, c).shape[0]
+            cap2 = min(quantized_capacity(int(counts[c])), shard_len)
+            cols = {}
+            for (n, typ, dic), gd, gm in zip(out_meta, out_datas,
+                                             out_masks):
+                cols[n] = Column(_shard(gd, c)[:cap2],
+                                 _shard(gm, c)[:cap2], typ, dic)
+            out.append(Batch(cols, _shard(out_valid, c)[:cap2]))
     if return_counts:
         return out, counts
     return out

@@ -522,8 +522,8 @@ class LocalRunner:
             self._session_tl.override = prev
 
     def execute_as(self, sql: str, user: str, cancel=None,
-                   deadline: Optional[float] = None
-                   ) -> MaterializedResult:
+                   deadline: Optional[float] = None,
+                   query_id: str = "") -> MaterializedResult:
         """Execute with a per-request identity (the single-node
         coordinator's path: many users share one runner). The user
         rides the THREAD-LOCAL session override, so analysis-time
@@ -534,15 +534,19 @@ class LocalRunner:
         behavior mid-flight for every other user of the shared
         runner — and is marked request_scoped so SET/RESET SESSION
         reject loudly instead of silently evaporating with the
-        copy."""
+        copy. `query_id`, the server's identifier of the request,
+        rides the same thread-local to the statement's ledger, whose
+        frames carry it on the profiler's timeline."""
         self._session_tl.override = dataclasses.replace(
             self._session, user=user,
             properties=dict(self._session.properties),
             request_scoped=True)
+        self._session_tl.query_id = query_id
         try:
             return self.execute(sql, cancel=cancel, deadline=deadline)
         finally:
             self._session_tl.override = None
+            self._session_tl.query_id = ""
 
     def _reject_request_scoped_mutation(self) -> None:
         """SET/RESET SESSION on a request-scoped session would mutate
@@ -724,7 +728,8 @@ class LocalRunner:
         # re-install it like the kernel counters). Admission-queue
         # wait happened BEFORE this frame — charge it up front so the
         # finished wall (queue + execution) is fully decomposed.
-        led = _ledger.QueryLedger()
+        led = _ledger.QueryLedger(
+            getattr(self._session_tl, "query_id", ""))
         prev_led = _ledger.install(led)
         queued_ns = int((getattr(self._session_tl, "queued_ms", 0.0)
                          or 0.0) * 1e6)
@@ -760,7 +765,8 @@ class LocalRunner:
             # nested planning/scan/kernel/... spans subtract, and the
             # executor wait is absorbed (run_drivers) so worker-thread
             # quanta never double-book it
-            with _ledger.span("driver.quantum"):
+            with _ledger.span("driver.quantum", detail="statement",
+                              query_id=led.query_id):
                 result = self._execute_lifecycled(sql)
         except BaseException as e:
             # a FAILED traced query keeps its timeline: events (root
@@ -810,16 +816,9 @@ class LocalRunner:
             # history entry behind system.runtime.queries, and the
             # process counters + unattributed-ratio histogram
             _ledger.uninstall(prev_led)
-            from presto_tpu.telemetry.metrics import METRICS
             led_doc = led.finish(
                 queued_ns + (_time.perf_counter_ns() - t0_ns))
-            for c, ms in led_doc["categories_ms"].items():
-                METRICS.inc("presto_tpu_ledger_ns_total",
-                            ms * 1e6, category=c)
-            METRICS.inc("presto_tpu_ledger_unattributed_ns_total",
-                        max(0.0, led_doc["unattributed_ms"]) * 1e6)
-            METRICS.observe("presto_tpu_ledger_unattributed_ratio",
-                            max(0.0, led_doc["unattributed_frac"]))
+            _ledger.publish(led_doc)
             entry = getattr(self._session_tl, "history_entry", None)
             if entry is not None:
                 entry["unattributed_ms"] = led_doc["unattributed_ms"]

@@ -547,71 +547,75 @@ class MeshRunner(LocalRunner):
         from presto_tpu.telemetry import ledger as _ledger
         rounds = 0
         while True:
-            # the same lifecycle checkpoints as the local drive loop:
-            # kill and deadline both terminate within one round, even
-            # mid-lifespan (retained bucket pages are dropped by the
-            # caller's finally-close of every exchange)
-            check_lifecycle(cancel, deadline)
-            all_done = not deferred
-            progress = False
-            for d in list(all_drivers):
-                if d.is_finished():
-                    continue
-                all_done = False
-                # per-DRIVER checkpoint, the same cadence the
-                # TaskExecutor gives every quantum: a mesh round walks
-                # (fragments x tasks) drivers and each process() may
-                # hide a multi-second XLA compile — a kill/deadline
-                # must land within one driver hand-off, not one round
+            # the round's own work (lifecycle checks, deferred spawns,
+            # lifespan advances: everything around the per-driver
+            # frames) is named apart from the statement's root frame
+            with _ledger.span("driver.quantum", detail="mesh_round"):
+                # the same lifecycle checkpoints as the local drive loop:
+                # kill and deadline both terminate within one round, even
+                # mid-lifespan (retained bucket pages are dropped by the
+                # caller's finally-close of every exchange)
                 check_lifecycle(cancel, deadline)
-                try:
-                    with _ledger.device_scope(
-                            getattr(d, "_mesh_device", None)):
-                        with _ledger.span("driver.step"):
-                            progress = d.process() or progress
-                except RetryableTaskError:
-                    if not recover_generation(d):
-                        raise
-                    progress = True
-                    break  # driver list mutated; restart the round
-            if deferred and spawn_ready_deferred():
-                continue
-            if all_done:
-                break
-            if not progress:
-                advanced = False
-                for fid, left in remaining_lifespans.items():
-                    in_exchanges = [
-                        exchanges[x] for x in
-                        fplan.fragments[fid].source_edges]
-                    if left <= 0:
-                        # LAST bucket of a recoverable fragment: once
-                        # its drivers finish, drop the retained pages
-                        # now instead of at query-end close()
-                        if recover and fid not in deferred \
-                                and fid in instance_drivers \
-                                and all(d.is_finished() for d
-                                        in instance_drivers[fid]):
-                            for ex in in_exchanges:
-                                ex.commit_lifespan()
+                all_done = not deferred
+                progress = False
+                for d in list(all_drivers):
+                    if d.is_finished():
                         continue
-                    if not all(d.is_finished()
-                               for d in instance_drivers[fid]):
-                        continue
-                    if not all(ex.lifespan_drained()
-                               for ex in in_exchanges):
-                        continue
-                    for ex in in_exchanges:
-                        ex.commit_lifespan()  # bucket done: drop its
-                        ex.advance_lifespan()  # retained pages
-                    remaining_lifespans[fid] = left - 1
-                    swap_generation(fid, retire)
-                    advanced = True
-                if advanced:
+                    all_done = False
+                    # per-DRIVER checkpoint, the same cadence the
+                    # TaskExecutor gives every quantum: a mesh round walks
+                    # (fragments x tasks) drivers and each process() may
+                    # hide a multi-second XLA compile — a kill/deadline
+                    # must land within one driver hand-off, not one round
+                    check_lifecycle(cancel, deadline)
+                    try:
+                        with _ledger.device_scope(
+                                getattr(d, "_mesh_device", None)):
+                            with _ledger.span("driver.step"):
+                                progress = d.process() or progress
+                    except RetryableTaskError:
+                        if not recover_generation(d):
+                            raise
+                        progress = True
+                        break  # driver list mutated; restart the round
+                if deferred and spawn_ready_deferred():
                     continue
-            rounds += 1
-            if rounds > max_rounds:
-                raise QueryError("query did not converge (deadlock?)")
+                if all_done:
+                    break
+                if not progress:
+                    advanced = False
+                    for fid, left in remaining_lifespans.items():
+                        in_exchanges = [
+                            exchanges[x] for x in
+                            fplan.fragments[fid].source_edges]
+                        if left <= 0:
+                            # LAST bucket of a recoverable fragment: once
+                            # its drivers finish, drop the retained pages
+                            # now instead of at query-end close()
+                            if recover and fid not in deferred \
+                                    and fid in instance_drivers \
+                                    and all(d.is_finished() for d
+                                            in instance_drivers[fid]):
+                                for ex in in_exchanges:
+                                    ex.commit_lifespan()
+                            continue
+                        if not all(d.is_finished()
+                                   for d in instance_drivers[fid]):
+                            continue
+                        if not all(ex.lifespan_drained()
+                                   for ex in in_exchanges):
+                            continue
+                        for ex in in_exchanges:
+                            ex.commit_lifespan()  # bucket done: drop its
+                            ex.advance_lifespan()  # retained pages
+                        remaining_lifespans[fid] = left - 1
+                        swap_generation(fid, retire)
+                        advanced = True
+                    if advanced:
+                        continue
+                rounds += 1
+                if rounds > max_rounds:
+                    raise QueryError("query did not converge (deadlock?)")
         retire(all_drivers)
 
     # ------------------------------------------------------------------

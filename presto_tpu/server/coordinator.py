@@ -84,10 +84,14 @@ class QueryLifecycle:
     must leave attempts == 1)."""
 
     def __init__(self, cancel: Optional[threading.Event] = None,
-                 deadline: Optional[float] = None):
+                 deadline: Optional[float] = None,
+                 query_id: str = ""):
         self.cancel = cancel if cancel is not None \
             else threading.Event()
         self.deadline = deadline
+        #: the client-visible query id: the statement's ledger frames
+        #: carry it on the profiler's timeline (telemetry/ledger.py)
+        self.query_id = query_id
         #: (task_id, worker_url) of the CURRENT attempt
         self.remote: List[tuple] = []
         self.attempts = 0
@@ -145,7 +149,7 @@ class _Query:
         #: expiry sheds with (see Coordinator._stamp_queue_deadline)
         self.queue_deadline: Optional[float] = None
         self.queue_shed_kind: Optional[str] = None
-        self.lifecycle = QueryLifecycle()
+        self.lifecycle = QueryLifecycle(query_id=self.id)
         #: QueryStats tree (telemetry.build_query_stats) — served by
         #: GET /v1/query/{id} and shipped to event listeners
         self.stats: Optional[dict] = None
@@ -970,6 +974,12 @@ th{{background:#222}}
                     "unattributed_frac": round(unattr / wall_ms, 4)
                     if wall_ms > 0 else 0.0,
                 }
+                if "details_ms" in led:
+                    # parts of their categories, scaled with them
+                    k = wall_ms / total if total > wall_ms > 0 else 1.0
+                    q.stats["ledger"]["details_ms"] = {
+                        c: {d: round(v * k, 3) for d, v in per.items()}
+                        for c, per in led["details_ms"].items()}
             if q.trace and isinstance(q.stats, dict) \
                     and "critical_path" not in q.stats:
                 # blocking-chain extraction over the merged fleet
@@ -1034,7 +1044,8 @@ th{{background:#222}}
             runner = self._runner()
             result = runner.execute_as(
                 sql, user, cancel=lifecycle.cancel.is_set,
-                deadline=lifecycle.deadline)
+                deadline=lifecycle.deadline,
+                query_id=lifecycle.query_id)
             if on_columns is not None:
                 on_columns([
                     {"name": n, "type": f.type.display()}
@@ -1206,7 +1217,6 @@ th{{background:#222}}
         from presto_tpu.telemetry import build_query_stats
         from presto_tpu.telemetry import kernels as _tk
         from presto_tpu.telemetry import ledger as _ledger
-        from presto_tpu.telemetry.metrics import METRICS
         # honor the statement's kernel_shape_buckets on the
         # coordinator's own root-fragment drive too: this thread plans
         # and drives pipelines directly, outside LocalRunner.execute
@@ -1223,7 +1233,8 @@ th{{background:#222}}
         # statement's (remote-task device time is attributed on the
         # workers; here it shows up as exchange-wait inside driver/
         # unattributed — the honest cross-process picture)
-        led = _ledger.QueryLedger()
+        led = _ledger.QueryLedger(
+            lifecycle.query_id if lifecycle is not None else "")
         prev_led = _ledger.install(led)
         t0_ns = _time.perf_counter_ns()
         result = None
@@ -1233,7 +1244,8 @@ th{{background:#222}}
             # bookkeeping, task-status collection) is driver overhead;
             # nested planning/exchange/serde spans subtract and the
             # root drive's executor wait is absorbed by run_drivers
-            with _ledger.span("driver.quantum"):
+            with _ledger.span("driver.quantum", detail="statement",
+                              query_id=led.query_id):
                 result = self._execute_attempt_inner(
                     sql, worker_urls, properties, on_columns, user,
                     lifecycle)
@@ -1253,13 +1265,7 @@ th{{background:#222}}
             _batch.set_shape_buckets(prev_sb)
             _ledger.uninstall(prev_led)
             led_doc = led.finish(_time.perf_counter_ns() - t0_ns)
-            for c, ms in led_doc["categories_ms"].items():
-                METRICS.inc("presto_tpu_ledger_ns_total",
-                            ms * 1e6, category=c)
-            METRICS.inc("presto_tpu_ledger_unattributed_ns_total",
-                        max(0.0, led_doc["unattributed_ms"]) * 1e6)
-            METRICS.observe("presto_tpu_ledger_unattributed_ratio",
-                            max(0.0, led_doc["unattributed_frac"]))
+            _ledger.publish(led_doc)
             qs = getattr(result, "query_stats", None)
             if qs is None:
                 import sys as _sys

@@ -37,6 +37,17 @@ Mechanics — self-time accounting with per-thread nesting:
     wall), and the executor charges the scheduling GAP — wall not
     covered by any quantum — to ``driver`` (executor overhead).
 
+  * A frame may carry a DETAIL (``span(category, detail)``): its self
+    time is charged to `category` exactly as a plain frame's, and
+    also kept per (category, detail) on the ledger (``details_ms`` in
+    the document, ``presto_tpu_ledger_detail_ns_total`` once a
+    statement). A detail names WHO inside the category spent the
+    time (the operator method of a hand-off, which of the three
+    ``driver.quantum`` frames, the phase of an exchange wave); it
+    never moves time between categories, so the invariant below is
+    untouched. What stays on the bare category is the self time of
+    its plain frames and its leaf charges.
+
 Zero overhead when no ledger is installed: every site is a thread-
 local load + branch (the ``faults.ARMED`` discipline, per-thread).
 
@@ -69,7 +80,12 @@ Category taxonomy (docs/OBSERVABILITY.md):
                   program, and the one per-wave host sync on the
                   received-row counts (parallel/shuffle.py — the ICI
                   tier of the exchange, kept apart from the DCN
-                  `exchange` HTTP wall; docs/SHARDING.md)
+                  `exchange` HTTP wall; docs/SHARDING.md). Details
+                  `assemble`, `dispatch`, `sync`, `slice`: a wave's
+                  four phases (the last cuts each consumer's batch
+                  out of the outputs); the bare category keeps the
+                  pre-wave compaction and the wave program's own
+                  call (a leaf charge)
     spool         spool I/O: task-output spool put/read-back, lifespan
                   spool disk pages
     retry_backoff transport-retry backoff sleeps
@@ -77,16 +93,26 @@ Category taxonomy (docs/OBSERVABILITY.md):
                   N+1's scan + h2d while split N's kernel runs on the
                   device (operators/driver.py; nested scan/h2d spans
                   subtract, so this is the overlap machinery's own
-                  self time)
-    driver.step   per-operator stepping: the Driver pair loop / batch
-                  pump's own self time (host Python moving batches)
+                  self time). Detail `<source kind>.get_output`
+    driver.step   per-operator stepping (host Python moving batches).
+                  Detail `<operator kind>.<method>`: one hand-off's
+                  self time, the operator's own Python net of
+                  kernels, scans, transfers and drains; the bare
+                  category is the loops' own self time (polling
+                  is_blocked / needs_input / is_finished, deadlines)
     driver.reassembly
                   batch/result reassembly: stats snapshotting, history
                   recording, coordinator-side row materialization
     driver.quantum
                   executor quantum bookkeeping + scheduling gaps +
                   statement-level drive framing (the catch-all that
-                  keeps the invariant honest)
+                  keeps the invariant honest). Details `statement`
+                  (the root frame of a statement or coordinator
+                  attempt), `executor` (a worker's quantum
+                  bookkeeping), `mesh_round` (the mesh drive loop's
+                  round: lifecycle checks, deferred spawns, lifespan
+                  advances); the bare category is the executor's
+                  scheduling gap (a leaf charge)
 
 The legacy monolithic ``driver`` category was split into the three
 ``driver.*`` sub-categories above (PR 16) so a drive-loop regression is
@@ -98,6 +124,7 @@ render and still count toward the coverage invariant —
 from __future__ import annotations
 
 import contextlib
+import itertools
 import threading
 import time
 from typing import Any, Dict, Optional, Tuple
@@ -121,17 +148,27 @@ DRIVER_CATEGORIES: Tuple[str, ...] = (
 )
 
 _TL = threading.local()
+_IDS = itertools.count(1)
 
 
 class QueryLedger:
     """Per-query category accumulator (ns). Thread-safe: executor
     worker threads and the submitting thread charge concurrently."""
 
-    __slots__ = ("_lock", "ns", "device_ns", "finished")
+    __slots__ = ("_lock", "query_id", "ns", "detail_ns", "device_ns",
+                 "finished")
 
-    def __init__(self):
+    def __init__(self, query_id: str = ""):
         self._lock = sanitize.lock("telemetry.ledger")
+        #: the request identifier the statement's frames carry on the
+        #: profiler's timeline (the root frame and every executor
+        #: quantum): the server's query id where one reached the
+        #: runner, else a process-unique one
+        self.query_id = query_id or f"ledger-{next(_IDS)}"
         self.ns: Dict[str, int] = {c: 0 for c in CATEGORIES}
+        #: (category, detail) -> ns: the self time of the detailed
+        #: frames, a part of `ns[category]` and never beside it
+        self.detail_ns: Dict[Tuple[str, str], int] = {}
         #: device index -> {category -> ns}: the shard-aware second
         #: axis (mesh drives wrap each task's quantum in device_scope,
         #: so kernel/driver charges land on the device doing the work)
@@ -139,11 +176,16 @@ class QueryLedger:
         self.finished: Optional[Dict[str, Any]] = None
 
     def charge(self, category: str, dur_ns: int,
-               device: Optional[int] = None) -> None:
+               device: Optional[int] = None,
+               detail: Optional[str] = None) -> None:
         if dur_ns <= 0:
             return
         with self._lock:
             self.ns[category] = self.ns.get(category, 0) + dur_ns
+            if detail is not None:
+                key = (category, detail)
+                self.detail_ns[key] = \
+                    self.detail_ns.get(key, 0) + dur_ns
             if device is not None:
                 per = self.device_ns.setdefault(device, {})
                 per[category] = per.get(category, 0) + dur_ns
@@ -171,8 +213,9 @@ class QueryLedger:
         (``parallel_scale`` < 1 records the factor and the raw sum),
         keeping the invariant true instead of serving a negative
         residual."""
-        snap = self.snapshot()
         with self._lock:
+            snap = dict(self.ns)
+            details = dict(self.detail_ns)
             dev_snap = {d: dict(per)
                         for d, per in self.device_ns.items()}
         attributed = sum(snap.values())
@@ -181,6 +224,7 @@ class QueryLedger:
             scale = wall_ns / attributed
             snap = {c: int(v * scale) for c, v in snap.items()}
             attributed = sum(snap.values())
+            details = {k: int(v * scale) for k, v in details.items()}
             dev_snap = {d: {c: int(v * scale) for c, v in per.items()}
                         for d, per in dev_snap.items()}
         unattributed = wall_ns - attributed
@@ -200,6 +244,13 @@ class QueryLedger:
         }
         if scale is not None:
             doc["parallel_scale"] = round(scale, 4)
+        if details:
+            # who inside a category: a PART of categories_ms[c] (the
+            # detailed frames' self time), normalized with it
+            per_cat: Dict[str, Dict[str, float]] = {}
+            for (c, d), v in sorted(details.items()):
+                per_cat.setdefault(c, {})[d] = round(v / 1e6, 3)
+            doc["details_ms"] = per_cat
         if dev_snap:
             # the shard-aware breakdown: same categories, one column
             # per mesh device that charged anything (normalized by the
@@ -231,6 +282,25 @@ def verify_coverage(doc: Dict[str, Any],
         f"(drift {drift:.3f}ms)")
 
 
+def publish(doc: Dict[str, Any]) -> None:
+    """Add a finished attribution document to the process counters:
+    every category to presto_tpu_ledger_ns_total, every detail to
+    presto_tpu_ledger_detail_ns_total, the residual to its counter
+    and its ratio histogram. Once a statement (or coordinator
+    attempt), where its ledger closes: no frame touches METRICS."""
+    from presto_tpu.telemetry.metrics import METRICS
+    for c, ms in doc["categories_ms"].items():
+        METRICS.inc("presto_tpu_ledger_ns_total", ms * 1e6, category=c)
+    for c, per in doc.get("details_ms", {}).items():
+        for d, ms in per.items():
+            METRICS.inc("presto_tpu_ledger_detail_ns_total", ms * 1e6,
+                        category=c, detail=d)
+    METRICS.inc("presto_tpu_ledger_unattributed_ns_total",
+                max(0.0, doc["unattributed_ms"]) * 1e6)
+    METRICS.observe("presto_tpu_ledger_unattributed_ratio",
+                    max(0.0, doc["unattributed_frac"]))
+
+
 # ---------------------------------------------------------------------------
 # thread-local install + nesting
 
@@ -254,31 +324,61 @@ def current() -> Optional[QueryLedger]:
     return getattr(_TL, "ledger", None)
 
 
-@contextlib.contextmanager
-def span(category: str):
+class _Frame:
+    """One open span of a thread that has a ledger: `start_ns`, the
+    time nested frames and leaf charges took (`nested_ns`), and, once
+    closed, `elapsed_ns` (the hand-off reads the operator's busy time
+    off it instead of a clock pair of its own)."""
+
+    __slots__ = ("led", "category", "detail", "start_ns", "nested_ns",
+                 "elapsed_ns", "_event")
+
+    def __init__(self, led, category, detail, meta):
+        self.led = led
+        self.category = category
+        self.detail = detail
+        self.nested_ns = 0
+        self.elapsed_ns = 0
+        name = f"ledger:{category}" if detail is None \
+            else f"ledger:{category}/{detail}"
+        self._event = TraceAnnotation(name, **meta)
+
+    def __enter__(self):
+        _TL.stack.append(self)
+        self.start_ns = time.perf_counter_ns()
+        self._event.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._event.__exit__(*exc)
+        stack = _TL.stack
+        stack.pop()
+        dur = self.elapsed_ns = time.perf_counter_ns() - self.start_ns
+        self.led.charge(self.category, dur - self.nested_ns,
+                        device=getattr(_TL, "device", None),
+                        detail=self.detail)
+        if stack:
+            stack[-1].nested_ns += dur
+        return False
+
+
+_NO_FRAME = contextlib.nullcontext()
+
+
+def span(category: str, detail: Optional[str] = None, **meta):
     """Charge this frame's SELF time (elapsed minus nested charges on
-    this thread) to `category`. A no-op — zero clock reads — when the
-    thread has no current ledger. With one, the frame is also a
-    `ledger:<category>` host event on jax.profiler's timeline, the
-    clock the device trace shares (about 0.5 us a frame, attached or
+    this thread) to `category`, and with a `detail` also to the
+    ledger's (category, detail) table: the same nanoseconds, named
+    finer. A no-op (zero clock reads; `with` binds None) when the
+    thread has no current ledger. With one, the frame is also a host
+    event on jax.profiler's timeline, the clock the device trace
+    shares: `ledger:<category>`, or `ledger:<category>/<detail>`, with
+    `meta` as the event's metadata (about 0.5 us a frame, attached or
     not: docs/OBSERVABILITY.md, "The device timeline")."""
     led = getattr(_TL, "ledger", None)
     if led is None:
-        yield
-        return
-    stack = _TL.stack
-    frame = [category, time.perf_counter_ns(), 0]
-    stack.append(frame)
-    try:
-        with TraceAnnotation(f"ledger:{category}"):
-            yield
-    finally:
-        stack.pop()
-        dur = time.perf_counter_ns() - frame[1]
-        led.charge(category, max(0, dur - frame[2]),
-                   device=getattr(_TL, "device", None))
-        if stack:
-            stack[-1][2] += dur
+        return _NO_FRAME
+    return _Frame(led, category, detail, meta)
 
 
 @contextlib.contextmanager
@@ -309,7 +409,7 @@ def add(category: str, dur_ns: int) -> None:
     led.charge(category, dur_ns, device=getattr(_TL, "device", None))
     stack = _TL.stack
     if stack:
-        stack[-1][2] += dur_ns
+        stack[-1].nested_ns += dur_ns
 
 
 def absorb(dur_ns: int) -> None:
@@ -323,7 +423,7 @@ def absorb(dur_ns: int) -> None:
         return
     stack = getattr(_TL, "stack", None)
     if stack:
-        stack[-1][2] += dur_ns
+        stack[-1].nested_ns += dur_ns
 
 
 @contextlib.contextmanager

@@ -306,7 +306,10 @@ METRICS.describe("presto_tpu_transfer_bytes_total",
                  "batch is placed on its task's device from the host; "
                  "d2d where it is copied there from another chip (a "
                  "warm mesh scan makes neither: its page-cache entry "
-                 "lives on the chip that reads it)")
+                 "lives on the chip that reads it); exchange_d2d "
+                 "(exchange_h2d) where the mesh exchange copies a "
+                 "batch, a bucket index or a wave's shard onto its "
+                 "consumer's chip")
 METRICS.describe("presto_tpu_executor_quanta_total",
                  "TaskExecutor time slices by outcome (finished/"
                  "progress/blocked/idle/failed/stalled)")
@@ -344,6 +347,22 @@ METRICS.describe("presto_tpu_ledger_ns_total",
                  "d2d/compile/dispatch/device_wait/d2h/serde/exchange/"
                  "spool/retry_backoff/prefetch/driver.*), summed "
                  "over finished queries")
+METRICS.describe("presto_tpu_ledger_detail_ns_total",
+                 "Self-time ns of the ledger's detailed frames by "
+                 "category and detail, a part of that category's "
+                 "presto_tpu_ledger_ns_total: driver.step by "
+                 "<operator kind>.<method> (a hand-off's own Python), "
+                 "prefetch by <source kind>.get_output, "
+                 "driver.quantum by statement/executor/mesh_round, "
+                 "exchange.all_to_all by assemble/dispatch/sync/slice; "
+                 "added once a finished query (ledger.publish)")
+METRICS.describe("presto_tpu_driver_passes_total",
+                 "Passes of a Driver over its operator chain by "
+                 "whether one moved a batch (moved=yes: a pair walk "
+                 "that moved, a pump split; moved=no: a walk or a "
+                 "pump poll that found an operator blocked or "
+                 "nothing to do), whichever loop made them; added "
+                 "when the driver closes")
 METRICS.describe("presto_tpu_serde_bytes_total",
                  "Page-serde codec bytes by stage (encode/decode) "
                  "and kind: raw = uncompressed payload, framed = "

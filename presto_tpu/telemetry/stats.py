@@ -142,9 +142,16 @@ def render_ledger(doc: Dict[str, Any]) -> str:
     wall = doc.get("wall_ms", 0.0)
     lines = ["wall attribution (telemetry/ledger.py, "
              "sum + unattributed == wall):"]
+    details = doc.get("details_ms", {})
     for c, ms in doc.get("categories_ms", {}).items():
         pct = (100.0 * ms / wall) if wall > 0 else 0.0
         lines.append(f"  {c:<20} {ms:>10.1f}ms  {pct:5.1f}%")
+        # who inside the category: parts of the line above (those
+        # that round to a tenth of a millisecond at least)
+        for d, part in sorted(details.get(c, {}).items(),
+                              key=lambda kv: -kv[1]):
+            if part >= 0.05:
+                lines.append(f"    /{d:<28} {part:>10.1f}ms")
     unattr = doc.get("unattributed_ms", 0.0)
     pct = (100.0 * unattr / wall) if wall > 0 else 0.0
     lines.append(f"  {'unattributed':<20} {unattr:>10.1f}ms  "

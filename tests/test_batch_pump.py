@@ -209,3 +209,55 @@ def test_pump_chaos_quantum_site(pump_state, small_executor):
         assert snap["tasks"] == 0 and snap["running_drivers"] == 0
     finally:
         faults.disarm()
+
+
+@pytest.mark.parametrize("sql", [SQL_AGG, SQL_JOIN], ids=["agg", "join"])
+def test_traced_statement_takes_the_pump(pump_state, sql):
+    """A `query_trace_enabled` statement runs the loop every other
+    statement runs (PR 39: the recorder's `op:` events come from the
+    one hand-off, so the trace gate left `_pump_ok`): its drivers
+    count under status="pump", none under "step", its recorder holds
+    the per-hand-off events, and it answers what the untraced one
+    answers."""
+    driver_mod.set_pump(True)
+    expected = LocalRunner("tpch", "tiny", NO_CACHE).execute(sql).rows()
+    r = LocalRunner("tpch", "tiny", properties={
+        **NO_CACHE, "query_trace_enabled": True})
+    n_pump0 = METRICS.get("presto_tpu_pump_drivers_total", status="pump")
+    n_step0 = METRICS.get("presto_tpu_pump_drivers_total", status="step")
+    res = r.execute(sql)
+    assert res.rows() == expected
+    assert _pumped(n_pump0), "the traced statement declined the pump"
+    assert METRICS.get("presto_tpu_pump_drivers_total",
+                       status="step") == n_step0
+    ops = {ev["name"] for ev in res.trace_events
+           if ev.get("cat") == "operator"}
+    assert any(n.startswith("op:scan:") and n.endswith(".get_output")
+               for n in ops), ops
+    assert any(n.endswith(".add_input") for n in ops), ops
+    # hand-offs, not polls: a get_output that returned nothing and a
+    # finish leave no event
+    assert not any(n.endswith(".finish") for n in ops), ops
+
+
+def test_passes_are_counted_by_whether_they_moved(pump_state):
+    """Every loop's passes reach presto_tpu_driver_passes_total when
+    the driver closes: the pump's splits are passes that moved, and a
+    pair walk counts by what it returned."""
+    def passes():
+        return {m: METRICS.get("presto_tpu_driver_passes_total", moved=m)
+                for m in ("yes", "no")}
+    r = LocalRunner("tpch", "tiny", properties=dict(SLOW_PROPS))
+    for pump in (True, False):
+        driver_mod.set_pump(pump)
+        before = passes()
+        splits0 = METRICS.get("presto_tpu_pump_splits_total")
+        r.execute(SQL_AGG)
+        after = passes()
+        moved = after["yes"] - before["yes"]
+        splits = METRICS.get("presto_tpu_pump_splits_total") - splits0
+        # every split is a pass that moved; the pair loop moves the
+        # same batches in walks of its own
+        assert moved >= max(splits, 2), (pump, moved, splits)
+        assert bool(splits) == pump
+        assert after["no"] >= before["no"]

@@ -35,6 +35,9 @@ PROPS = {"fragment_result_cache_enabled": False}
 LEDGER = 'presto_tpu_ledger_ns_total{category="%s"}'
 PROTOCOL = 'presto_tpu_protocol_ns_total{phase="%s"}'
 TRANSFER = 'presto_tpu_transfer_bytes_total{direction="%s"}'
+DETAIL = ('presto_tpu_ledger_detail_ns_total'
+          '{category="%s",detail="%s"}')
+PASSES = 'presto_tpu_driver_passes_total{moved="%s"}'
 
 
 def _readings(coord=None):
@@ -173,6 +176,15 @@ def _family_grew(run, family):
     assert total(run["after"]) > total(run["before"]), family
 
 
+def _details_grew(run, prefix):
+    """As harness/driver_detail.py reads a category's details: every
+    series of the family that starts with the category's label."""
+    def total(readings):
+        return sum(v for k, v in readings.items()
+                   if k.startswith(prefix))
+    assert total(run["after"]) > total(run["before"]), prefix
+
+
 def _present(run, series):
     assert isinstance(run["after"].get(series), (int, float)), series
 
@@ -249,6 +261,33 @@ READS = [
     _case("mesh", "presto_tpu_exchange_all_to_all_waves_total"),
     _case("mesh", 'presto_tpu_mesh_queries_total{status="ok"}'),
     _case("mesh", "presto_tpu_mesh_lock_wait_ns_total"),
+    # the ledger's details and the driver's passes (PR 39):
+    # operator_host_ / driver_loop_ms_per_query read every detail of
+    # driver.step, driver_passes_per_query / driver_moved_pass_share
+    # both values of `moved`; the named ones are what the trace's
+    # labels and PERF.md's tables are made of
+    *[_case(run, 'presto_tpu_ledger_detail_ns_total'
+            '{category="driver.step",', _details_grew)
+      for run in ("one_chip", "mesh")],
+    *[_case("one_chip", DETAIL % d) for d in (
+        ("driver.step", "hash_build.add_input"),
+        ("driver.step", "hash_build.finish"),
+        ("prefetch", "scan:lineitem.get_output"),
+        ("driver.quantum", "statement"),
+        ("driver.quantum", "executor"))],
+    *[_case("mesh", DETAIL % d) for d in (
+        ("driver.step", "exchange_sink.add_input"),
+        ("driver.quantum", "mesh_round"),
+        ("exchange.all_to_all", "assemble"),
+        ("exchange.all_to_all", "dispatch"),
+        ("exchange.all_to_all", "sync"),
+        ("exchange.all_to_all", "slice"))],
+    *[_case(run, PASSES % m) for run in ("one_chip", "mesh")
+      for m in ("yes", "no")],
+    # the exchange's own placements: a direction apart from the
+    # scans' d2d, which scan_transfer_bytes_per_query keeps reading
+    _case("mesh", TRANSFER % "exchange_d2d"),
+    _case("mesh", LEDGER % "d2d"),
     _case("one_chip", "stats.queued_ms", _present,
           why="present:one-client-never-queues"),
     _case("one_chip", "stats.wall_ms"),

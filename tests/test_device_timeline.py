@@ -72,6 +72,20 @@ EAGER_OPS = {"_reduce_sum", "add", "convert_element_type",
 #: named once, under the family that owns them
 SHARED = {"join_probe_hash", "join_probe_search"}
 
+#: the ledger's detailed host names, `ledger:<category>/<detail>`
+#: (PR 39): a detail is a word of this table, or, under `driver.step`
+#: and `prefetch`, `<operator kind>.<method>` with the kind one of
+#: the plan's operators. A new detail is a new line here
+#: (test_every_detailed_name_is_in_the_table).
+LEDGER_DETAILS = {
+    "driver.quantum": {"statement", "executor", "mesh_round"},
+    "exchange.all_to_all": {"assemble", "dispatch", "sync", "slice"},
+}
+HANDOFF_METHODS = {
+    "driver.step": {"get_output", "add_input", "finish"},
+    "prefetch": {"get_output"},
+}
+
 _NO_RESULT_REPLAY = {"fragment_result_cache_enabled": False}
 
 
@@ -80,8 +94,9 @@ def _sql(name):
         return f.read()
 
 
-def _host_events(log_dir):
-    """{thread: [(start_ns, end_ns, name)]} of the host's planes."""
+def _host_events(log_dir, meta=False):
+    """{thread: [(start_ns, end_ns, name)]} of the host's planes; with
+    `meta`, each event's metadata as a fourth member."""
     path = sorted(glob.glob(os.path.join(
         log_dir, "plugins", "profile", "*", "*.xplane.pb")))[-1]
     data = jax.profiler.ProfileData.from_file(path)
@@ -91,9 +106,11 @@ def _host_events(log_dir):
             continue
         for i, line in enumerate(plane.lines):  # names repeat
             events = [(e.start_ns, e.start_ns + e.duration_ns, e.name)
+                      + ((dict(e.stats),) if meta else ())
                       for e in line.events]
             if events:
-                threads[(plane.name, i, line.name)] = sorted(events)
+                threads[(plane.name, i, line.name)] = sorted(
+                    events, key=lambda ev: ev[:3])
     return threads
 
 
@@ -280,3 +297,149 @@ def test_span_without_a_ledger_reads_no_clock(monkeypatch):
     with ledger.span("scan"):
         pass
     ledger.add("dispatch", 5)
+
+
+# ---------------------------------------------------------------------------
+# operator-level detail under the ledger's frames (PR 39): the same
+# names through the batch pump, the pair loop and the mesh's loop
+
+
+def _traced(runner, sqls, tmp_path_factory):
+    """(host events by thread with each event's metadata, operator
+    kinds of the plans) of one warm execution of each statement."""
+    for sql in sqls:
+        for _ in range(3):
+            runner.execute(sql)
+    log_dir = str(tmp_path_factory.mktemp("profile"))
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    kinds = set()
+    jax.profiler.start_trace(log_dir, profiler_options=opts)
+    try:
+        for sql in sqls:
+            stats = runner.execute(sql).query_stats
+            kinds |= {op["name"] for task in stats["tasks"]
+                      for pipeline in task["pipelines"]
+                      for op in pipeline}
+    finally:
+        jax.profiler.stop_trace()
+    threads = {
+        thread: [ev for ev in events if ev[2].startswith("ledger:")]
+        for thread, events in _host_events(log_dir, meta=True).items()}
+    return threads, kinds
+
+
+@pytest.fixture(scope="module", params=["pump", "pair", "mesh"])
+def loop_trace(request, tmp_path_factory):
+    """Q3 and Q6 under jax.profiler through each of the three drive
+    loops: the executor's batch pump, its pair loop (the pump's switch
+    off), and MeshRunner's round over four of the host's devices."""
+    from presto_tpu.operators import driver as driver_mod
+    from presto_tpu.runner import LocalRunner, runner_for
+    sqls = [_sql("q3"), _sql("q6")]
+    prev = driver_mod.pump_enabled()
+    driver_mod.set_pump(request.param != "pair")
+    try:
+        if request.param == "mesh":
+            runner = runner_for("tpch", "tiny", {
+                **_NO_RESULT_REPLAY, "mesh_devices": 4})
+        else:
+            runner = LocalRunner("tpch", "tiny",
+                                 properties=_NO_RESULT_REPLAY)
+        threads, kinds = _traced(runner, sqls, tmp_path_factory)
+    finally:
+        driver_mod.set_pump(prev)
+    return request.param, threads, kinds
+
+
+def _names(threads):
+    return {name for events in threads.values()
+            for _, _, name, _ in events}
+
+
+def test_every_operator_kind_has_its_handoff_on_the_timeline(loop_trace):
+    loop, threads, kinds = loop_trace
+    assert {"hash_build", "scan:lineitem", "output"} <= kinds
+    seen = _names(threads)
+    for kind in kinds:
+        mine = {n for n in seen if n.startswith(
+            (f"ledger:driver.step/{kind}.", f"ledger:prefetch/{kind}."))}
+        assert mine, (loop, kind, sorted(seen))
+    # a source is pulled under `prefetch` by the pump alone
+    pulled = {n for n in seen if n.startswith("ledger:prefetch/")}
+    assert bool(pulled) == (loop == "pump"), pulled
+    assert "ledger:driver.step/hash_build.add_input" in seen
+    assert "ledger:driver.step/hash_build.finish" in seen
+
+
+def test_every_detailed_name_is_in_the_table(loop_trace):
+    loop, threads, kinds = loop_trace
+    detailed = {n for n in _names(threads) if "/" in n}
+    assert detailed
+    for name in detailed:
+        category, detail = name[len("ledger:"):].split("/", 1)
+        if category in LEDGER_DETAILS:
+            assert detail in LEDGER_DETAILS[category], name
+        else:
+            kind, method = detail.rsplit(".", 1)
+            assert method in HANDOFF_METHODS[category], name
+            assert kind in kinds, name
+    # what the bare names keep: the loops' own frames
+    assert "ledger:driver.step" in _names(threads)
+    assert "ledger:driver.quantum" not in _names(threads)
+
+
+def test_quantum_frames_are_three_names_with_the_query_id(loop_trace):
+    loop, threads, _ = loop_trace
+    roots = [ev for events in threads.values() for ev in events
+             if ev[2] == "ledger:driver.quantum/statement"]
+    assert len(roots) == 2                  # Q3 and Q6
+    ids = [meta.get("query_id") for _, _, _, meta in roots]
+    assert all(ids) and len(set(ids)) == 2, ids
+    seen = _names(threads)
+    if loop == "mesh":
+        assert "ledger:driver.quantum/mesh_round" in seen
+    else:
+        # an executor worker's quantum names the statement it serves
+        quanta = [ev for events in threads.values() for ev in events
+                  if ev[2] == "ledger:driver.quantum/executor"]
+        assert quanta
+        assert {meta.get("query_id") for _, _, _, meta in quanta} \
+            == set(ids)
+        for start, end, _, meta in quanta:
+            (root,) = [r for r in roots
+                       if r[3]["query_id"] == meta["query_id"]]
+            assert root[0] <= start and end <= root[1]
+
+
+def test_handoffs_nest_in_the_loops_frame(loop_trace):
+    """A hand-off is opened inside a loop's plain `driver.step` frame
+    (process_quantum's, or the mesh round's per-driver one): same
+    category, so the detail moves time inside it and nowhere else."""
+    loop, threads, _ = loop_trace
+    checked = 0
+    for events in threads.values():
+        plain = [(s, e) for s, e, n, _ in events
+                 if n == "ledger:driver.step"]
+        for s, e, name, _ in events:
+            if name.startswith("ledger:driver.step/"):
+                assert any(ps <= s and e <= pe for ps, pe in plain), name
+                checked += 1
+    assert checked
+
+
+def test_wave_phases_are_frames_of_the_exchange(loop_trace):
+    loop, threads, _ = loop_trace
+    seen = _names(threads)
+    phases = {f"ledger:exchange.all_to_all/{p}"
+              for p in LEDGER_DETAILS["exchange.all_to_all"]}
+    if loop != "mesh":
+        assert not phases & seen
+        return
+    assert phases <= seen, sorted(seen)
+    for events in threads.values():
+        waves = [(s, e) for s, e, n, _ in events
+                 if n == "ledger:exchange.all_to_all"]
+        for s, e, name, _ in events:
+            if name in phases:
+                assert any(ws <= s and e <= we for ws, we in waves)

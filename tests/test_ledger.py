@@ -222,3 +222,180 @@ def test_query_doctor_end_to_end(runner, tmp_path):
     f.write_text(json.dumps({"stats": res.query_stats}))
     assert query_doctor.main(["--file", str(f)]) == 0
     assert query_doctor.main(["--file", str(f), "--json"]) == 0
+
+
+# ---------------------------------------------------------------------------
+# details: a frame may say WHO inside its category (PR 39)
+
+
+class _Ticks:
+    """A clock that advances 1 ms at every read: frames are then worth
+    exact, repeatable nanoseconds."""
+
+    def __init__(self):
+        self.now = 0
+
+    def perf_counter_ns(self):
+        self.now += 1_000_000
+        return self.now
+
+
+def _nested(monkeypatch, inner_detail, outer_detail=None):
+    """outer(category) { inner(category, inner_detail) { leaf } } on a
+    ticking clock; returns the finished document."""
+    from presto_tpu.telemetry import ledger
+    monkeypatch.setattr(ledger, "time", _Ticks())
+    led = ledger.QueryLedger()
+    prev = ledger.install(led)
+    try:
+        with ledger.span("driver.step", detail=outer_detail):
+            with ledger.span("driver.step", detail=inner_detail):
+                ledger.add("dispatch", 250_000)
+            with ledger.span("scan"):
+                pass
+    finally:
+        ledger.uninstall(prev)
+    return led.finish(10_000_000)
+
+
+@pytest.mark.parametrize("inner, outer", [
+    ("hash_build.add_input", None),
+    ("hash_build.add_input", "mesh_round"),
+    (None, "statement"),
+], ids=["detail-in-plain", "detail-in-detail", "plain-in-detail"])
+def test_detail_moves_no_time_between_categories(monkeypatch, inner,
+                                                 outer):
+    """A detailed frame nested in a plain one of its category leaves
+    the category's total, every other category and the coverage
+    invariant exactly as two plain frames leave them."""
+    from presto_tpu.telemetry import ledger
+    plain = _nested(monkeypatch, None)
+    doc = _nested(monkeypatch, inner, outer)
+    ledger.verify_coverage(doc)
+    assert doc["categories_ms"] == plain["categories_ms"]
+    assert doc["unattributed_ms"] == plain["unattributed_ms"]
+    assert "details_ms" not in plain
+    details = doc["details_ms"]
+    assert set(details) == {"driver.step"}
+    assert set(details["driver.step"]) == {inner, outer} - {None}
+    # the inner frame: 1 ms between its two reads, less the leaf
+    if inner is not None:
+        assert details["driver.step"][inner] == 0.75
+    assert sum(details["driver.step"].values()) \
+        <= doc["categories_ms"]["driver.step"]
+
+
+def test_details_sum_to_at_most_their_category_when_normalized():
+    """Thread time past the wall is scaled onto it: the details are
+    parts of their categories and shrink with them."""
+    from presto_tpu.telemetry import ledger
+    led = ledger.QueryLedger()
+    led.charge("driver.step", 6_000_001, detail="a.add_input")
+    led.charge("driver.step", 3_000_001, detail="b.get_output")
+    led.charge("driver.step", 1_000_001)
+    led.charge("dispatch", 10_000_003)
+    doc = led.finish(5_000_000)
+    ledger.verify_coverage(doc)
+    assert doc["parallel_scale"] < 1
+    for c, per in doc["details_ms"].items():
+        assert sum(per.values()) <= doc["categories_ms"][c] + 1e-9
+    assert doc["details_ms"]["driver.step"]["a.add_input"] \
+        == pytest.approx(1.5, abs=0.002)
+
+
+def test_detailed_frame_without_a_ledger_reads_no_clock(monkeypatch):
+    from presto_tpu.telemetry import ledger
+
+    class NoClock:
+        def perf_counter_ns(self):
+            raise AssertionError("span read the clock")
+
+    def no_annotation(name, **meta):
+        raise AssertionError("span opened a TraceAnnotation")
+    assert ledger.current() is None
+    monkeypatch.setattr(ledger, "time", NoClock())
+    monkeypatch.setattr(ledger, "TraceAnnotation", no_annotation)
+    with ledger.span("driver.step", detail="hash_build.add_input",
+                     query_id="q1") as frame:
+        assert frame is None
+
+
+def test_frame_is_a_named_host_event_with_its_metadata(monkeypatch):
+    """`ledger:<category>` without a detail, `ledger:<category>/
+    <detail>` with one; `meta` goes to the annotation's keywords."""
+    from presto_tpu.telemetry import ledger
+    opened = []
+
+    class Annotation:
+        def __init__(self, name, **meta):
+            opened.append((name, meta))
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+    monkeypatch.setattr(ledger, "TraceAnnotation", Annotation)
+    led = ledger.QueryLedger("q-17")
+    prev = ledger.install(led)
+    try:
+        with ledger.span("driver.quantum", detail="statement",
+                         query_id=led.query_id) as frame:
+            with ledger.span("planning"):
+                pass
+    finally:
+        ledger.uninstall(prev)
+    assert opened == [
+        ("ledger:driver.quantum/statement", {"query_id": "q-17"}),
+        ("ledger:planning", {})]
+    assert frame.elapsed_ns >= frame.nested_ns > 0
+    # a ledger without a server's id still has one of its own
+    assert ledger.QueryLedger().query_id != ledger.QueryLedger().query_id
+
+
+def test_publish_feeds_every_family_once_a_document():
+    """The one publishing function: categories, details, the residual
+    and its ratio, from the document alone."""
+    from presto_tpu.telemetry import ledger
+    from presto_tpu.telemetry.metrics import METRICS
+    detail = ('presto_tpu_ledger_detail_ns_total{category="driver.step",'
+              'detail="unit_test.add_input"}')
+    category = 'presto_tpu_ledger_ns_total{category="driver.step"}'
+    before = METRICS.snapshot()
+    h_before = METRICS.histogram_snapshot(
+        "presto_tpu_ledger_unattributed_ratio")["count"]
+    ledger.publish({
+        "wall_ms": 10.0, "categories_ms": {"driver.step": 6.0},
+        "details_ms": {"driver.step": {"unit_test.add_input": 4.0}},
+        "unattributed_ms": 4.0, "unattributed_frac": 0.4})
+    after = METRICS.snapshot()
+    assert after[detail] - before.get(detail, 0) == 4_000_000
+    assert after[category] - before.get(category, 0) == 6_000_000
+    assert after["presto_tpu_ledger_unattributed_ns_total"] \
+        - before.get("presto_tpu_ledger_unattributed_ns_total", 0) \
+        == 4_000_000
+    assert METRICS.histogram_snapshot(
+        "presto_tpu_ledger_unattributed_ratio")["count"] == h_before + 1
+
+
+def test_statement_details_reach_stats_and_explain_analyze(runner):
+    """details_ms rides the document wherever the categories go: the
+    statement's stats, and EXPLAIN ANALYZE's attribution section."""
+    from presto_tpu.telemetry.ledger import verify_coverage
+    sql = ("select returnflag, count(*) from lineitem "
+           "group by returnflag")
+    doc = runner.execute(sql).query_stats["ledger"]
+    verify_coverage(doc)
+    details = doc["details_ms"]
+    assert "statement" in details["driver.quantum"]
+    assert any(d.startswith("scan:lineitem.")
+               for d in details["prefetch"]), details
+    assert any(d.endswith(".add_input") for d in details["driver.step"])
+    for c, per in details.items():
+        # each a part of its category (every value rounded to 1 us)
+        assert sum(per.values()) <= doc["categories_ms"][c] \
+            + 0.001 * (len(per) + 1), (c, per)
+    text = "\n".join(r[0] for r in runner.execute(
+        "explain analyze " + sql).rows())
+    assert "wall attribution" in text
+    assert "    /" in text and ".add_input" in text
