@@ -675,7 +675,7 @@ def plan_cross_fragment_filters(fplan: FragmentedPlan
             if isinstance(node, N.TableScanNode):
                 return (node, symbol, crossed) \
                     if symbol in node.assignments else None
-            if isinstance(node, N.FilterNode):
+            if isinstance(node, (N.FilterNode, N.SemiJoinNode)):
                 node = node.source
             elif isinstance(node, N.ProjectNode):
                 expr = dict(node.assignments).get(symbol)

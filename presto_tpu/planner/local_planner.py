@@ -118,11 +118,12 @@ DF_SKIP_BUILD_ROWS = 1 << 20
 
 
 def _trace_scan_column(node: N.PlanNode, symbol: str, shared=frozenset()):
-    """Follow `symbol` down through filters and identity projections to
-    the TableScanNode that produces it; None when anything else (a
-    join, aggregation, or exchange boundary) intervenes, or when any
-    node on the path is SHARED (a spooled subtree also feeds other
-    consumers — a join-specific filter there would corrupt them)."""
+    """Follow `symbol` down through filters, semi joins' probe sides
+    and identity projections to the TableScanNode that produces it;
+    None when anything else (a join, aggregation, or exchange boundary)
+    intervenes, or when any node on the path is SHARED (a spooled
+    subtree also feeds other consumers — a join-specific filter there
+    would corrupt them)."""
     from presto_tpu.expr.ir import InputRef
     while True:
         if id(node) in shared:
@@ -130,6 +131,10 @@ def _trace_scan_column(node: N.PlanNode, symbol: str, shared=frozenset()):
         if isinstance(node, N.TableScanNode):
             return (node, symbol) if symbol in node.assignments else None
         if isinstance(node, N.FilterNode):
+            node = node.source
+            continue
+        if isinstance(node, N.SemiJoinNode):
+            # passes its source's rows through unchanged, as a filter
             node = node.source
             continue
         if isinstance(node, N.ProjectNode):
@@ -1008,16 +1013,14 @@ class LocalExecutionPlanner:
             node.source, node.filtering_source,
             [(node.source_key, node.filtering_key)])
         # IN/EXISTS keeps only source rows whose key appears in the
-        # filtering side — the same pruning contract as an inner join,
-        # so the build publishes dynamic filters too (NOT IN must not:
-        # pruning would drop exactly the rows it keeps)
-        df_publish = self._plan_dynamic_filters(
-            node.source, node.filtering_source,
-            [(node.source_key, node.filtering_key)]) \
+        # filtering side, so the build publishes to scans in OTHER
+        # fragments (pruning there saves an exchange). No local filter:
+        # one could only reach a scan feeding this probe through
+        # filters and projections, and would repeat the probe's own
+        # membership search lane for lane. NOT IN publishes nothing:
+        # pruning would drop exactly the rows it keeps.
+        df_publish = (self._cross_df_publish(node) or None) \
             if not node.negate else None
-        cross = self._cross_df_publish(node) if not node.negate else []
-        if cross:
-            df_publish = (df_publish or []) + cross
         build_pipe: List = []
         self._visit(node.filtering_source, build_pipe)
         build_pipe.append(HashBuildOperatorFactory(

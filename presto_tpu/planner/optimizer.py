@@ -228,8 +228,66 @@ def _rewrite(node: N.PlanNode, estimator=None,
             out = pushed
         else:
             out = _rewrite_filter(node, estimator)
+    elif isinstance(node, N.SemiJoinNode):
+        out = _sink_semi_join(node, shared) or node
     memo[orig_id] = out
     return out
+
+
+def _sink_semi_join(node: N.SemiJoinNode,
+                    shared: Optional[Set[int]] = None
+                    ) -> Optional[N.PlanNode]:
+    """SemiJoin over a JoinNode: move the semi join below the join,
+    onto the input that carries its key, one join at a time (the rule
+    runs after the join order is chosen, so it never moves a build).
+    IN/EXISTS, negated or not, is a row-wise predicate on one key; an
+    inner join leaves that key equal and non-null, a LEFT join leaves
+    its preserved side's rows whole — so the semi join keeps the same
+    rows below the join as above it, and the join stops widening rows
+    the semi join would drop.
+
+    A build key that a criterion equates with a probe key sinks onto
+    the probe under that key: the build, its layout and the join's own
+    dynamic filters stay as chosen. A build key nothing equates sinks
+    into the build. The null-supplying side of an outer join, FULL and
+    cross joins, and a shared join or input stay put (the rewrite
+    MUTATES the join, as _push_filter_through_join does)."""
+    src = node.source
+    if not isinstance(src, N.JoinNode):
+        return None
+    if shared and (id(src) in shared or id(src.left) in shared
+                   or id(src.right) in shared):
+        return None
+    side_key = _semi_sink_target(src, node.source_key)
+    if side_key is None:
+        return None
+    side, key = side_key
+    child = getattr(src, side)
+    sunk = N.SemiJoinNode(child, node.filtering_source, key,
+                          node.filtering_key, node.negate,
+                          tuple(child.output))
+    from presto_tpu.telemetry.metrics import METRICS
+    METRICS.inc("presto_tpu_semi_join_sinks_total")
+    setattr(src, side, _sink_semi_join(sunk, shared) or sunk)
+    keep = {f.symbol for f in node.output}
+    src.output = tuple(f for f in src.output if f.symbol in keep)
+    return src
+
+
+def _semi_sink_target(join: N.JoinNode,
+                      key: str) -> Optional[Tuple[str, str]]:
+    """(input attribute, key symbol there) for a semi join on `key`
+    sitting over `join`, or None where it must stay above."""
+    left = {f.symbol: f for f in join.left.output}
+    right = {f.symbol: f for f in join.right.output}
+    if key in left and join.join_type in ("inner", "left"):
+        return "left", key
+    if key in right and join.join_type == "inner":
+        for l, r in join.criteria:
+            if r == key and l in left and left[l].type == right[r].type:
+                return "left", l
+        return "right", key
+    return None
 
 
 def _push_filter_through_join(node: N.FilterNode, estimator=None,

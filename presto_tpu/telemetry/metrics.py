@@ -282,6 +282,11 @@ METRICS.describe("presto_tpu_semi_join_matched_rows_total",
                  "Rows the semi joins of drained statements kept: "
                  "probe rows with a match, or for NOT IN / NOT EXISTS "
                  "those without one (the operator's output rows)")
+METRICS.describe("presto_tpu_semi_join_sinks_total",
+                 "Joins an IN/EXISTS semi join was moved below at "
+                 "planning (optimizer._sink_semi_join), one per join "
+                 "passed: TPC-H Q18's semi join passes two and lands "
+                 "on the lineitem scan")
 METRICS.describe("presto_tpu_protocol_ns_total",
                  "Client-protocol ns on the coordinator by phase: "
                  "accept = POST /v1/statement in to response out, "
