@@ -239,6 +239,16 @@ class Operator(abc.ABC):
     def close(self) -> None:
         pass
 
+    def count_drained(self, stats: "OperatorStats") -> None:
+        """Add this operator's armed row counts to the process's
+        counters once its statement has drained (telemetry/stats.py:
+        count_streamed_rows): its `row_series`, where it names one."""
+        if self.row_series is not None:
+            from presto_tpu.telemetry.metrics import METRICS
+            rows_in, rows_out = self.row_series
+            METRICS.inc(rows_in, stats.input_rows)
+            METRICS.inc(rows_out, stats.output_rows)
+
     # -- stats helpers ------------------------------------------------------
 
     def _count_in(self, batch: Batch) -> None:

@@ -35,6 +35,15 @@ from presto_tpu.telemetry.metrics import METRICS
 
 #: lanes the lookup join's probes searched / gathered output columns at
 _LANES = "presto_tpu_join_probe_lanes_total"
+#: the least bytes the sorted layout's builds and probes read
+_SORTED_BYTES = "presto_tpu_join_sorted_bytes_total"
+#: the build row index a lookup join gathers each output row by (int32)
+_ROW_INDEX_BYTES = 4
+
+
+def _key_bytes(batch: Batch, key_names) -> int:
+    """Bytes a row of `batch`'s key columns holds: static widths."""
+    return sum(batch.columns[k].data.dtype.itemsize for k in key_names)
 
 
 class JoinCapacityExceeded(Exception):
@@ -372,6 +381,9 @@ class HashBuildOperator(Operator):
         if table.layout == "direct":
             METRICS.inc("presto_tpu_join_direct_table_slots_total",
                         table.slot_of.shape[0])
+        else:
+            METRICS.inc(_SORTED_BYTES,
+                        total * _key_bytes(merged, self.key_names))
         self.bridge.table = table
         self._batches = []
 
@@ -557,7 +569,6 @@ def make_probe_kernel(key_names: Tuple[str, ...], join_type: str,
     # join_probe group's device time is the whole probe's
     family, part = ("fragment", "join_probe") if pre is not None \
         else ("join_probe", None)
-    jit_list = None
     back = _kernels.jit(_materialize, "join_probe", "materialize",
                         static_argnums=(5,))
     if ops_common.cpu_backend():
@@ -622,25 +633,43 @@ def make_probe_kernel(key_names: Tuple[str, ...], join_type: str,
                 return batch, join_ops._candidates_enc(
                     table, batch, key_names, verify)
 
-        @functools.partial(_kernels.jit, family=family, part=part,
-                           static_argnums=(3,))
-        def whole(table, batch, matched, out_capacity: int):
+        def _whole(table, batch, matched, out_capacity: int):
             batch, lo_enc = _search(table, batch)
             return _expand_project(table, batch, lo_enc, None, matched,
                                    out_capacity)
 
-        @functools.partial(_kernels.jit, family=family, part=part)
-        def front(table, batch, matched):
+        def _front_of(table, batch, matched):
             batch, lo_enc = _search(table, batch)
             return _front(table, batch, lo_enc, matched)
 
-    # compile-vs-execute attribution rides the cached kernels. The CPU
-    # forms are host wrappers over several jits — the per-probe stages
-    # plus the shared module-level search jits — so all executable
-    # caches are polled for compile detection (one list for both: a
-    # stage the other form compiled reads as this one's at worst). A
-    # probe with a fused upstream chain is a whole-fragment program
-    # (`fragment` family).
+        # one program a build layout, so that a module is the sorted
+        # layout's hash-and-search or the direct one's gather, never
+        # both: `join_probe` / `fragment_join_probe` probe a direct
+        # table, `..._sorted` a sorted one
+        parts = {"direct": part,
+                 "sorted": "sorted" if part is None else f"{part}_sorted"}
+        wholes = {layout: _kernels.jit(_whole, family, p,
+                                       static_argnums=(3,))
+                  for layout, p in parts.items()}
+        fronts = {layout: _kernels.jit(_front_of, family, p)
+                  for layout, p in parts.items()}
+        jit_list = [*wholes.values(), *fronts.values()]
+
+        def whole(table, batch, matched, out_capacity: int):
+            return wholes[table.layout](table, batch, matched,
+                                        out_capacity)
+
+        def front(table, batch, matched):
+            return fronts[table.layout](table, batch, matched)
+
+    # compile-vs-execute attribution rides the cached kernels. Both
+    # forms are host wrappers over several jits — on the CPU the
+    # per-probe stages plus the shared module-level search jits, on
+    # the TPU one program a layout — so all executable caches are
+    # polled for compile detection (one list for both: a stage the
+    # other form compiled reads as this one's at worst). A probe with
+    # a fused upstream chain is a whole-fragment program (`fragment`
+    # family).
     kernel = ProbeKernel(
         _kernels.instrument_kernel(whole, family, jits=jit_list),
         _kernels.instrument_kernel(front, family, jits=jit_list),
@@ -733,6 +762,10 @@ class LookupJoinOperator(Operator):
         self._cur_table = None
         self._cur_part = -1
         self._probe_bufs = None
+        #: the layout of the build this operator probed and its keys'
+        #: bytes a row, for count_drained (None: never probed)
+        self._layout: Optional[str] = None
+        self._probe_key_bytes = 0
         ctx.driver_context.deferred_checks.append(self._deferred_check)
 
     def _deferred_check(self):
@@ -761,6 +794,11 @@ class LookupJoinOperator(Operator):
         if self.join_type == "full" and self._matched is None:
             self._matched = jnp.zeros(table.sorted_hash.shape[0],
                                       dtype=bool)
+        self._layout = table.layout
+        # the build's key columns: the probe's share their types, and
+        # a fused upstream chain makes the probe's inside the kernel
+        self._probe_key_bytes = _key_bytes(
+            table.batch, self.build_keys or self.key_names)
         METRICS.inc(_LANES, batch.capacity, stage="searched")
         # selective joins emit few rows into a fat capacity; left
         # uncompacted that padding would ride every downstream
@@ -892,6 +930,19 @@ class LookupJoinOperator(Operator):
         return self._finishing and not self._pending \
             and self._probe_bufs is None \
             and (self.join_type != "full" or self._outer_emitted)
+
+    def count_drained(self, stats) -> None:
+        """The live probe rows by the layout they were probed against
+        and, against a sorted build, what its search and row index
+        read (presto_tpu_join_sorted_bytes_total)."""
+        if self._layout is None:
+            return
+        METRICS.inc("presto_tpu_join_probe_rows_total", stats.input_rows,
+                    layout=self._layout)
+        if self._layout == "sorted":
+            METRICS.inc(_SORTED_BYTES,
+                        stats.input_rows * self._probe_key_bytes
+                        + stats.output_rows * _ROW_INDEX_BYTES)
 
 
 class SemiJoinOperator(Operator):

@@ -240,13 +240,14 @@ def _cache_sizes(jits) -> int:
 
 
 def record(name: str, dur_ns: int, compiled: bool,
-           reason: Optional[str] = None) -> None:
+           reason: Optional[str] = None, framed_ns: int = 0) -> None:
     """Credit one kernel call to the current operator, the current
     query, and the process counters. `reason` classifies a compile for
     the retrace counter: "new_kernel" (this kernel object's FIRST
     trace — a genuinely new program) vs "shape" (an already-traced
     kernel re-traced for a new input signature: the bucketing gap the
-    kernel_shape_buckets property exists to close)."""
+    kernel_shape_buckets property exists to close). `framed_ns` is the
+    part of the call that ledger frames inside it charged already."""
     op = getattr(_TL, "op", None)
     if op is not None:
         if compiled:
@@ -256,7 +257,7 @@ def record(name: str, dur_ns: int, compiled: bool,
     # attribution ledger: compile wall vs async DISPATCH wall (device
     # completion is measured at drain points as device_wait —
     # telemetry/ledger.py); flight recorder keeps compile edges
-    _ledger.add_kernel(dur_ns, compiled)
+    _ledger.add_kernel(dur_ns, compiled, framed_ns)
     if compiled and _flight.ENABLED:
         _flight.record("compile", name, round(dur_ns / 1e6, 1),
                        reason or "")
@@ -363,6 +364,7 @@ def instrument_kernel(kernel, name: str, jits=None):
         with state["lock"]:
             state["active"][tok] = False
         before = _cache_sizes(jits)
+        framed0 = _ledger.nested_ns()
         t0 = time.perf_counter_ns()
         try:
             with TraceAnnotation(
@@ -384,7 +386,8 @@ def instrument_kernel(kernel, name: str, jits=None):
                 state["accounted"] = after
                 for k in state["active"]:
                     state["active"][k] = True
-        record(name, dur, compiled, reason)
+        record(name, dur, compiled, reason,
+               max(0, _ledger.nested_ns() - framed0))
         if _trace.ACTIVE:
             rec = _trace.current()
             if rec is not None:

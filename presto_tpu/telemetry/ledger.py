@@ -443,12 +443,25 @@ def kernel_scope(category: str):
         _TL.kernel_category = prev
 
 
-def add_kernel(dur_ns: int, compiled: bool) -> None:
+def nested_ns() -> int:
+    """The nested time of this thread's innermost open frame so far (0
+    without one): a leaf measured around code that opens frames of its
+    own reads it before and after, the frames inside having charged
+    the difference already."""
+    stack = getattr(_TL, "stack", None)
+    return stack[-1].nested_ns if stack else 0
+
+
+def add_kernel(dur_ns: int, compiled: bool, framed_ns: int = 0) -> None:
     """The telemetry.kernels hook: a compiling call is COMPILE wall, a
     warm call is host DISPATCH wall (async — device-side completion is
     measured separately as device_wait at drain points; see the
     async-dispatch undercount note in docs/OBSERVABILITY.md). Warm
-    dispatch honors any enclosing `kernel_scope` redirect."""
+    dispatch honors any enclosing `kernel_scope` redirect. `framed_ns`
+    is the part of the call that frames inside it charged (a host
+    wrapper's blocking fetch, under `device_wait` and `d2h`): charged
+    twice, it would push the enclosing frame's self time below 0."""
+    dur_ns -= framed_ns
     if compiled:
         add("compile", dur_ns)
     else:

@@ -282,6 +282,18 @@ METRICS.describe("presto_tpu_semi_join_matched_rows_total",
                  "Rows the semi joins of drained statements kept: "
                  "probe rows with a match, or for NOT IN / NOT EXISTS "
                  "those without one (the operator's output rows)")
+METRICS.describe("presto_tpu_join_probe_rows_total",
+                 "Live rows the lookup joins of drained statements "
+                 "probed, by the layout of the build each probed "
+                 "(sorted or direct; the operator's input rows, "
+                 "counted as presto_tpu_agg_stream_rows_total is)")
+METRICS.describe("presto_tpu_join_sorted_bytes_total",
+                 "Least bytes the sorted join layout must read: a "
+                 "sorted build's live rows x its keys' widths, at its "
+                 "finish; a lookup join's live probe rows x its probe "
+                 "keys' widths + its output rows x 4 (the int32 build "
+                 "row each gathers by), at the statement's drain. "
+                 "Static widths x rows already counted: no sync")
 METRICS.describe("presto_tpu_semi_join_sinks_total",
                  "Joins an IN/EXISTS semi join was moved below at "
                  "planning (optimizer._sink_semi_join), one per join "

@@ -11,8 +11,6 @@ from __future__ import annotations
 
 from typing import Any, Dict, List, Optional
 
-from presto_tpu.telemetry.metrics import METRICS
-
 
 def snapshot_drivers(drivers, pool=None) -> List[List[Dict[str, Any]]]:
     """Materialize per-operator stats into JSON-able dicts, one list
@@ -35,20 +33,17 @@ def snapshot_drivers(drivers, pool=None) -> List[List[Dict[str, Any]]]:
 
 
 def count_streamed_rows(drivers) -> None:
-    """Add a drained statement's streaming-aggregation and semi-join
-    rows to the process's counters (the operators that name a
-    `row_series`). Call it after snapshot_drivers, which has
-    materialized the stats: it reads no device value of its own. Only
-    operators whose row counters were armed (profile, or the history
-    recorder's interesting_ops) count."""
+    """Add a drained statement's operator rows to the process's
+    counters (`Operator.count_drained`: the streaming aggregation's
+    and the semi join's `row_series`, the lookup join's probe rows by
+    layout). Call it after snapshot_drivers, which has materialized
+    the stats: it reads no device value of its own. Only operators
+    whose row counters were armed (profile, or the history recorder's
+    interesting_ops) count."""
     for d in drivers:
         for op in d.operators:
-            stats = op.ctx.stats
-            if op.row_series is None or not stats.count_rows:
-                continue
-            rows_in, rows_out = op.row_series
-            METRICS.inc(rows_in, stats.input_rows)
-            METRICS.inc(rows_out, stats.output_rows)
+            if op.ctx.stats.count_rows:
+                op.count_drained(op.ctx.stats)
 
 
 def _ms(ns: int) -> float:

@@ -104,6 +104,15 @@ def q18():
 
 
 @pytest.fixture(scope="module")
+def q5():
+    """Q5 twice: the statement of the cell sf10_q5_serial. On tiny the
+    optimizer puts the customer join last of the first four, so its
+    build takes the two keys (custkey, nationkey) and the sorted
+    layout; at SF10 the supplier's does. Both are multi-key builds."""
+    return _serve({}, [QUERIES[5]] * 2)
+
+
+@pytest.fixture(scope="module")
 def mesh():
     """Q3 twice on the served four-device mesh of test_mesh_served.py;
     the second arrives while the mesh is held, so it waits for it."""
@@ -256,6 +265,15 @@ READS = [
     _case("q18", "presto_tpu_semi_join_probe_rows_total"),
     _case("q18", "presto_tpu_semi_join_matched_rows_total", _present,
           why="present:no-order-passes-300-on-tiny"),
+    # the cell sf10_q5_serial: a two-key build stays sorted,
+    # and the lookup joins add their probe rows by layout, and what
+    # the sorted layout reads, at the statement's drain
+    _case("q5", 'presto_tpu_join_builds_total{layout="sorted"}'),
+    _case("q5", 'presto_tpu_join_direct_fallback_total'
+          '{reason="multi_key"}'),
+    *[_case("q5", 'presto_tpu_join_probe_rows_total{layout="%s"}' % lay)
+      for lay in ("sorted", "direct")],
+    _case("q5", "presto_tpu_join_sorted_bytes_total"),
     _case("mesh", "presto_tpu_exchange_all_to_all_rows_total"),
     _case("mesh", "presto_tpu_exchange_all_to_all_bytes_total"),
     _case("mesh", "presto_tpu_exchange_all_to_all_waves_total"),

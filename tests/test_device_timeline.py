@@ -28,7 +28,8 @@ DEVICE_NAMES = {
     "join_build_apply_perm": "join_build",
     "join_build_direct": "join_build", "join_build_stats": "join_build",
     "join_probe_direct": "join_probe",
-    "join_probe": "join_probe", "join_probe_stage1": "join_probe",
+    "join_probe": "join_probe", "join_probe_sorted": "join_probe",
+    "join_probe_stage1": "join_probe",
     "join_probe_stage2": "join_probe",
     "join_probe_materialize": "join_probe",
     "join_probe_hash": "join_probe", "join_probe_search": "join_probe",
@@ -44,6 +45,7 @@ DEVICE_NAMES = {
     "fragment_topn": "fragment", "fragment_distinct": "fragment",
     "fragment_agg_step": "fragment",
     "fragment_join_probe": "fragment",
+    "fragment_join_probe_sorted": "fragment",
     "fragment_join_probe_stage0": "fragment",
     "fragment_join_probe_stage0_direct": "fragment",
     "fragment_join_probe_stage1": "fragment",

@@ -56,6 +56,37 @@ def test_span_self_time_nesting():
     assert doc["unattributed_ms"] >= -0.01
 
 
+def test_kernel_wrapper_charges_its_inner_frames_once():
+    """An instrumented host wrapper whose body opens frames of its own
+    (a build's blocking fetch: `device_wait`, `d2h`) charges dispatch
+    only with what those frames did not: the enclosing detailed
+    frame's self time stays positive, so its detail is charged
+    however long the fetch waits."""
+    from presto_tpu.telemetry import kernels, ledger
+
+    def host_wrapper():
+        time.sleep(0.002)
+        with ledger.span("device_wait"):
+            time.sleep(0.03)
+
+    wrapped = kernels.instrument_kernel(host_wrapper, "join_build")
+    wrapped()       # the first call of a kernel object is its compile
+    led = ledger.QueryLedger()
+    prev = ledger.install(led)
+    try:
+        t0 = time.perf_counter_ns()
+        with ledger.span("driver.step", detail="hash_build.finish"):
+            wrapped()
+        wall = time.perf_counter_ns() - t0
+    finally:
+        ledger.uninstall(prev)
+    snap = led.snapshot()
+    assert snap["device_wait"] >= 30_000_000
+    assert 2_000_000 <= snap["dispatch"] < 30_000_000
+    assert led.detail_ns[("driver.step", "hash_build.finish")] > 0
+    assert sum(snap.values()) <= wall
+
+
 def test_uninstalled_thread_is_noop():
     from presto_tpu.telemetry import ledger
     assert ledger.current() is None

@@ -526,3 +526,73 @@ def test_q18_programs_at_the_cells_size(one_chip, tpu_forks,
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes \
         + mem.output_size_in_bytes < 2 << 30
+
+
+#: TPC-H Q5 at SF10 (the cell sf10_q5_serial): the supplier build's
+#: 100,000 rows sit on this rung, and so does each batch of the
+#: customer join's output (about 160,000 live lines of 1994's orders a
+#: lineitem batch) that probes it
+Q5_LANES = 1 << 18
+
+
+def _two_key_table(lanes):
+    """An abstract sorted-hash BuildTable over the unique composite key
+    (suppkey, nationkey)."""
+    from presto_tpu.ops import join
+    from presto_tpu.types import BIGINT
+    batch = join.abstract_batch(lanes, [("bk1", BIGINT), ("bk2", BIGINT)])[0]
+    k = join.choose_radix_bits(lanes)
+    return join.BuildTable(
+        _sds(lanes, jnp.int64), _sds(lanes, jnp.int64),
+        _sds((1 << k) + 1, jnp.int64), _sds(lanes, jnp.int64),
+        jax.ShapeDtypeStruct((), jnp.int64), batch, radix_bits=k,
+        search_depth=8, unique_runs=True)
+
+
+@pytest.mark.parametrize("program, lanes", [
+    ("sorted_build", Q5_LANES), ("sorted_front", Q5_LANES),
+    ("sorted_front", BATCH), ("sorted_front", WIDE)])
+def test_q5_two_key_sorted_join_programs(one_chip, tpu_forks,
+                                         record_property, program, lanes):
+    """The sorted layout's programs on two keys, which no other cell
+    runs (compile seconds for a described v5e beside each): the build hashes both keys, orders the rows by hash
+    (one sort) and derives the radix metadata (34 s at 262,144 lanes);
+    the probe's front hashes the probe's two keys and searches its
+    bucket with gathers alone, at Q5's 262,144 lanes and at the 1M and
+    4M lanes a lineitem batch has (2-3 s each)."""
+    from presto_tpu.ops import join
+    from presto_tpu.types import BIGINT, DOUBLE
+    keys = ("bk1", "bk2")
+    if program == "sorted_build":
+        batch = join.abstract_batch(lanes, [
+            ("bk1", BIGINT), ("bk2", BIGINT)])[0]
+        fn = lambda b: join._build_sorted(  # noqa: E731
+            b, keys, join.choose_radix_bits(lanes))
+        args = (batch,)
+    else:
+        probe = join.abstract_batch(lanes, [
+            ("pk1", BIGINT), ("pk2", BIGINT), ("extendedprice", DOUBLE),
+            ("discount", DOUBLE)])[0]
+
+        def fn(table, p):
+            pk = ("pk1", "pk2")
+            return join.aligned_front(
+                table, p, pk, join._candidates_enc(table, p, pk), None,
+                "inner", tuple(p.names), keys, "hash")
+        args = (_two_key_table(Q5_LANES), probe)
+    t0 = time.perf_counter()
+    compiled = _compile(fn, args, one_chip)
+    seconds = time.perf_counter() - t0
+    record_property("compile_seconds", round(seconds, 1))
+    print(f"{program} at {lanes}: compiled for a described v5e in "
+          f"{seconds:.1f} s")
+    text = compiled.as_text()
+    if program == "sorted_build":
+        assert text.count(" sort(") == 1
+    else:
+        # the bounded search is unrolled gathers at the batch's width
+        assert " sort(" not in text and " while(" not in text
+        assert set(_moves(text)) == {("gather", lanes)}
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes \
+        + mem.output_size_in_bytes < 2 << 30
