@@ -31,7 +31,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from presto_tpu.ops.common import flag_pack_form
 from presto_tpu.telemetry import kernels as _kernels
+from presto_tpu.telemetry.metrics import METRICS
 from presto_tpu.types import Type, VARCHAR, BOOLEAN, DOUBLE, BIGINT
 
 MIN_CAPACITY = 16
@@ -378,6 +380,7 @@ class Batch:
             # gather PER COLUMN, which dominated semi-join/filter
             # drains at high selectivity (600k-row batches packing to
             # 1k slots)
+            count_flag_pack(self.capacity, capacity)
             return _compact_shrink(self, capacity)
         out = _compact(self)
         if capacity is None or capacity == self.capacity:
@@ -473,6 +476,15 @@ def _compact_jit(batch: Batch) -> Batch:
     return Batch(cols, batch.row_valid[order])
 
 
+FLAG_PACK_LANES = "presto_tpu_flag_pack_lanes_total"
+
+
+def count_flag_pack(n: int, size: int) -> None:
+    """Count a pack of `n` flagged lanes into `size` by the form
+    first_true_indices takes at those shapes (static: no sync)."""
+    METRICS.inc(FLAG_PACK_LANES, size, form=flag_pack_form(n, size))
+
+
 @functools.partial(_kernels.jit, family="compact", part="shrink",
                    static_argnums=(1,))
 def _compact_shrink_jit(batch: Batch, capacity: int) -> Batch:
@@ -537,9 +549,10 @@ _register_contract(KernelContract(
 _register_contract(KernelContract(
     family="compact", module=__name__, build=_compact_shrink_point,
     structure_varies=True,
-    structure_reason="first_true_indices binary-searches the rank "
-                     "prefix: log2(capacity) unrolled rounds on the "
-                     "CPU side of fast_searchsorted",
+    structure_reason="first_true_indices scatters each live lane's "
+                     "index by its rank at this quarter-width shape "
+                     "(flag_pack_form); where it searches instead, "
+                     "log2(capacity) unrolled rounds on the CPU side",
     notes="the bounded-nonzero shrink entry point"))
 
 

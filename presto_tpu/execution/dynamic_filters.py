@@ -304,7 +304,9 @@ register_contract(KernelContract(
     family="dynamic_filter", module=__name__,
     build=_distinct_set_point,
     structure_varies=True,
-    structure_reason="first_true_indices binary-searches the rank "
-                     "prefix of the input: log2(capacity) unrolled "
-                     "rounds on the CPU side of fast_searchsorted",
+    structure_reason="first_true_indices scatters the DF_SET_MAX "
+                     "packed slots by rank over inputs under about "
+                     "53K lanes and binary-searches the rank above "
+                     "(flag_pack_form): log2(capacity) unrolled rounds "
+                     "on the CPU side of fast_searchsorted",
     notes="bounded distinct-set build (sort + boundary dedupe)"))

@@ -21,8 +21,8 @@ from jax.profiler import TraceAnnotation
 
 from presto_tpu.batch import (
     COMPACT_FLOOR, COMPACT_MIN, Batch, Column, begin_deferred_compact,
-    bucket_capacity, end_deferred_compact, operator_capacity,
-    pad_for_kernel, remap_column, start_async_copy,
+    bucket_capacity, count_flag_pack, end_deferred_compact,
+    operator_capacity, pad_for_kernel, remap_column, start_async_copy,
 )
 from presto_tpu.native.pages import to_host
 from presto_tpu.operators.base import (
@@ -835,6 +835,8 @@ class LookupJoinOperator(Operator):
         width = min(operator_capacity(n, floor=COMPACT_MIN),
                     side.capacity)
         METRICS.inc(_LANES, width, stage="materialized")
+        if width < side.capacity:
+            count_flag_pack(side.capacity, width)
         return self._kernel.back(build, side, brow, verified, live,
                                  width)
 

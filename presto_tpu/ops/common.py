@@ -130,17 +130,55 @@ def prefix_sum(x: jnp.ndarray, dtype=jnp.int32) -> jnp.ndarray:
     return (inner + offsets[:, None]).reshape(-1)[:n]
 
 
-def first_true_indices(flags: jnp.ndarray, size: int,
+def flag_pack_form(n: int, size: int) -> str:
+    """How first_true_indices finds the first `size` flagged lanes of
+    `n`, from the two static shapes alone: "search" when a binary
+    search of every output slot into the rank gathers fewer lanes
+    (`size` x its ceil(log2(n)) + 1 rounds) than the one scatter
+    writes (`n`), "scatter" otherwise. The readings it rests on, ms
+    for the search / the scatter alone on a v5e (PERF.md, section 5):
+    1M -> 65,536 10.9 / 5.9, 256K -> 16,384 3.0 / 2.0, 1M -> 262,144
+    40.6 / 5.9, 4M -> 262,144 45.5 / 21.3, 64K -> 16,384 2.8 / 1.0;
+    262,144 -> 8,192 1.9 / 2.0, 1M -> 4,096 1.6 / 5.8, 4M -> 4,096
+    2.4 / 21.3, 4M -> 65,536 12.6 / 21.3 (XLA:TPU puts no sort before
+    this scatter). The one near tie, 1M -> 32,768 (6.0 / 5.9), takes
+    the search."""
+    return "scatter" if size * search_iters(n) >= n else "search"
+
+
+def _first_true_search(flags: jnp.ndarray, size: int,
                        fill_value: int) -> jnp.ndarray:
-    """Indices of the first `size` True entries, ascending, padded
-    with `fill_value` — `jnp.nonzero(flags, size=, fill_value=)[0]`
-    as a prefix sum plus a binary search for each output slot (the
-    stock lowering scans and scatters over the whole input)."""
+    """first_true_indices as a binary search of each output slot into
+    the rank: `size` lanes gathered a round."""
     rank = prefix_sum(flags)
     slots = jnp.arange(size, dtype=jnp.int32)
     idx = fast_searchsorted(rank, slots + 1, side="left")
     return jnp.where(slots < rank[-1], idx.astype(jnp.int32),
                      jnp.int32(fill_value))
+
+
+def _first_true_scatter(flags: jnp.ndarray, size: int,
+                        fill_value: int) -> jnp.ndarray:
+    """first_true_indices as ONE scatter of every lane's index by its
+    rank: a flagged lane's rank is unique, every other lane and every
+    rank past `size` is dropped, and `fill_value` stays where no lane
+    lands."""
+    n = flags.shape[0]
+    at = jnp.where(flags, prefix_sum(flags) - 1, size)
+    return jnp.full(size, fill_value, jnp.int32).at[at].set(
+        jnp.arange(n, dtype=jnp.int32), mode="drop")
+
+
+def first_true_indices(flags: jnp.ndarray, size: int,
+                       fill_value: int) -> jnp.ndarray:
+    """Indices of the first `size` True entries, ascending, padded
+    with `fill_value` — `jnp.nonzero(flags, size=, fill_value=)[0]`
+    bit for bit, as a prefix sum and then the form flag_pack_form
+    picks for the shapes (the stock lowering scans and scatters over
+    the whole input)."""
+    if flag_pack_form(flags.shape[0], size) == "scatter":
+        return _first_true_scatter(flags, size, fill_value)
+    return _first_true_search(flags, size, fill_value)
 
 
 def _narrow_sort_key(a: jnp.ndarray) -> List[jnp.ndarray]:

@@ -1278,9 +1278,10 @@ register_contract(KernelContract(
 register_contract(KernelContract(
     family="join_probe", module=__name__, build=_materialize_point,
     structure_varies=True,
-    structure_reason="first_true_indices binary-searches the rank "
-                     "prefix: log2(capacity) unrolled rounds on the "
-                     "CPU side of fast_searchsorted",
+    structure_reason="first_true_indices scatters each live lane's "
+                     "index by its rank at this quarter-width shape "
+                     "(flag_pack_form); where it searches instead, "
+                     "log2(capacity) unrolled rounds on the CPU side",
     notes="the aligned probe's back half at a quarter of the batch's "
           "width: live rows packed, both sides' columns gathered"))
 register_contract(KernelContract(

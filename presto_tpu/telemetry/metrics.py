@@ -264,6 +264,14 @@ METRICS.describe("presto_tpu_join_probe_lanes_total",
                  "COMPACT_FLOOR gathers at the bucket of its live "
                  "count; any other at its output capacity. Static "
                  "shapes, counted on the host")
+METRICS.describe("presto_tpu_flag_pack_lanes_total",
+                 "Output lanes of the packs that find a batch's live "
+                 "rows (ops/common.first_true_indices) by form: scatter "
+                 "= one scatter of each live lane's index by its rank, "
+                 "search = a binary search of each output slot, as "
+                 "flag_pack_form picks from the static shapes. Counted "
+                 "on the host by the aligned probe's back below the "
+                 "batch's width and by Batch.compact's shrink")
 METRICS.describe("presto_tpu_agg_stream_rows_total",
                  "Live rows into the streaming aggregations of drained "
                  "statements (the operator's input, before a fused "

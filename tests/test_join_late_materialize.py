@@ -272,6 +272,42 @@ def test_fused_filter_and_projection_run_in_the_back(layout, join_type,
     assert after["materialized"] - before["materialized"] == width
 
 
+def _flag_packs():
+    snap = METRICS.snapshot()
+    return {f: snap.get(
+        f'presto_tpu_flag_pack_lanes_total{{form="{f}"}}', 0)
+        for f in ("scatter", "search")}
+
+
+#: a probe batch wide enough that a back on the ladder's floor takes
+#: the search: 4,096 slots x 19 rounds < 262,144 lanes
+WIDE_LANES = 1 << 18
+
+
+@pytest.mark.parametrize("matches, width, form", [
+    (2000, 4096, "search"), (10000, 16384, "scatter")])
+def test_the_back_counts_its_flag_pack_form(matches, width, form):
+    """The back packs the live rows of a 262,144-lane probe batch into
+    the bucket of their count, and counts its output lanes under the
+    form first_true_indices takes there; the answer is the oracle's."""
+    build, build_rows = _build_side()
+    table = _table("direct", build, "inner")
+    keys = [3 * (i % 2990) for i in range(matches)] + \
+        [3 * i + 1 for i in range(2 * matches)]
+    batch = Batch.from_pydict(
+        {"pk": (keys, BIGINT), "pv": (list(range(len(keys))), BIGINT)},
+        capacity=WIDE_LANES)
+    spy = _kernel("inner")
+    before = _flag_packs()
+    _, (out,) = _drive(table, [batch], "inner", spy)
+    assert spy.calls == [("front", WIDE_LANES), ("back", width)]
+    assert out.to_pylist() == _oracle(
+        build_rows, list(zip(keys, range(len(keys)))), "inner")
+    grown = {f: v - before[f] for f, v in _flag_packs().items()}
+    assert grown == {form: width,
+                     ({"scatter", "search"} - {form}).pop(): 0}
+
+
 @pytest.mark.parametrize("layout", join.LAYOUTS)
 def test_fused_upstream_chain_runs_in_the_front(layout):
     """The scan-side chain (here: keep even `pv`) decides which probe

@@ -1,13 +1,15 @@
 """Kernel tests against pandas/numpy oracles (reference analog:
 presto-main operator tests asserting output pages, OperatorAssertion.java:53)."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pandas as pd
 import pytest
 
 from presto_tpu.batch import Batch, bucket_capacity
-from presto_tpu.ops import hashagg, join, sort
+from presto_tpu.execution.dynamic_filters import DF_SET_MAX
+from presto_tpu.ops import common, hashagg, join, sort
 from presto_tpu.types import BIGINT, DOUBLE, VARCHAR
 
 
@@ -149,6 +151,58 @@ def test_segment_ends_from_the_boundary_mask(case):
         want = [contrib[gid == g].sum(dtype=np.int64) if g < groups
                 else 0 for g in range(out_cap)]
     assert got.tolist() == [int(w) for w in want]
+
+
+#: density -> share of flagged lanes (none and all exactly)
+_FLAG_DENSITIES = {"none": 0.0, "sparse": 0.01, "half": 0.5, "all": 1.0}
+_FLAG_FORMS = {"search": common._first_true_search,
+               "scatter": common._first_true_scatter}
+
+
+@pytest.mark.parametrize("form", sorted(_FLAG_FORMS))
+@pytest.mark.parametrize("fill", ["zero", "last_lane"])
+@pytest.mark.parametrize("density", sorted(_FLAG_DENSITIES))
+@pytest.mark.parametrize("n", [1024, 65536, 262144])
+def test_first_true_indices_forms_equal_nonzero(n, density, fill, form):
+    """Both forms of first_true_indices, and the one the rule picks,
+    against jnp.nonzero(flags, size=, fill_value=) bit for bit, with
+    `size` below, at and above the number of flagged lanes."""
+    rng = np.random.default_rng(n + len(density))
+    share = _FLAG_DENSITIES[density]
+    flags = rng.random(n) < share if 0 < share < 1 \
+        else np.full(n, share == 1.0)
+    k = int(flags.sum())
+    fill_value = 0 if fill == "zero" else n - 1
+    flags = jnp.asarray(flags)
+    # nonzero at a smaller size is the prefix of nonzero at size n
+    every = np.asarray(jnp.nonzero(flags, size=n,
+                                   fill_value=fill_value)[0])
+    for size in sorted({max(k // 2, 1), max(k, 1), min(2 * k + 16, n)}):
+        @jax.jit
+        def both(f):
+            return (_FLAG_FORMS[form](f, size, fill_value),
+                    common.first_true_indices(f, size, fill_value))
+        got, picked = both(flags)
+        assert got.dtype == jnp.int32 and got.shape == (size,)
+        assert np.asarray(got).tolist() == every[:size].tolist(), \
+            (size, k)
+        assert np.asarray(picked).tolist() == every[:size].tolist()
+
+
+@pytest.mark.parametrize("n, size, form", [
+    (1 << 20, 1 << 16, "scatter"),   # Q3's lineitem-orders back
+    (1 << 18, 1 << 14, "scatter"),   # its split end's, Q5's supplier
+    (1 << 20, 1 << 18, "scatter"),   # Q5's orders back, 1M rung
+    (1 << 22, 1 << 18, "scatter"),   # and 4M rung
+    (1 << 18, 1 << 13, "search"),    # a narrow pack of 262,144 lanes
+    (1 << 20, 1 << 12, "search"),    # a shrink to the ladder's floor
+    (1 << 20, DF_SET_MAX, "search"),  # a dynamic filter's distinct set
+])
+def test_flag_pack_form_at_the_cells_shapes(n, size, form):
+    """The rule picks from the two shapes alone: the scatter where a
+    search of every output slot would gather more lanes than the
+    scatter writes."""
+    assert common.flag_pack_form(n, size) == form
 
 
 def _run_core(core, valid, keys, inputs, weights, aggs, out_cap):

@@ -222,13 +222,25 @@ def _aligned_back(build, probe, brow, verified, live):
                              "inner", tuple(build.names))
 
 
-@pytest.mark.parametrize("half", ["front", "back"])
+def _q5_back(build, probe, brow, verified, live):
+    """Q5's lineitem-orders back at SF10: about 160,000 of a 1M-lane
+    batch's lines are of 1994, so it packs onto 262,144 lanes."""
+    from presto_tpu.ops import join
+    return join.aligned_back(build, probe, brow, verified, live, 1 << 18,
+                             "inner", tuple(build.names))
+
+
+@pytest.mark.parametrize("half", ["front", "back", "q5_back"])
 def test_late_materialized_probe_at_q3_size(one_chip, tpu_forks, half):
     """The aligned probe's two programs at the shapes of Q3's lineitem
     join at sf1: the front searches a 1M-row batch and gathers no
     build column; the back packs 1,048,576 lanes into 65,536 and
-    gathers 3 probe and 4 build columns there."""
-    from presto_tpu.ops import join
+    gathers 3 probe and 4 build columns there. And Q5's back at SF10:
+    1,048,576 lanes into 262,144, 4 probe columns and 2 build columns
+    gathered out of the 4M-lane orders build. Both backs find the live
+    rows with one scatter of their ranks (flag_pack_form): no search
+    loop, and no sort before the scatter."""
+    from presto_tpu.ops import common, join
     from presto_tpu.types import BIGINT, DATE, DOUBLE, INTEGER
     table, _ = join._abstract_direct_table(BATCH, 1 << 21)
     build, _ = join.abstract_batch(BATCH, [
@@ -237,19 +249,30 @@ def test_late_materialized_probe_at_q3_size(one_chip, tpu_forks, half):
     probe, _ = join.abstract_batch(BATCH, [
         ("pk", BIGINT), ("extendedprice", DOUBLE),
         ("discount", DOUBLE)])
+    width = {"back": 1 << 16, "q5_back": 1 << 18}.get(half)
     if half == "front":
         fn, args = _aligned_front, (table, probe)
     else:
         fn = _aligned_back
+        if half == "q5_back":
+            fn = _q5_back
+            build, _ = join.abstract_batch(4 * BATCH, [
+                ("orderkey", BIGINT), ("custkey", BIGINT)])
+            probe, _ = join.abstract_batch(BATCH, [
+                ("orderkey", BIGINT), ("suppkey", BIGINT),
+                ("extendedprice", DOUBLE), ("discount", DOUBLE)])
+        assert common.flag_pack_form(BATCH, width) == "scatter"
         args = (build, probe, _sds(BATCH, jnp.int32),
                 _sds(BATCH, jnp.bool_),
                 jax.ShapeDtypeStruct((), jnp.int64))
     text = _compile(fn, args, one_chip).as_text()
     assert ("sort(" in text) is False, "nothing here sorts"
-    if half == "back":
+    if half != "front":
+        assert " while(" not in text, "no search loop in the back"
+        assert _moves(text)[("scatter", width)] == 1
         out = jax.eval_shape(fn, *args)
         assert {x.shape for x in jax.tree_util.tree_leaves(out)} \
-            == {(1 << 16,)}
+            == {(width,)}
 
 
 @pytest.mark.parametrize("family", ["spmd_shuffle", "spmd_fragment"])
@@ -363,7 +386,10 @@ def test_q3_build_of_sixteen_batches_at_sf10_size(
         args = (_sf10_orders(wide), lineitem, _sds(BATCH, jnp.int32),
                 _sds(BATCH, jnp.bool_),
                 jax.ShapeDtypeStruct((), jnp.int64))
-        want = {("gather", 1 << 16): 22}        # none at a wider lane
+        # the live rows' indices by one scatter of their ranks
+        # (flag_pack_form at 1M -> 65,536), then the columns' gathers;
+        # none at a wider lane
+        want = {("gather", 1 << 16): 21, ("scatter", 1 << 16): 1}
     t0 = time.perf_counter()
     compiled = _compile(fn, args, one_chip)
     seconds = time.perf_counter() - t0
